@@ -17,7 +17,7 @@ import os
 import sys
 import time
 
-sys.path.insert(0, ".")
+sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
 BASELINE_STEPS_PER_SEC = 100_000 / (14 * 3600)  # reference README.md:45-51
 
@@ -28,6 +28,30 @@ N_ACTIONS = 9  # MsPacman
 # The peak-FLOPs table and MFU math live in the library now
 # (sheeprl_tpu.telemetry.throughput) so train loops and this bench share one
 # implementation; see peak_flops_record / flops_of_lowered / mfu there.
+
+
+def require_accelerator() -> str:
+    """What a bench leg measures on, settled before anything else touches
+    JAX: the attached accelerator's platform, or `cpu-forced` when the
+    operator set BENCH_FORCE_CPU=1. A leg that finds no accelerator exits
+    non-zero: a CPU timing under the name of a device metric is worse than
+    no number."""
+    if os.environ.get("BENCH_FORCE_CPU"):
+        from sheeprl_tpu.utils.virtual_mesh import force_virtual_cpu_mesh
+
+        force_virtual_cpu_mesh(1)
+        return "cpu-forced"
+    import jax
+
+    platform = jax.devices()[0].platform
+    if platform == "cpu":
+        print(
+            "bench: JAX found no accelerator (platform cpu); nothing is measured. "
+            "BENCH_FORCE_CPU=1 measures this host instead, labelled as such.",
+            file=sys.stderr,
+        )
+        raise SystemExit(3)
+    return platform
 
 
 def record() -> dict:
@@ -162,7 +186,7 @@ def record() -> dict:
     data = stage_data()
     _phase(f"probe step {warm_step_s:.2f}s; timing")
 
-    # time-capped: on a slow link/machine stop early and report SPS over the
+    # time-capped: on a slow machine stop early and report SPS over the
     # reps that ran, instead of being killed by the subprocess budget. The
     # cap also shrinks to whatever remains of the SUBPROCESS budget
     # (BENCH_STEP_BUDGET_S) after setup/compile — a cold compile must
@@ -179,8 +203,7 @@ def record() -> dict:
     # dispatch is async, so the wall check must SYNC first or it never
     # fires. Granularity is adaptive: a slow host (seconds per step) syncs
     # every rep — pipelining is irrelevant there and a coarser check would
-    # blow straight past the budget; a fast chip keeps the 5-rep pipeline
-    # (per-rep syncs over a remote link would dominate the measurement).
+    # blow straight past the budget; a fast chip keeps the 5-rep pipeline.
     sync_every = 1 if warm_step_s > 1.0 else 5
     reps = 0
     t0 = time.perf_counter()
@@ -247,7 +270,8 @@ def main() -> None:
     # one schema-validated JSONL line on stdout (shared with in-run telemetry)
     from sheeprl_tpu.telemetry.sinks import write_event
 
-    write_event({"event": "bench", **record()}, sys.stdout)
+    platform = require_accelerator()
+    write_event({"event": "bench", **record(), "platform": platform}, sys.stdout)
 
 
 if __name__ == "__main__":

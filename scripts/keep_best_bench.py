@@ -3,8 +3,8 @@ real-accelerator run so far (highest vs_baseline, platform not cpu-*).
 
 Usage: python scripts/keep_best_bench.py <new_record.json>
 The input file holds bench.py stdout (one JSON record per line; last line is
-the headline). The watcher calls this after every opportunistic bench run so
-a flaky link still leaves the best window's number on disk for round close.
+the headline). Run it after a bench run to keep the best accelerator
+record so far on disk.
 """
 from __future__ import annotations
 

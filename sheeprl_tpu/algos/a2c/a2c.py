@@ -90,7 +90,7 @@ def main(dist: Distributed, cfg: Config) -> None:
         dist, cfg, obs_space, action_space, init_key, state["params"] if state else None
     )
     tx = clipped(instantiate(cfg.algo.optimizer), cfg.algo.get("max_grad_norm", 0.0))
-    opt_state = state["opt_state"] if state else tx.init(params)
+    opt_state = dist.replicate(state["opt_state"] if state else tx.init(params))
 
     rollout_steps = int(cfg.algo.rollout_steps)
     rb = ReplayBuffer(
@@ -108,7 +108,7 @@ def main(dist: Distributed, cfg: Config) -> None:
     value_fn = make_value_fn(module)
     update = make_update_fn(module, tx, cfg)
     # per-step inference on the player device (host CPU when the mesh is a
-    # remote accelerator); blocking refresh keeps A2C on-policy
+    # an accelerator); blocking refresh keeps A2C on-policy
     mirror, pdev, player_key, root_key = make_param_mirror(
         cfg, dist.local_device, params, root_key, allow_async=False
     )

@@ -324,6 +324,7 @@ def main(dist: Distributed, cfg: Config) -> None:
             "actor": txs["actor"].init(params["actor"]),
             "critic": txs["critic"].init(params["critic"]),
         }
+    opt_states = dist.replicate(opt_states)  # all train state on the mesh before the first step
 
     seq_len = int(cfg.algo.per_rank_sequence_length)
     buffer_size = int(cfg.buffer.size) if not cfg.dry_run else max(4 * seq_len, 64)

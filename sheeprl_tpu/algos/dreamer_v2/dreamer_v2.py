@@ -479,6 +479,7 @@ def main(dist: Distributed, cfg: Config) -> None:
             "critic": txs["critic"].init(params["critic"]),
             "step": jnp.zeros((), jnp.int32),
         }
+    opt_states = dist.replicate(opt_states)  # all train state on the mesh before the first step
 
     seq_len = int(cfg.algo.per_rank_sequence_length)
     rb = _build_buffer(cfg, num_envs, obs_keys, log_dir, rank)

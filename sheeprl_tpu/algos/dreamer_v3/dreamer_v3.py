@@ -100,7 +100,15 @@ def make_train_fn(
     cfg: Config,
     is_continuous: bool,
     actions_dim: Sequence[int],
+    state_shardings: Any = None,
 ):
+    """The jitted train function. ``state_shardings`` is the sharding tree
+    of ``(params, opt_states, moments)`` as the loop placed them: the step
+    then hands the state back placed the same way. Left to itself the
+    compiler picks output shardings of its own on a multi-axis mesh (at S
+    widths under dp2 x fsdp2 it returns 91 of 236 replicated leaves
+    fsdp-sharded), the second call sees other input shardings than the
+    first, and the whole program compiles again."""
     cnn_keys = tuple(cfg.algo.cnn_keys.encoder)
     mlp_keys = tuple(cfg.algo.mlp_keys.encoder)
     wm_cfg = cfg.algo.world_model
@@ -112,31 +120,31 @@ def make_train_fn(
     wm_apply, actor_apply, critic_apply, _cast, compute_dtype, mixed = make_precision_applies(
         cfg, wm, actor, critic
     )
-    # Pallas scan-resident GRU (ops/pallas_gru.py): only the decoupled path
-    # qualifies (its GRU inputs are time-parallel), only when the fused
-    # weight block fits VMEM; off TPU the kernel runs in interpret mode
-    # (value "interpret" forces that explicitly, e.g. for CI)
+    # Pallas scan-resident GRU (ops/pallas_gru.py): True is the compiled TPU
+    # kernel, "interpret" the interpreter (what the CPU tests run). Asking
+    # for it where it cannot run is an error, never a quiet scan: only the
+    # decoupled path qualifies (its GRU inputs are time-parallel), the
+    # kernel computes in f32, and the fused weight block must fit VMEM.
     pallas_mode = wm_cfg.select("pallas_gru") or False
-    use_pallas = (
-        decoupled
-        and bool(pallas_mode)
-        and not mixed  # the kernel is f32-internal; keep both paths' numerics equal
-        and pg.fits_vmem(int(wm_cfg.recurrent_model.dense_units), R)
-    )
-    if pallas_mode and not use_pallas:
-        reason = (
-            "decoupled_rssm=False"
-            if not decoupled
-            else "mixed precision (the kernel computes in f32)"
-            if mixed
-            else "weights exceed the VMEM budget"
+    if pallas_mode not in (False, True, "interpret"):
+        raise ValueError(
+            f"algo.world_model.pallas_gru must be False|True|interpret, got {pallas_mode!r}"
         )
-        print(
-            f"[dreamer_v3] algo.world_model.pallas_gru is set but UNUSED: {reason} "
-            "— the XLA scan path runs instead",
-            file=sys.stderr,
-        )
-    pallas_interpret = pallas_mode == "interpret" or jax.default_backend() != "tpu"
+    use_pallas = bool(pallas_mode)
+    if use_pallas:
+        if not decoupled:
+            raise ValueError("algo.world_model.pallas_gru needs algo.world_model.decoupled_rssm=True")
+        if mixed:
+            raise ValueError(
+                "algo.world_model.pallas_gru computes in f32: use fabric.precision=32-true"
+            )
+        if not pg.fits_vmem(int(wm_cfg.recurrent_model.dense_units), R):
+            raise ValueError(
+                "algo.world_model.pallas_gru: the fused GRU weights "
+                f"([{int(wm_cfg.recurrent_model.dense_units)}+{R}, 3*{R}] f32) exceed the "
+                "kernel's VMEM budget (XS and S presets fit)"
+            )
+    pallas_interpret = pallas_mode == "interpret"
     # phase-space observation loss rides the einsum decoder (see decode_phases)
     phase_obs_loss = use_phase_obs_loss(wm_cfg, cnn_keys)
     horizon = int(cfg.algo.horizon)
@@ -411,7 +419,9 @@ def make_train_fn(
 
     acknowledge_partial_donation()  # uint8/flag leaves can't alias; expected
 
-    @partial(jax.jit, donate_argnums=(0, 1, 2, 3))
+    out_shardings = None if state_shardings is None else (*state_shardings, None)  # metrics: the compiler's choice
+
+    @partial(jax.jit, donate_argnums=(0, 1, 2, 3), out_shardings=out_shardings)
     def train(params, opt_states, moments, batches, keys):
         """G gradient steps in ONE device call: scan `one_step` over the
         leading axis of `batches` [G, T, B, ...] / `keys` [G] (the reference
@@ -446,7 +456,7 @@ def make_player(wm: WorldModel, actor: Actor, cfg: Config, actions_dim, is_conti
     """Recurrent player (replaces reference PlayerDV3, agent.py:596-693):
     state = (recurrent h, stochastic z, last action a), all [N, ...]. Runs
     wherever its params are committed (see parallel/placement.py): host CPU
-    backend by default when the learner sits on a remote accelerator. The
+    backend by default when the learner sits on an accelerator. The
     PRNG key is threaded through the jitted step so the env loop never
     dispatches a host-side `jax.random.split` per frame."""
     cnn_keys = tuple(cfg.algo.cnn_keys.encoder)
@@ -546,9 +556,7 @@ def main(dist: Distributed, cfg: Config) -> None:
     txs, opt_states = build_optimizers(cfg, params)
     if state:
         opt_states = state["opt_states"]
-        moments = state["moments"]
-    else:
-        moments = init_moments()
+    moments = dist.replicate(state["moments"] if state else init_moments())
     opt_states = maybe_shard_opt_state(cfg, dist, opt_states)
 
     seq_len = int(cfg.algo.per_rank_sequence_length)
@@ -565,10 +573,13 @@ def main(dist: Distributed, cfg: Config) -> None:
     if state and cfg.buffer.checkpoint and "rb" in state:
         rb.load_state_dict(state["rb"])
 
-    train = make_train_fn(wm, actor, critic, txs, cfg, is_continuous, actions_dim)
+    train = make_train_fn(
+        wm, actor, critic, txs, cfg, is_continuous, actions_dim,
+        state_shardings=jax.tree.map(lambda x: x.sharding, (params, opt_states, moments)),
+    )
     player_init, player_step_fn = make_player(wm, actor, cfg, actions_dim, is_continuous, num_envs)
     # Actor/learner split (parallel/placement.py): per-step inference runs on
-    # the player device (host CPU backend when the mesh is a remote
+    # the player device (host CPU backend when the mesh is an
     # accelerator); the mirror re-syncs its {wm, actor} subtree after every
     # train burst — the only place params change.
     mirror, pdev, player_key, root_key = make_param_mirror(
@@ -601,8 +612,8 @@ def main(dist: Distributed, cfg: Config) -> None:
     last_checkpoint = state["last_checkpoint"] if state else 0
     clip_rewards_fn = (lambda r: np.tanh(r)) if cfg.env.clip_rewards else (lambda r: r)
 
-    # [G, T, B, ...] replay batches: HBM-resident ring (rows cross the link
-    # once, batches gather on device) on a single remote accelerator, else
+    # [G, T, B, ...] replay batches: HBM-resident ring (rows are shipped
+    # once, batches gather on device) on a single accelerator, else
     # host-sampled + dp-sharded staging (data/device_ring.py)
     prefetch = make_sequential_prefetcher(
         cfg,

@@ -170,6 +170,7 @@ def main(dist: Distributed, cfg: Config) -> None:
             "critic": txs["critic"].init(params["critic"]),
             "alpha": txs["alpha"].init(params["log_alpha"]),
         }
+    opt_states = dist.replicate(opt_states)  # all train state on the mesh before the first step
 
     buffer_size = int(cfg.buffer.size) if not cfg.dry_run else max(2 * num_envs, 8)
     rb = ReplayBuffer(
@@ -207,7 +208,7 @@ def main(dist: Distributed, cfg: Config) -> None:
     last_checkpoint = state["last_checkpoint"] if state else 0
 
     # per-step inference on the player device (host CPU when the mesh is a
-    # remote accelerator); mirror re-syncs the actor after each train burst
+    # an accelerator); mirror re-syncs the actor after each train burst
     mirror, pdev, player_key, root_key = make_param_mirror(
         cfg, dist.local_device, {"actor": params["actor"]}, root_key
     )

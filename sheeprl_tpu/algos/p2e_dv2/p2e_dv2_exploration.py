@@ -464,6 +464,7 @@ def main(dist: Distributed, cfg: Config) -> None:
     else:
         opt_states = {k: txs[k].init(params[k]) for k in txs}
         opt_states["step"] = jnp.zeros((), jnp.int32)
+    opt_states = dist.replicate(opt_states)  # all train state on the mesh before the first step
 
     rb = _build_buffer(cfg, num_envs, obs_keys, log_dir, rank)
     if state and cfg.buffer.checkpoint and "rb" in state:

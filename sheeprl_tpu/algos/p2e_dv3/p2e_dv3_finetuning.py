@@ -116,6 +116,7 @@ def main(dist: Distributed, cfg: Config, exploration_cfg: Config) -> None:
         opt_states = {k: txs[k].init(params[k]) for k in txs}
         opt_states["step"] = jnp.zeros((), jnp.int32)
     opt_states = maybe_shard_opt_state(cfg, dist, opt_states)
+    moments = dist.replicate(moments)  # all train state on the mesh before the first step
 
     seq_len = int(cfg.algo.per_rank_sequence_length)
     buffer_size = int(cfg.buffer.size) if not cfg.dry_run else max(4 * seq_len, 64)

@@ -175,7 +175,7 @@ def main(dist: Distributed, cfg: Config) -> None:
     )
 
     tx = clipped(instantiate(cfg.algo.optimizer), cfg.algo.get("max_grad_norm", 0.0))
-    opt_state = state["opt_state"] if state else tx.init(params)
+    opt_state = dist.replicate(state["opt_state"] if state else tx.init(params))
 
     rollout_steps = int(cfg.algo.rollout_steps)
     rb = ReplayBuffer(
@@ -200,7 +200,7 @@ def main(dist: Distributed, cfg: Config) -> None:
     value_fn = make_value_fn(module)
     update = make_update_fn(module, tx, cfg, num_minibatches, mb_size)
     # per-step inference runs on the player device (host CPU when the mesh is
-    # a remote accelerator — parallel/placement.py); blocking refresh after
+    # an accelerator — parallel/placement.py); blocking refresh after
     # every update keeps PPO strictly on-policy
     mirror, pdev, player_key, root_key = make_param_mirror(
         cfg, dist.local_device, params, root_key, allow_async=False
@@ -296,8 +296,8 @@ def main(dist: Distributed, cfg: Config) -> None:
             obs = next_obs
 
             ep_stats.extend(episode_stats(info))
-        # mirror params: keeps the bootstrap off the remote link (the GAE
-        # scan then runs on the player device; data is tiny [T, N])
+        # mirror params: the bootstrap value runs on the player device like
+        # the rollout's other forwards (data is tiny [T, N])
         next_value = value_fn(mirror.current(), prepare_obs(obs, cnn_keys, mlp_keys, num_envs))
         return buf.buffer, next_value, ep_stats
 

@@ -101,7 +101,7 @@ def _player_loop(
         )
 
         # per-step inference on the player device (host CPU when the mesh is
-        # a remote accelerator); ParamMirror's defensive copy keeps the
+        # an accelerator); ParamMirror's defensive copy keeps the
         # trainer's donated buffers from dying under us on shared devices
         pdev = player_device(cfg, dist.local_device)
         mirror = ParamMirror(init_params, pdev)
@@ -221,7 +221,7 @@ def main(dist: Distributed, cfg: Config) -> None:
     )
 
     tx = clipped(instantiate(cfg.algo.optimizer), cfg.algo.get("max_grad_norm", 0.0))
-    opt_state = state["opt_state"] if state else tx.init(params)
+    opt_state = dist.replicate(state["opt_state"] if state else tx.init(params))
 
     rollout_steps = int(cfg.algo.rollout_steps)
     num_envs = int(cfg.env.num_envs)

@@ -175,7 +175,7 @@ def main(dist: Distributed, cfg: Config) -> None:
     reset_on_done = bool(cfg.algo.reset_recurrent_state_on_done)
 
     tx = clipped(instantiate(cfg.algo.optimizer), cfg.algo.get("max_grad_norm", 0.0))
-    opt_state = state["opt_state"] if state else tx.init(params)
+    opt_state = dist.replicate(state["opt_state"] if state else tx.init(params))
 
     rollout_steps = int(cfg.algo.rollout_steps)
     seq_len = int(cfg.algo.per_rank_sequence_length)
@@ -229,7 +229,7 @@ def main(dist: Distributed, cfg: Config) -> None:
         return np.concatenate(oh, axis=-1)
 
     # per-step inference on the player device (host CPU when the mesh is a
-    # remote accelerator); blocking refresh keeps PPO strictly on-policy
+    # an accelerator); blocking refresh keeps PPO strictly on-policy
     mirror, pdev, player_key, root_key = make_param_mirror(
         cfg, dist.local_device, params, root_key, allow_async=False
     )

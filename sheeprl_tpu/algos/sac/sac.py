@@ -171,6 +171,7 @@ def main(dist: Distributed, cfg: Config) -> None:
             "alpha": txs["alpha"].init(params["log_alpha"]),
             "step": jnp.zeros((), jnp.int32),
         }
+    opt_states = dist.replicate(opt_states)  # all train state on the mesh before the first step
 
     buffer_size = int(cfg.buffer.size) if not cfg.dry_run else max(2 * num_envs, 8)
     rb = ReplayBuffer(
@@ -208,7 +209,7 @@ def main(dist: Distributed, cfg: Config) -> None:
     last_checkpoint = state["last_checkpoint"] if state else 0
     cumulative_grad_steps = state["cumulative_grad_steps"] if state else 0
 
-    # [G, B, ...] batches: HBM ring on a single remote accelerator, else
+    # [G, B, ...] batches: HBM ring on a single accelerator, else
     # host-sampled + dp-sharded staging (data/device_ring.py)
     prefetch = make_uniform_prefetcher(
         cfg,
@@ -219,7 +220,7 @@ def main(dist: Distributed, cfg: Config) -> None:
     )
     pending_metrics: list = []
     # per-step inference on the player device (host CPU when the mesh is a
-    # remote accelerator); mirror re-syncs the actor after each train burst
+    # an accelerator); mirror re-syncs the actor after each train burst
     mirror, pdev, player_key, root_key = make_param_mirror(
         cfg, dist.local_device, {"actor": params["actor"]}, root_key
     )

@@ -89,7 +89,7 @@ def _player_loop(
             ratio.load_state_dict(state["ratio"])
 
         # per-step inference on the player device (host CPU when the mesh is
-        # a remote accelerator); ParamMirror's defensive copy keeps the
+        # an accelerator); ParamMirror's defensive copy keeps the
         # trainer's donated buffers from dying under us on shared devices
         pdev = player_device(cfg)
         mirror = ParamMirror(init_actor_params, pdev)
@@ -220,6 +220,7 @@ def main(dist: Distributed, cfg: Config) -> None:
             "alpha": txs["alpha"].init(params["log_alpha"]),
             "step": jnp.zeros((), jnp.int32),
         }
+    opt_states = dist.replicate(opt_states)  # all train state on the mesh before the first step
 
     train = make_train_fn(actor, critic, txs, cfg, target_entropy)
     batch_size = int(cfg.algo.per_rank_batch_size) * dist.world_size

@@ -222,6 +222,7 @@ def main(dist: Distributed, cfg: Config) -> None:
             "decoder": txs["decoder"].init(params["decoder"]),
             "step": jnp.zeros((), jnp.int32),
         }
+    opt_states = dist.replicate(opt_states)  # all train state on the mesh before the first step
 
     buffer_size = int(cfg.buffer.size) if not cfg.dry_run else max(2 * num_envs, 8)
     rb = ReplayBuffer(
@@ -262,7 +263,7 @@ def main(dist: Distributed, cfg: Config) -> None:
     last_log = state["last_log"] if state else 0
     last_checkpoint = state["last_checkpoint"] if state else 0
 
-    # [G, B, ...] pixel batches: HBM ring on a single remote accelerator
+    # [G, B, ...] pixel batches: HBM ring on a single accelerator
     # (next_* frames are stored explicitly, hence the ×2 obs hint and the
     # next_-prefixed cnn keys keeping uint8), else host sampling
     prefetch = make_uniform_prefetcher(
@@ -275,7 +276,7 @@ def main(dist: Distributed, cfg: Config) -> None:
     )
 
     # per-step inference on the player device (host CPU when the mesh is a
-    # remote accelerator); mirror re-syncs encoder+actor after a train burst
+    # an accelerator); mirror re-syncs encoder+actor after a train burst
     mirror, pdev, player_key, root_key = make_param_mirror(
         cfg, dist.local_device, {"encoder": params["encoder"], "actor": params["actor"]}, root_key
     )

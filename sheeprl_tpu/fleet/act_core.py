@@ -16,7 +16,9 @@ the inference-service mode both call the exact same jitted core; the
 service recomputes the same row keys from the base key the worker ships
 (``row_keys``: ``fold_in(key, slot)`` per env slot), so a row acted
 locally and a row acted remotely are the same computation on the same
-operands.
+operands. The compiled program still depends on the batch width, and with
+it the last bit of a float may: bit equality holds at equal width, float32
+rounding across widths (see :mod:`~sheeprl_tpu.fleet.act_service`).
 
 Cores expose the surface :mod:`sheeprl_tpu.fleet.act_service` batches
 behind and :mod:`sheeprl_tpu.fleet.programs` steps locally:

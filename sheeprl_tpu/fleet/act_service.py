@@ -15,9 +15,14 @@ machinery is reused wholesale: deadline-coalescing flush loop
 Parity is the contract, not an aspiration: the service calls the SAME
 jitted core a worker-mode program calls locally, with per-row keys
 recomputed from the shipped base key (``act_core.row_keys``), so a row
-acted remotely is bit-identical to the row acted on the worker host —
-regardless of padding or cross-worker coalescing (the act-parity test
-pins this for SAC and DV3).
+acted remotely is the same function of (params, obs, key, state) as the
+row acted on the worker host, whatever it was padded or coalesced with.
+In bits: the same rows with the same keys at the same bucket width give
+the same bits. Across widths the compiler may vectorise the same math
+differently, and the last bit of a float can move (it does for SAC's tanh
+on this XLA:CPU, and a TPU tiles matmuls by width), so a worker's own
+narrower call agrees to float32 rounding only. The act-parity test pins
+both statements for SAC and DV3.
 
 Durability properties:
 
@@ -34,7 +39,7 @@ Durability properties:
   `FleetEngine.publish` with the NEXT ledger version *before* the
   supervisor broadcasts to workers, so by the time any worker learns of
   publication N the service already acts with it: staleness accounting
-  stays bit-identical to the per-worker path.
+  stays identical to the per-worker path.
 """
 from __future__ import annotations
 

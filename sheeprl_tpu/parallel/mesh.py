@@ -63,24 +63,6 @@ _PRECISION_POLICIES = {
 }
 
 
-def distributed_is_initialized() -> bool:
-    """`jax.distributed.is_initialized` is a recent addition; on versions
-    that predate it (e.g. 0.4.3x) fall back to probing the internal client
-    handle. The public probe is preferred so test topologies can patch it."""
-    probe = getattr(jax.distributed, "is_initialized", None)
-    if callable(probe):
-        try:
-            return bool(probe())
-        except Exception:
-            return False
-    try:
-        from jax._src import distributed as _distributed_internal
-
-        return getattr(_distributed_internal.global_state, "client", None) is not None
-    except Exception:
-        return False
-
-
 @dataclass
 class Precision:
     name: str
@@ -112,7 +94,7 @@ class Distributed:
         del strategy  # parity knob; sharding subsumes DDP/single-device
         # Multi-host initialization (DCN): driven by standard JAX env vars /
         # TPU metadata; only attempt when explicitly configured.
-        if num_nodes > 1 and not distributed_is_initialized():
+        if num_nodes > 1 and not jax.distributed.is_initialized():
             jax.distributed.initialize()
 
         if accelerator in ("auto", None):
@@ -121,10 +103,9 @@ class Distributed:
             backend = {"cuda": "gpu"}.get(accelerator, accelerator)
         else:
             raise ValueError(f"Unknown accelerator '{accelerator}'")
-        try:
-            all_devices = jax.devices(backend) if backend else jax.devices()
-        except RuntimeError:
-            all_devices = jax.devices()
+        # an accelerator asked for by name and not there is an error
+        # (jax.devices raises), never a quiet run on whatever else exists
+        all_devices = jax.devices(backend) if backend else jax.devices()
 
         if devices in ("auto", -1, "-1", None):
             n = len(all_devices)
@@ -245,6 +226,14 @@ class Distributed:
         return jax.tree.map(lambda x: jax.device_put(x, s), tree)
 
     def replicate(self, tree: Any) -> Any:
+        """Every leaf replicated over the mesh. Train loops put ALL the state
+        a jitted step carries through here (or a sharded placement) before
+        the first call — fresh optimizer counters, `Moments`, a resumed
+        checkpoint's numpy leaves — not only the params: an array's type
+        includes the mesh of its sharding, so a step whose first call mixes
+        mesh-placed params with unplaced leaves gets, from its own outputs,
+        different input types on the second call, and traces and compiles
+        the whole program again."""
         s = self.replicated
         return jax.tree.map(lambda x: jax.device_put(x, s), tree)
 
@@ -339,16 +328,17 @@ def maybe_shard_opt_state(cfg: Any, dist: Optional["Distributed"], opt_states: A
     """Optimizer-state layout: on a multi-axis mesh (fsdp or tp > 1) the
     state always follows the rule engine — moments mirror their params'
     inferred specs, replicated leaves get the ZeRO-1 fallback. On a pure-dp
-    mesh the historical behavior is preserved: sharded over ``dp`` only when
-    ``fabric.shard_optimizer_state`` asks for it. Applied once, to fresh AND
-    resumed state."""
+    mesh it is sharded over ``dp`` only when ``fabric.shard_optimizer_state``
+    asks for it, and replicated otherwise. Applied once, to fresh AND resumed
+    state. Every leaf comes back placed on the mesh: see
+    :meth:`Distributed.replicate` for why that matters."""
     if dist is None:
         return opt_states
     if not dist.is_pure_dp:
         return dist.shard_opt_state(opt_states)
     if cfg.select("fabric.shard_optimizer_state", False):
         return dist.shard_over_dp(opt_states)
-    return opt_states
+    return dist.replicate(opt_states)
 
 
 def maybe_shard_params(cfg: Any, dist: Optional["Distributed"], params: Any) -> Any:

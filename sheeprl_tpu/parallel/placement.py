@@ -1,36 +1,37 @@
-"""Latency-aware actor/learner placement.
+"""Actor/learner placement: which device runs per-step policy inference.
 
 The reference runs player and trainer on the same torch device (e.g.
-sheeprl/algos/dreamer_v3/dreamer_v3.py builds PlayerDV3 on ``fabric.device``)
-— fine when the accelerator sits on the local PCIe bus. A TPU often does not:
-it is reached over a network link where every dispatch+fetch round trip costs
-tens of milliseconds, while the per-env-step policy forward of a small net is
-microseconds of compute. Serving single-env inference from the remote chip
-makes the *latency*, not the FLOPs, the frame-rate.
-
-So the framework splits the loop (Podracer/Sebulba-style actor–learner
-placement, re-derived for a single-controller JAX process):
+sheeprl/algos/dreamer_v3/dreamer_v3.py builds PlayerDV3 on ``fabric.device``).
+Here the two are placed separately. The per-env-step policy forward of a small
+net is microseconds of compute, and every step also pays a dispatch and a
+device→host fetch of the action before the environment can move; the gradient
+step is the large fused program. So the loop is split (Podracer/Sebulba-style
+actor–learner placement, re-derived for a single-controller JAX process):
 
 * the **learner** (the big fused gradient-step program) stays on the
-  accelerator mesh, fed by the staged host→HBM prefetcher;
-* the **player** (per-step policy inference + recurrent state) runs on the
-  host CPU backend of the *same* process — same weights, same jitted code,
-  compiled for ``cpu`` simply by committing its inputs there;
+  accelerator mesh, fed by the replay prefetcher;
+* the **player** (per-step policy inference + recurrent state) runs wherever
+  its params are committed — same weights, same jitted code, compiled for
+  that device simply by committing its inputs there;
 * a :class:`ParamMirror` keeps the player's copy of the weights in sync,
   refreshed after every train burst (parameters only change there).
 
-The mirror has two refresh modes:
+``algo.player.device`` picks the player's device: ``host`` is the CPU backend
+of this process (an error where the process has none), ``accelerator`` the
+learner's first device, and ``auto`` the CPU backend whenever the learner is
+an accelerator and the process has one, else the learner's device. Which of
+``host`` and ``accelerator`` is faster on a locally attached chip is not
+measured (ROADMAP, Speed item 4); ``chip_smoke.py`` prints where the player
+ran.
+
+The mirror has two refresh modes (``algo.player.async_refresh``):
 
 * ``blocking`` (default) — the next player step waits for the new weights:
   exactly the reference's always-latest-params semantics;
-* ``async`` — the device→host transfer is dispatched immediately but the
+* ``async`` — the device→player copy is dispatched immediately but the
   player keeps using the previous weights until the new ones have landed
-  (``jax.Array.is_ready``), hiding the link latency entirely. Staleness is
-  bounded by one transfer (a few env steps); standard practice in
-  distributed actor–learner RL (IMPALA-family).
-
-Configured per-run via ``algo.player.device`` (auto | host | accelerator)
-and ``algo.player.async_refresh``.
+  (``jax.Array.is_ready``). Staleness is bounded by one transfer (a few env
+  steps); standard practice in distributed actor–learner RL (IMPALA-family).
 """
 from __future__ import annotations
 
@@ -40,22 +41,14 @@ import jax
 
 
 def host_device() -> Any:
-    """The CPU backend device of this process (falls back to the default
-    device when JAX was initialized with a cpu-only platform)."""
-    try:
-        return jax.local_devices(backend="cpu")[0]
-    except RuntimeError:
-        return jax.local_devices()[0]
+    """The CPU backend device of this process. Raises ``RuntimeError`` where
+    the process has none (e.g. ``JAX_PLATFORMS=tpu``)."""
+    return jax.local_devices(backend="cpu")[0]
 
 
 def player_device(cfg: Any, accelerator: Optional[Any] = None) -> Any:
-    """Resolve where per-step policy inference should run.
-
-    ``auto`` places the player on the host CPU backend whenever the default
-    backend is an accelerator (remote dispatch latency ≫ tiny-net compute),
-    and on the default device when the process is CPU-only (tests, dryruns —
-    there is nothing to win and one device fewer to think about).
-    """
+    """Resolve where per-step policy inference should run (see the module
+    docstring for the three ``algo.player.device`` modes)."""
     mode = "auto"
     if cfg is not None:
         mode = cfg.select("algo.player.device", "auto") or "auto"
@@ -66,7 +59,12 @@ def player_device(cfg: Any, accelerator: Optional[Any] = None) -> Any:
         return host_device()
     if mode != "auto":
         raise ValueError(f"algo.player.device must be auto|host|accelerator, got '{mode}'")
-    return host_device() if default.platform != "cpu" else default
+    if default.platform == "cpu":
+        return default
+    try:
+        return host_device()
+    except RuntimeError:  # accelerator-only process: auto stays on the learner's device
+        return default
 
 
 class ParamMirror:
@@ -77,7 +75,7 @@ class ParamMirror:
     this step. In blocking mode that is always the newest copy (the player
     step then waits on the transfer); in async mode the newest copy is
     swapped in only once every leaf ``is_ready()``, so the player never
-    stalls on the link.
+    stalls on the transfer.
 
     Thread contract (the overlap engine, ``engine/overlap.py``, relies on
     it): ``refresh`` is called by the learner thread, ``current`` by the
@@ -98,17 +96,26 @@ class ParamMirror:
         self._swap_lock = threading.Lock()
 
     def _put(self, params: Any) -> Any:
-        """Copy params to the mirror device. ``device_put`` ALIASES an array
-        that already lives on the target device — and the learner's train
-        step donates its param buffers, which would delete the mirror's copy
-        out from under the player (single-device CPU runs, where learner and
-        player share cpu:0). Force a real on-device copy for those leaves."""
+        """Copy params to the mirror device, every leaf committed to that ONE
+        device (`SingleDeviceSharding`): the player's key and recurrent state
+        are single-device arrays too, and a leaf that kept the learner's
+        mesh sharding would give the player step's inputs two different
+        abstract meshes — its key comes back from the first call under the
+        params' mesh and the second call retraces.
+
+        ``device_put`` ALIASES a buffer that already lives on the target
+        device — the whole array on a single-device run where learner and
+        player share the device, or this device's copy of a leaf replicated
+        over the mesh — and the learner's train step donates its param
+        buffers, which would delete the mirror's copy out from under the
+        player. Those leaves get a real on-device copy first."""
 
         def put_leaf(x: Any) -> Any:
-            if isinstance(x, jax.Array) and x.devices() == {self.device}:
+            if isinstance(x, jax.Array) and x.is_fully_replicated and self.device in x.devices():
                 import jax.numpy as jnp
 
-                return jnp.copy(x)  # new buffer on the same device
+                local = next(s.data for s in x.addressable_shards if s.device == self.device)
+                x = jnp.copy(local)  # new buffer on the same device
             return jax.device_put(x, self.device)
 
         return jax.tree.map(put_leaf, params)
@@ -140,8 +147,7 @@ class ParamMirror:
 
 def place_for_inference(cfg: Any, params: Any) -> Any:
     """One-shot placement for evaluation rollouts: commit a params subtree to
-    the player device (host CPU when the default backend is a remote
-    accelerator — the same latency story as the training players). Feed the
+    the player device (the same choice as the training players). Feed the
     jitted policy NUMPY inputs so every step runs on this device."""
     return jax.device_put(params, player_device(cfg))
 

@@ -390,9 +390,7 @@ class InferencePolicy:
 
         out: list = []
         try:
-            import jax
-
-            device = jax.devices()[0]
+            device = self.device  # the roof of the device the policy runs on
             params, _ = self.current_params()
             flops_rec = peak_flops_record(device)
             bw_rec = peak_bytes_per_s_record(device)

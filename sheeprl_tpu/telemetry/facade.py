@@ -381,13 +381,10 @@ class Telemetry:
         `throughput.flops_of_lowered`); enables in-run MFU in log records."""
         if flops is None:
             return
-        try:
-            import jax
+        import jax
 
-            rec = peak_flops_record(jax.devices()[0])
-            self.throughput.set_model_flops(flops, rec.get("peak_flops"), jax.device_count())
-        except Exception:
-            self.throughput.set_model_flops(flops)
+        rec = peak_flops_record(jax.devices()[0])
+        self.throughput.set_model_flops(flops, rec.get("peak_flops"), jax.device_count())
 
     def instrument(self, fn: Any, name: Optional[str] = None) -> Any:
         """Wrap a python callable before `jax.jit` so retraces are counted
@@ -397,21 +394,18 @@ class Telemetry:
     # -- roofline ----------------------------------------------------------
     def _peaks(self) -> Dict[str, Any]:
         if self._roofline_peaks is None:
-            try:
-                import jax
+            import jax
 
-                dev = jax.devices()[0]
-                fr = peak_flops_record(dev)
-                br = peak_bytes_per_s_record(dev)
-                self._roofline_peaks = {
-                    "peak_flops": fr.get("peak_flops"),
-                    "peak_bytes_per_s": br.get("peak_bytes_per_s"),
-                    "basis": str(br.get("peak_bytes_per_s_basis") or ""),
-                    "device_kind": str(getattr(dev, "device_kind", "")),
-                    "n_devices": int(jax.device_count()),
-                }
-            except Exception:
-                self._roofline_peaks = {}
+            dev = jax.devices()[0]
+            fr = peak_flops_record(dev)
+            br = peak_bytes_per_s_record(dev)
+            self._roofline_peaks = {
+                "peak_flops": fr.get("peak_flops"),
+                "peak_bytes_per_s": br.get("peak_bytes_per_s"),
+                "basis": str(br.get("peak_bytes_per_s_basis") or ""),
+                "device_kind": str(getattr(dev, "device_kind", "")),
+                "n_devices": int(jax.device_count()),
+            }
         return self._roofline_peaks
 
     def register_roofline(

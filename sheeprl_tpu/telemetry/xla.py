@@ -58,33 +58,30 @@ def _ensure_listener() -> None:
         if _listener_installed:
             return
         _listener_installed = True
-    try:
-        import jax.monitoring as monitoring
+    import jax.monitoring as monitoring
 
-        def _on_duration(name: str, secs: float, **_kw: Any) -> None:
-            with _lock:
-                if name == _COMPILE_EVENT:
-                    _counters["compile_count"] += 1
-                    _counters["compile_seconds"] += float(secs)
-                    tag = getattr(_tls, "tag", None) or UNTAGGED
-                    slot = _compile_breakdown.setdefault(tag, {"count": 0, "seconds": 0.0})
-                    slot["count"] += 1
-                    slot["seconds"] += float(secs)
-                elif name == _TRACE_EVENT:
-                    _counters["jaxpr_trace_count"] += 1
+    def _on_duration(name: str, secs: float, **_kw: Any) -> None:
+        with _lock:
+            if name == _COMPILE_EVENT:
+                _counters["compile_count"] += 1
+                _counters["compile_seconds"] += float(secs)
+                tag = getattr(_tls, "tag", None) or UNTAGGED
+                slot = _compile_breakdown.setdefault(tag, {"count": 0, "seconds": 0.0})
+                slot["count"] += 1
+                slot["seconds"] += float(secs)
+            elif name == _TRACE_EVENT:
+                _counters["jaxpr_trace_count"] += 1
 
-        monitoring.register_event_duration_secs_listener(_on_duration)
+    monitoring.register_event_duration_secs_listener(_on_duration)
 
-        def _on_event(name: str, **_kw: Any) -> None:
-            with _lock:
-                if name == _CACHE_HIT_EVENT:
-                    _counters["cache_hits"] += 1
-                elif name == _CACHE_MISS_EVENT:
-                    _counters["cache_misses"] += 1
+    def _on_event(name: str, **_kw: Any) -> None:
+        with _lock:
+            if name == _CACHE_HIT_EVENT:
+                _counters["cache_hits"] += 1
+            elif name == _CACHE_MISS_EVENT:
+                _counters["cache_misses"] += 1
 
-        monitoring.register_event_listener(_on_event)
-    except Exception:
-        pass  # very old jax: counters stay at 0 rather than crashing
+    monitoring.register_event_listener(_on_event)
 
 
 def compile_counters() -> Dict[str, float]:

@@ -6,6 +6,7 @@ live in `sheeprl_tpu.ops` because on TPU they are jitted device code.
 """
 from __future__ import annotations
 
+import os
 from typing import Any, Dict, Optional
 
 import numpy as np
@@ -85,29 +86,26 @@ def save_configs(cfg: Any, log_dir: str) -> None:
     save_config(cfg, f"{log_dir}/config.yaml")
 
 
-DEFAULT_XLA_CACHE_DIR = "~/.cache/sheeprl_tpu/xla_cache"
+# <checkout>/.xla_cache (git-ignored): a fixed path, because the directory
+# is part of every cache key — a cache that moves never hits
+DEFAULT_XLA_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))), ".xla_cache"
+)
 
 
 def enable_compilation_cache() -> None:
-    """Persistent XLA compilation cache: the DreamerV3 train program takes
-    tens of seconds to compile on TPU, and on a flaky-link machine every
-    bench/run attempt would re-pay it. `JAX_COMPILATION_CACHE_DIR` overrides
-    the location (`~/.cache/sheeprl_tpu/xla_cache` by default); set
-    `SHEEPRL_NO_COMPILATION_CACHE=1` to disable. Safe to call repeatedly."""
-    import os
-
+    """Persistent XLA compilation cache (the DreamerV3 train program takes
+    most of a minute to compile for a TPU). Where `JAX_COMPILATION_CACHE_DIR`
+    is set, JAX already keeps its cache there and no other directory is set
+    in code; otherwise the cache lives in `DEFAULT_XLA_CACHE_DIR`.
+    `SHEEPRL_NO_COMPILATION_CACHE=1` disables it. Safe to call repeatedly."""
     if os.environ.get("SHEEPRL_NO_COMPILATION_CACHE"):
         return
     import jax
 
-    cache_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR") or os.path.expanduser(
-        DEFAULT_XLA_CACHE_DIR
-    )
-    try:
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
-    except Exception:  # very old jax: a cold compile beats a crash
-        pass
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir", DEFAULT_XLA_CACHE_DIR)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
 
 
 def acknowledge_partial_donation() -> None:

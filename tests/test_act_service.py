@@ -5,12 +5,14 @@ The contract under test is PARITY: moving the policy step off the worker
 hosts onto the learner-hosted batched service must not move the numbers.
 
 * SAC: a coalesced, power-of-two-padded service batch returns each
-  worker's rows bitwise-identical to that worker stepping the same act
-  core locally (per-row keys recomputed from the shipped base key);
+  worker's rows bitwise-identical to the same act core stepping those rows
+  with the same per-row keys AT THE SAME BUCKET WIDTH (see `_act_at_width`
+  for why the width is part of the contract), and equal to the worker's
+  own narrower call within float32 rounding;
 * DV3: same, with the (h, z, a) latents living service-side — session
   carry across steps, reset-mask re-initialization, and the idempotent
   retry path (a re-sent request answers from cache WITHOUT re-stepping
-  latents) all stay bitwise-equal to the worker-hosted player;
+  latents) all stay bitwise-equal to the player core at the same width;
 * e2e: a 2-worker SAC fleet run under ``fleet.act_mode=inference``
   produces a replay buffer BITWISE-IDENTICAL to the worker-hosted run's —
   the acceptance statement of the Sebulba refactor;
@@ -60,6 +62,41 @@ def test_take_batch_respects_width_and_mask_boundaries():
 
     # a request wider than every bucket rides alone, padded to its own pow-2
     assert svc._bucket(11) == 16
+
+
+def _act_at_width(core, params, obs, keys, n, width, state=None):
+    """`core.act` on ``n`` rows padded to ``width`` the way the service pads
+    (zero obs/key rows, init-state rows), sliced back to ``n``.
+
+    The bitwise contract is: same rows, same keys, same bucket width -> same
+    bits. It is NOT "whatever the width": a row's value depends only on
+    (params, obs[i], key[i], state[i]) — the vmap guarantees that — but XLA
+    vectorises elementwise math differently at different batch widths, so
+    the LAST BIT of a float can move with the width (jaxlib 0.9.0 CPU: SAC's
+    tanh gives -0.9983063 at width 8 and -0.9983062 at width 3; a TPU tiles
+    its matmuls by width too). Comparing against the core at another width
+    with array_equal would test the compiler, not the service, so the
+    width-independent comparison below uses a float32 rounding tolerance
+    instead, and the bitwise one runs the reference at the service's width."""
+    import jax
+
+    from sheeprl_tpu.fleet.act_service import _concat_rows, _pad_rows
+
+    keys = np.asarray(jax.device_get(keys))
+    keys = np.concatenate([keys, np.zeros((width - n,) + keys.shape[1:], keys.dtype)], axis=0)
+    if state is not None and width > n:
+        init_row = jax.tree.map(np.asarray, core.init_state(params, 1))
+        state = _concat_rows([jax.tree.map(np.asarray, state)] + [init_row] * (width - n))
+    actions, cat, new_state = core.act(params, _pad_rows(obs, n, width), keys, state=state)
+    cut = lambda x: None if x is None else np.asarray(x)[:n]
+    return cut(actions), cut(cat), (
+        None if new_state is None else jax.tree.map(cut, new_state)
+    )
+
+
+# one ulp of float32 near 1.0 is 6e-8 (1.2e-7 above it): two widths of the
+# same program may differ by a few of them after a matmul and a tanh
+_WIDTH_RTOL, _WIDTH_ATOL = 1e-5, 1e-6
 
 
 # ---------------------------------------------------------------------------
@@ -114,10 +151,17 @@ def test_sac_service_batch_bitwise_matches_worker_core():
 
     host = core.extract_params(params_np)  # the worker-mode program's params
     for w, n in layout.items():
-        ref, _, _ = core.act(host, obs[w], row_keys(keys[w], n))
         assert replies[w]["version"] == 5
-        assert np.array_equal(replies[w]["actions"], np.asarray(ref)), (
-            "service actions diverged from the worker-hosted core"
+        # bitwise at the service's width: coalescing with another worker's
+        # rows and the row's position in the bucket change nothing
+        ref8, _, _ = _act_at_width(core, host, obs[w], row_keys(keys[w], n), n, 8)
+        assert np.array_equal(replies[w]["actions"], ref8), (
+            "service actions diverged from the same core at the same bucket width"
+        )
+        # the worker-hosted call at its own width: same value to rounding
+        ref, _, _ = core.act(host, obs[w], row_keys(keys[w], n))
+        np.testing.assert_allclose(
+            replies[w]["actions"], np.asarray(ref), rtol=_WIDTH_RTOL, atol=_WIDTH_ATOL
         )
 
     # exact-width batch (4 rows -> bucket 4, no padding) is ALSO bitwise equal
@@ -231,17 +275,25 @@ def test_dv3_service_sessions_resets_and_idempotency():
         send(0, 2, k0, o0, 1, reset=[True, True]),
         send(1, 1, k1, o1, 1, reset=[True]),
     ])
-    ref0_a, ref0_cat, ref0_st = core.act(
-        host, o0, row_keys(np.asarray(k0), 2), state=core.init_state(host, 2)
+    # reference at the service's bucket width (4): see _act_at_width
+    ref0_a, ref0_cat, ref0_st = _act_at_width(
+        core, host, o0, row_keys(np.asarray(k0), 2), 2, 4, state=core.init_state(host, 2)
     )
-    ref1_a, _, ref1_st = core.act(
-        host, o1, row_keys(np.asarray(k1), 1), state=core.init_state(host, 1)
+    ref1_a, _, ref1_st = _act_at_width(
+        core, host, o1, row_keys(np.asarray(k1), 1), 1, 4, state=core.init_state(host, 1)
     )
-    assert np.array_equal(replies[0]["actions"], np.asarray(ref0_a))
-    assert np.array_equal(replies[0]["actions_cat"], np.asarray(ref0_cat))
-    assert np.array_equal(replies[1]["actions"], np.asarray(ref1_a))
+    assert np.array_equal(replies[0]["actions"], ref0_a)
+    assert np.array_equal(replies[0]["actions_cat"], ref0_cat)
+    assert np.array_equal(replies[1]["actions"], ref1_a)
     _state_rows_equal(svc, 0, ref0_st, 2)
     _state_rows_equal(svc, 1, ref1_st, 1)
+    # ... and the worker-hosted player's own width-2 call: same latents to
+    # float32 rounding (the discrete actions are then the same draw)
+    _, _, narrow_st = core.act(
+        host, o0, row_keys(np.asarray(k0), 2), state=core.init_state(host, 2)
+    )
+    for got, want in zip(jax.tree.leaves(ref0_st), jax.tree.leaves(narrow_st)):
+        np.testing.assert_allclose(got, np.asarray(want), rtol=_WIDTH_RTOL, atol=_WIDTH_ATOL)
 
     # -- step 2: worker 0 again, no reset — the service must act from the
     # latents it stored, exactly like the worker-hosted player's carry

@@ -44,3 +44,21 @@ def test_sequential_sample_native_equals_fallback(monkeypatch):
     for k in s_native:
         np.testing.assert_array_equal(s_native[k], s_fallback[k])
         assert s_native[k].shape == (2, 5, 4) + s_native[k].shape[3:]
+
+
+def test_native_build_is_keyed_by_the_source_and_lives_in_the_checkout():
+    """Built from replay_gather.cpp as it stands into <checkout>/.native_build
+    (git-ignored), under a name that changes with the source: a stale or
+    foreign .so beside the package is never what gets loaded."""
+    import hashlib
+    from pathlib import Path
+
+    status = native.native_status()
+    if not status["loaded"]:
+        pytest.skip(f"native toolchain unavailable: {status['error']}")
+    pkg = Path(native.__file__).resolve().parent
+    digest = hashlib.sha256((pkg / "replay_gather.cpp").read_bytes()).hexdigest()[:16]
+    path = Path(status["path"])
+    assert path.parent == pkg.parents[1] / ".native_build"
+    assert digest in path.name and status["error"] is None
+    assert not (pkg / "_replay_gather.so").exists()  # nothing is built into the package

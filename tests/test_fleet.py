@@ -231,7 +231,12 @@ def test_chaos_crash_and_hang_ledger_matches_overlap_engine():
             "fleet_chaos",
             extra=[
                 "algo.fleet.workers=2",
-                "fleet.hang_s=1.0",
+                # a slice gets fleet.hang_s from its last beat, and a
+                # worker's first policy slice holds a jit compile: at 1 s a
+                # loaded machine (xdist workers on shared cores) SIGKILLed
+                # healthy workers until both were quarantined. 5 s still
+                # catches the injected 60 s hang, and nothing else.
+                "fleet.hang_s=5.0",
                 "resilience.chaos.enabled=True",
                 "resilience.chaos.crash_at_step=50",
                 "resilience.chaos.crash_workers=[0]",

@@ -19,10 +19,7 @@ from sheeprl_tpu.utils.checkpoint import CheckpointManager, _fetch_global
 def _two_host_topology(monkeypatch, index: int = 1):
     monkeypatch.setattr(jax, "process_count", lambda: 2)
     monkeypatch.setattr(jax, "process_index", lambda: index)
-    # raising=False: jax<0.5 has no public is_initialized — mesh.py's
-    # distributed_is_initialized() prefers this attribute when present, so
-    # creating it here patches both old and new jax
-    monkeypatch.setattr(jax.distributed, "is_initialized", lambda: True, raising=False)
+    monkeypatch.setattr(jax.distributed, "is_initialized", lambda: True)
 
 
 def test_distributed_rank_gating_under_two_hosts(monkeypatch):

@@ -106,3 +106,39 @@ def test_batched_hfirst_gradient_parity():
     gr = jax.grad(lambda hf: jnp.sum(reference_sequence(feats, first, hf, w, scale, bias) ** 2))(h_first)
     assert gk.shape == (B, H)
     np.testing.assert_allclose(np.asarray(gk), np.asarray(gr), rtol=1e-4, atol=1e-5)
+
+
+@pytest.mark.parametrize(
+    "overrides, match",
+    [
+        (["algo.world_model.pallas_gru=True"], "decoupled_rssm"),
+        (
+            ["algo.world_model.decoupled_rssm=True", "algo.world_model.pallas_gru=True",
+             "fabric.precision=bf16-mixed"],
+            "32-true",
+        ),
+        (
+            ["algo.world_model.decoupled_rssm=True", "algo.world_model.pallas_gru=True",
+             "algo.world_model.recurrent_model.recurrent_state_size=4096",
+             "algo.world_model.recurrent_model.dense_units=1024"],
+            "VMEM",
+        ),
+        (["algo.world_model.decoupled_rssm=True", "algo.world_model.pallas_gru=maybe"], "interpret"),
+    ],
+    ids=["coupled", "mixed-precision", "too-wide", "unknown-mode"],
+)
+def test_pallas_gru_asked_for_where_it_cannot_run_is_an_error(overrides, match):
+    """It used to print "UNUSED" and train on the XLA scan."""
+    from dreamer_tiny import make_trainer
+
+    with pytest.raises(ValueError, match=match):
+        make_trainer(overrides)
+
+
+def test_pallas_gru_true_means_the_compiled_kernel_not_the_interpreter():
+    """`True` no longer turns into interpret mode off a TPU: on this CPU
+    backend the compiled kernel cannot be lowered, and the burst says so."""
+    from dreamer_tiny import burst_metrics
+
+    with pytest.raises(ValueError, match="[Ii]nterpret"):
+        burst_metrics(["algo.world_model.decoupled_rssm=True", "algo.world_model.pallas_gru=True"])

@@ -175,3 +175,51 @@ def test_every_algorithm_has_an_evaluation():
     assert len(algorithm_registry) >= 17, (
         f"reference parity needs all 17 entry points; got {sorted(algorithm_registry)}"
     )
+
+
+# ------------------------------------------------- compilation cache dir ----
+def _with_restored_cache_dir(fn):
+    import jax
+
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        return fn(jax)
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+
+
+def test_compilation_cache_default_is_a_fixed_path_in_the_checkout(monkeypatch):
+    """The directory is part of every cache key, so the default must not
+    depend on $HOME or the cwd: <checkout>/.xla_cache (git-ignored)."""
+    import os
+
+    from sheeprl_tpu.utils.utils import DEFAULT_XLA_CACHE_DIR, enable_compilation_cache
+
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    monkeypatch.delenv("SHEEPRL_NO_COMPILATION_CACHE", raising=False)
+    monkeypatch.setenv("HOME", "/nonexistent-home")
+    checkout = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert DEFAULT_XLA_CACHE_DIR == os.path.join(checkout, ".xla_cache")
+
+    def check(jax):
+        jax.config.update("jax_compilation_cache_dir", None)
+        enable_compilation_cache()
+        assert jax.config.jax_compilation_cache_dir == DEFAULT_XLA_CACHE_DIR
+
+    _with_restored_cache_dir(check)
+
+
+def test_compilation_cache_dir_from_the_environment_wins(monkeypatch, tmp_path):
+    """Where JAX_COMPILATION_CACHE_DIR is set, JAX already caches there (it
+    reads the variable itself) and the program sets no other directory."""
+    from sheeprl_tpu.utils.utils import enable_compilation_cache
+
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "placed-from-outside"))
+    monkeypatch.delenv("SHEEPRL_NO_COMPILATION_CACHE", raising=False)
+
+    def check(jax):
+        jax.config.update("jax_compilation_cache_dir", "what-jax-read-from-the-environment")
+        enable_compilation_cache()
+        assert jax.config.jax_compilation_cache_dir == "what-jax-read-from-the-environment"
+
+    _with_restored_cache_dir(check)
