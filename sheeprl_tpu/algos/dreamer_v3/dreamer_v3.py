@@ -167,101 +167,105 @@ def make_train_fn(
 
         # ---------------- world model ------------------------------------
         def wm_loss_fn(wm_params):
-            embedded = wm_apply(wm_params, WorldModel.embed, batch_obs)  # [T, B, E]
+            with jax.named_scope("wm_encoder"):
+                embedded = wm_apply(wm_params, WorldModel.embed, batch_obs)  # [T, B, E]
 
-            if decoupled:
-                # DecoupledRSSM (reference dreamer_v3.py:115-129): posterior
-                # logits for the WHOLE sequence in one time-parallel MLP —
-                # only h + prior stay sequential. The posterior driving the
-                # recurrent model at step i is the step i-1 sample (zeros at
-                # i=0, reference :118-121).
-                post_logits = wm_apply(wm_params, WorldModel.representation_logits, embedded)
-                zs = compute_stochastic_state(
-                    post_logits, int(wm_cfg.discrete_size), k_dyn
-                ).reshape(T, B, stoch_flat)
-                z_prev = jnp.concatenate([jnp.zeros_like(zs[:1]), zs[:-1]], axis=0)
+            with jax.named_scope("wm_rssm"):
+                if decoupled:
+                    # DecoupledRSSM (reference dreamer_v3.py:115-129): posterior
+                    # logits for the WHOLE sequence in one time-parallel MLP —
+                    # only h + prior stay sequential. The posterior driving the
+                    # recurrent model at step i is the step i-1 sample (zeros at
+                    # i=0, reference :118-121).
+                    post_logits = wm_apply(wm_params, WorldModel.representation_logits, embedded)
+                    zs = compute_stochastic_state(
+                        post_logits, int(wm_cfg.discrete_size), k_dyn
+                    ).reshape(T, B, stoch_flat)
+                    z_prev = jnp.concatenate([jnp.zeros_like(zs[:1]), zs[:-1]], axis=0)
 
-                if use_pallas:
-                    # everything around the recurrence is time-parallel: the
-                    # is_first masking of (z, a), the pre-GRU feature matmul
-                    # and the prior head all batch over T; only the GRU runs
-                    # sequentially — inside the VMEM-resident Pallas kernel
-                    h0_row, z0_row = wm_apply(
-                        wm_params, WorldModel.initial_states, (B,)
-                    )
-                    z_in = (1 - is_first) * z_prev + is_first * z0_row[None]
-                    a_in = (1 - is_first) * batch_actions
-                    feats = wm_apply(
-                        wm_params,
-                        WorldModel.recurrent_features,
-                        jnp.concatenate([z_in, a_in], -1),
-                    )
-                    gru_p = wm_params["rssm"]["recurrent_model"]["gru"]
-                    ln_p = gru_p["LayerNorm_0"]["LayerNorm_0"]
-                    hs = pg.gru_sequence(
-                        feats,
-                        is_first,
-                        h0_row,
-                        gru_p["fused"]["kernel"],
-                        ln_p["scale"],
-                        ln_p["bias"],
-                        pallas_interpret,
-                    )
-                    prior_logits = wm_apply(wm_params, WorldModel.transition_logits, hs)
+                    if use_pallas:
+                        # everything around the recurrence is time-parallel: the
+                        # is_first masking of (z, a), the pre-GRU feature matmul
+                        # and the prior head all batch over T; only the GRU runs
+                        # sequentially — inside the VMEM-resident Pallas kernel
+                        h0_row, z0_row = wm_apply(
+                            wm_params, WorldModel.initial_states, (B,)
+                        )
+                        z_in = (1 - is_first) * z_prev + is_first * z0_row[None]
+                        a_in = (1 - is_first) * batch_actions
+                        feats = wm_apply(
+                            wm_params,
+                            WorldModel.recurrent_features,
+                            jnp.concatenate([z_in, a_in], -1),
+                        )
+                        gru_p = wm_params["rssm"]["recurrent_model"]["gru"]
+                        ln_p = gru_p["LayerNorm_0"]["LayerNorm_0"]
+                        hs = pg.gru_sequence(
+                            feats,
+                            is_first,
+                            h0_row,
+                            gru_p["fused"]["kernel"],
+                            ln_p["scale"],
+                            ln_p["bias"],
+                            pallas_interpret,
+                        )
+                        prior_logits = wm_apply(wm_params, WorldModel.transition_logits, hs)
+                    else:
+
+                        def dyn_step_dec(h, xs):
+                            z_in, a, first = xs
+                            h, prior_logits = wm_apply(
+                                wm_params, WorldModel.dynamic_decoupled, z_in, h, a, first
+                            )
+                            return h, (h, prior_logits)
+
+                        h0 = jnp.zeros((B, R))
+                        _, (hs, prior_logits) = jax.lax.scan(
+                            dyn_step_dec, h0, (z_prev, batch_actions, is_first)
+                        )
                 else:
 
-                    def dyn_step_dec(h, xs):
-                        z_in, a, first = xs
-                        h, prior_logits = wm_apply(
-                            wm_params, WorldModel.dynamic_decoupled, z_in, h, a, first
+                    def dyn_step(carry, xs):
+                        h, z = carry
+                        a, e, first, k = xs
+                        h, z, post_logits, prior_logits = wm_apply(
+                            wm_params, WorldModel.dynamic, z, h, a, e, first, k
                         )
-                        return h, (h, prior_logits)
+                        return (h, z), (h, z, post_logits, prior_logits)
 
+                    keys = jax.random.split(k_dyn, T)
                     h0 = jnp.zeros((B, R))
-                    _, (hs, prior_logits) = jax.lax.scan(
-                        dyn_step_dec, h0, (z_prev, batch_actions, is_first)
+                    z0 = jnp.zeros((B, stoch_flat))
+                    _, (hs, zs, post_logits, prior_logits) = jax.lax.scan(
+                        dyn_step, (h0, z0), (batch_actions, embedded, is_first, keys)
                     )
-            else:
-
-                def dyn_step(carry, xs):
-                    h, z = carry
-                    a, e, first, k = xs
-                    h, z, post_logits, prior_logits = wm_apply(
-                        wm_params, WorldModel.dynamic, z, h, a, e, first, k
-                    )
-                    return (h, z), (h, z, post_logits, prior_logits)
-
-                keys = jax.random.split(k_dyn, T)
-                h0 = jnp.zeros((B, R))
-                z0 = jnp.zeros((B, stoch_flat))
-                _, (hs, zs, post_logits, prior_logits) = jax.lax.scan(
-                    dyn_step, (h0, z0), (batch_actions, embedded, is_first, keys)
-                )
             latents = jnp.concatenate([zs, hs], axis=-1)
-            po, obs_targets = decode_obs_dists(
-                wm_apply, wm_params, WorldModel, latents, batch_obs, cnn_keys, mlp_keys, phase_obs_loss
-            )
-            pr = TwoHotEncodingDistribution(wm_apply(wm_params, WorldModel.reward, latents), dims=1)
-            pc = Independent(
-                BernoulliSafeMode(logits=wm_apply(wm_params, WorldModel.cont, latents)), 1
-            )
-            continues_targets = 1 - batch["terminated"]
-            S, D = int(wm_cfg.stochastic_size), int(wm_cfg.discrete_size)
-            rec_loss, kl, state_loss, reward_loss, observation_loss, continue_loss = reconstruction_loss(
-                po,
-                obs_targets,
-                pr,
-                batch["rewards"],
-                prior_logits.reshape(T, B, S, D),
-                post_logits.reshape(T, B, S, D),
-                float(wm_cfg.kl_dynamic),
-                float(wm_cfg.kl_representation),
-                float(wm_cfg.kl_free_nats),
-                float(wm_cfg.kl_regularizer),
-                pc,
-                continues_targets,
-                float(wm_cfg.continue_scale_factor),
-            )
+            with jax.named_scope("wm_decoder"):
+                po, obs_targets = decode_obs_dists(
+                    wm_apply, wm_params, WorldModel, latents, batch_obs, cnn_keys, mlp_keys, phase_obs_loss
+                )
+            with jax.named_scope("wm_heads"):
+                pr = TwoHotEncodingDistribution(wm_apply(wm_params, WorldModel.reward, latents), dims=1)
+                pc = Independent(
+                    BernoulliSafeMode(logits=wm_apply(wm_params, WorldModel.cont, latents)), 1
+                )
+                continues_targets = 1 - batch["terminated"]
+                S, D = int(wm_cfg.stochastic_size), int(wm_cfg.discrete_size)
+                rec_loss, kl, state_loss, reward_loss, observation_loss, continue_loss = reconstruction_loss(
+                    po,
+                    obs_targets,
+                    pr,
+                    batch["rewards"],
+                    prior_logits.reshape(T, B, S, D),
+                    post_logits.reshape(T, B, S, D),
+                    float(wm_cfg.kl_dynamic),
+                    float(wm_cfg.kl_representation),
+                    float(wm_cfg.kl_free_nats),
+                    float(wm_cfg.kl_regularizer),
+                    pc,
+                    continues_targets,
+                    float(wm_cfg.continue_scale_factor),
+                )
             aux = {
                 "zs": zs,
                 "hs": hs,
@@ -277,8 +281,9 @@ def make_train_fn(
             return rec_loss, aux
 
         (wm_loss, wm_aux), wm_grads = jax.value_and_grad(wm_loss_fn, has_aux=True)(params["wm"])
-        updates, opt_states["wm"] = txs["wm"].update(wm_grads, opt_states["wm"], params["wm"])
-        params["wm"] = optax.apply_updates(params["wm"], updates)
+        with jax.named_scope("optimizer"):
+            updates, opt_states["wm"] = txs["wm"].update(wm_grads, opt_states["wm"], params["wm"])
+            params["wm"] = optax.apply_updates(params["wm"], updates)
 
         # ---------------- behaviour: actor -------------------------------
         imagined_prior0 = jax.lax.stop_gradient(wm_aux["zs"]).reshape(T * B, stoch_flat)
@@ -303,68 +308,71 @@ def make_train_fn(
                 return (z, h, a), (state, a)
 
             keys = jax.random.split(key, horizon)
-            _, (states, actions) = jax.lax.scan(img_step, (imagined_prior0, recurrent0, a0), keys)
+            with jax.named_scope("imagination"):
+                _, (states, actions) = jax.lax.scan(img_step, (imagined_prior0, recurrent0, a0), keys)
             trajectories = jnp.concatenate([state0[None], states], axis=0)  # [H+1, TB, L]
             imagined_actions = jnp.concatenate([a0[None], actions], axis=0)
             return trajectories, imagined_actions
 
         def actor_loss_fn(actor_params, moments):
-            trajectories, imagined_actions = rollout(actor_params, k_img)
-            values = TwoHotEncodingDistribution(
-                critic_apply(params["critic"], trajectories), dims=1
-            ).mean
-            rewards_img = TwoHotEncodingDistribution(
-                wm_apply(params["wm"], WorldModel.reward, trajectories), dims=1
-            ).mean
-            continues = Independent(
-                BernoulliSafeMode(logits=wm_apply(params["wm"], WorldModel.cont, trajectories)), 1
-            ).mode
-            continues = jnp.concatenate([true_continue0[None], continues[1:]], axis=0)
-            lv = lambda_values_op(rewards_img[1:], values[1:], continues[1:] * gamma, lmbda)
-            discount = jax.lax.stop_gradient(
-                unrolled_cumprod(continues * gamma) / gamma
-            )
-            moments, offset, invscale = update_moments(
-                moments,
-                lv,
-                float(moments_cfg.decay),
-                float(moments_cfg.max),
-                float(moments_cfg.percentile.low),
-                float(moments_cfg.percentile.high),
-            )
-            baseline = values[:-1]
-            normed_lv = (lv - offset) / invscale
-            normed_baseline = (baseline - offset) / invscale
-            advantage = normed_lv - normed_baseline
-            pre_dist = actor_apply(actor_params, jax.lax.stop_gradient(trajectories))
-            from .agent import actor_dists
+            with jax.named_scope("actor"):
+                trajectories, imagined_actions = rollout(actor_params, k_img)
+                values = TwoHotEncodingDistribution(
+                    critic_apply(params["critic"], trajectories), dims=1
+                ).mean
+                rewards_img = TwoHotEncodingDistribution(
+                    wm_apply(params["wm"], WorldModel.reward, trajectories), dims=1
+                ).mean
+                continues = Independent(
+                    BernoulliSafeMode(logits=wm_apply(params["wm"], WorldModel.cont, trajectories)), 1
+                ).mode
+                continues = jnp.concatenate([true_continue0[None], continues[1:]], axis=0)
+                lv = lambda_values_op(rewards_img[1:], values[1:], continues[1:] * gamma, lmbda)
+                discount = jax.lax.stop_gradient(
+                    unrolled_cumprod(continues * gamma) / gamma
+                )
+                moments, offset, invscale = update_moments(
+                    moments,
+                    lv,
+                    float(moments_cfg.decay),
+                    float(moments_cfg.max),
+                    float(moments_cfg.percentile.low),
+                    float(moments_cfg.percentile.high),
+                )
+                baseline = values[:-1]
+                normed_lv = (lv - offset) / invscale
+                normed_baseline = (baseline - offset) / invscale
+                advantage = normed_lv - normed_baseline
+                pre_dist = actor_apply(actor_params, jax.lax.stop_gradient(trajectories))
+                from .agent import actor_dists
 
-            dists = actor_dists(actor, pre_dist)
-            if is_continuous:
-                objective = advantage
-            else:
-                logprobs = []
-                start = 0
-                for d, adim in zip(dists, actions_dim):
-                    act = jax.lax.stop_gradient(imagined_actions[..., start : start + adim])
-                    logprobs.append(d.log_prob(act)[..., None][:-1])
-                    start += adim
-                objective = sum(logprobs) * jax.lax.stop_gradient(advantage)
-            entropy = ent_coef * sum(d.entropy() for d in dists)[..., None]
-            policy_loss = -jnp.mean(discount[:-1] * (objective + entropy[:-1]))
-            aux = {
-                "trajectories": jax.lax.stop_gradient(trajectories),
-                "lambda_values": jax.lax.stop_gradient(lv),
-                "discount": discount,
-                "moments": jax.tree.map(jax.lax.stop_gradient, moments),
-            }
+                dists = actor_dists(actor, pre_dist)
+                if is_continuous:
+                    objective = advantage
+                else:
+                    logprobs = []
+                    start = 0
+                    for d, adim in zip(dists, actions_dim):
+                        act = jax.lax.stop_gradient(imagined_actions[..., start : start + adim])
+                        logprobs.append(d.log_prob(act)[..., None][:-1])
+                        start += adim
+                    objective = sum(logprobs) * jax.lax.stop_gradient(advantage)
+                entropy = ent_coef * sum(d.entropy() for d in dists)[..., None]
+                policy_loss = -jnp.mean(discount[:-1] * (objective + entropy[:-1]))
+                aux = {
+                    "trajectories": jax.lax.stop_gradient(trajectories),
+                    "lambda_values": jax.lax.stop_gradient(lv),
+                    "discount": discount,
+                    "moments": jax.tree.map(jax.lax.stop_gradient, moments),
+                }
             return policy_loss, aux
 
         (policy_loss, a_aux), a_grads = jax.value_and_grad(actor_loss_fn, has_aux=True)(
             params["actor"], moments
         )
-        updates, opt_states["actor"] = txs["actor"].update(a_grads, opt_states["actor"], params["actor"])
-        params["actor"] = optax.apply_updates(params["actor"], updates)
+        with jax.named_scope("optimizer"):
+            updates, opt_states["actor"] = txs["actor"].update(a_grads, opt_states["actor"], params["actor"])
+            params["actor"] = optax.apply_updates(params["actor"], updates)
         moments = a_aux["moments"]
 
         # ---------------- critic ------------------------------------------
@@ -373,27 +381,30 @@ def make_train_fn(
         discount = a_aux["discount"]
 
         def critic_loss_fn(critic_params):
-            qv = TwoHotEncodingDistribution(
-                critic_apply(critic_params, traj_sg[:-1]), dims=1
-            )
-            target_values = TwoHotEncodingDistribution(
-                critic_apply(params["target_critic"], traj_sg[:-1]), dims=1
-            ).mean
-            loss = -qv.log_prob(lv_sg) - qv.log_prob(jax.lax.stop_gradient(target_values))
-            return jnp.mean(loss * discount[:-1, ..., 0])
+            with jax.named_scope("critic"):
+                qv = TwoHotEncodingDistribution(
+                    critic_apply(critic_params, traj_sg[:-1]), dims=1
+                )
+                target_values = TwoHotEncodingDistribution(
+                    critic_apply(params["target_critic"], traj_sg[:-1]), dims=1
+                ).mean
+                loss = -qv.log_prob(lv_sg) - qv.log_prob(jax.lax.stop_gradient(target_values))
+                return jnp.mean(loss * discount[:-1, ..., 0])
 
         value_loss, c_grads = jax.value_and_grad(critic_loss_fn)(params["critic"])
-        updates, opt_states["critic"] = txs["critic"].update(c_grads, opt_states["critic"], params["critic"])
-        params["critic"] = optax.apply_updates(params["critic"], updates)
+        with jax.named_scope("optimizer"):
+            updates, opt_states["critic"] = txs["critic"].update(c_grads, opt_states["critic"], params["critic"])
+            params["critic"] = optax.apply_updates(params["critic"], updates)
 
         # target critic EMA (reference dreamer_v3.py:674-680)
         step = opt_states["step"] + 1
         do_t = (step % target_freq) == 0
-        params["target_critic"] = jax.tree.map(
-            lambda t, s: jnp.where(do_t, (1 - tau) * t + tau * s, t),
-            params["target_critic"],
-            params["critic"],
-        )
+        with jax.named_scope("critic"):
+            params["target_critic"] = jax.tree.map(
+                lambda t, s: jnp.where(do_t, (1 - tau) * t + tau * s, t),
+                params["target_critic"],
+                params["critic"],
+            )
         opt_states["step"] = step
 
         S, D = int(wm_cfg.stochastic_size), int(wm_cfg.discrete_size)
@@ -678,23 +689,33 @@ def main(dist: Distributed, cfg: Config) -> None:
                     oh.append(np.eye(adim, dtype=np.float32)[acts2d[:, j]])
                 actions_np = np.concatenate(oh, axis=-1)
         else:
-            host_obs = prepare_obs(obs, cnn_keys, mlp_keys, num_envs)
-            env_actions, actions_cat, player_state, player_key = player_step_fn(
-                mirror.current(), host_obs, player_state, player_key,
-                action_mask=extract_masks(obs, num_envs),
-            )
-            actions_np = np.asarray(actions_cat)
-            actions_env = np.asarray(env_actions)
+            with telem.span("Player/act"):
+                host_obs = prepare_obs(obs, cnn_keys, mlp_keys, num_envs)
+                env_actions, actions_cat, player_state, player_key = player_step_fn(
+                    mirror.current(), host_obs, player_state, player_key,
+                    action_mask=extract_masks(obs, num_envs),
+                )
+                actions_np = np.asarray(actions_cat)
+                actions_env = np.asarray(env_actions)
             if is_continuous:
                 actions_env = actions_env.reshape(num_envs, -1)
             elif not is_multidiscrete:
                 actions_env = actions_env.reshape(num_envs)
 
-        step_data["actions"] = actions_np.reshape(1, num_envs, -1)
-        sink.add(step_data, validate_args=cfg.buffer.validate_args)
+        with telem.span("Player/record"):
+            step_data["actions"] = actions_np.reshape(1, num_envs, -1)
+            sink.add(step_data, validate_args=cfg.buffer.validate_args)
 
-        next_obs, rewards, terminated, truncated, info = envs.step(actions_env)
+        with telem.span("Player/env_step"):
+            next_obs, rewards, terminated, truncated, info = envs.step(actions_env)
         p_step += num_envs
+        with telem.span("Player/record"):
+            record_step(sink, next_obs, rewards, terminated, truncated, info)
+
+    def record_step(sink, next_obs, rewards, terminated, truncated, info) -> None:
+        """The rows one vector env step leaves behind, and the reset
+        bookkeeping of the envs that ended in it."""
+        nonlocal obs, player_state
         dones = np.logical_or(terminated, truncated)
 
         for ep_rew, ep_len in episode_stats(info):
@@ -752,11 +773,12 @@ def main(dist: Distributed, cfg: Config) -> None:
     def flush_logs() -> None:
         nonlocal last_log
         if policy_step - last_log >= cfg.metric.log_every or cfg.dry_run:
-            for m in pending_metrics:  # host-sync deferred to log cadence
-                for k, v in m.items():
-                    aggregator.update(k, np.asarray(v))
-            pending_metrics.clear()
-            telem.log(policy_step)
+            with telem.span("Time/log_flush"):
+                for m in pending_metrics:  # host-sync deferred to log cadence
+                    for k, v in m.items():
+                        aggregator.update(k, np.asarray(v))
+                pending_metrics.clear()
+                telem.log(policy_step)
             last_log = policy_step
 
     def maybe_checkpoint() -> None:
@@ -765,7 +787,8 @@ def main(dist: Distributed, cfg: Config) -> None:
             cfg.checkpoint.every > 0 and policy_step - last_checkpoint >= cfg.checkpoint.every
         ) or cfg.dry_run or policy_step >= total_steps:
             last_checkpoint = policy_step
-            ckpt.save(policy_step, _ckpt_state())
+            with telem.span("Time/checkpoint"):
+                ckpt.save(policy_step, _ckpt_state())
 
     engine = OverlapEngine.setup(
         cfg, telem, guard, total_steps=total_steps, initial_step=policy_step
@@ -783,6 +806,7 @@ def main(dist: Distributed, cfg: Config) -> None:
         fleet.start("sheeprl_tpu.fleet.programs:dreamer_v3_program", num_envs, cfg)
         fleet.publish(mirror.current())
         stopped = False
+        bursts = 0  # train calls so far: the `burst` of Time/train_time
         while policy_step < total_steps:
             telem.tick(policy_step)
             if guard.stop_reached(policy_step, total_steps, None, save=False):
@@ -792,14 +816,16 @@ def main(dist: Distributed, cfg: Config) -> None:
                 rnd = fleet.take_round(policy_step)
             if rnd is None:
                 break
-            fleet.apply_sliced(rnd, rb, aggregator)
+            with telem.span("Time/learner_apply", env_steps=rnd.env_steps, packets=1):
+                fleet.apply_sliced(rnd, rb, aggregator)
             policy_step += rnd.env_steps
             g = 0
             if policy_step >= learning_starts:
                 g = ratio(policy_step / dist.world_size)
                 telem.record_grad_steps(g)
             if g > 0:
-                with telem.span("Time/train_time"):
+                bursts += 1
+                with telem.span("Time/train_time", grad_steps=g, burst=bursts):
                     batches = prefetch.take(g)  # [G, T, B, ...]
                     root_key, sub = jax.random.split(root_key)
                     params, opt_states, moments, metrics = train(
@@ -813,7 +839,8 @@ def main(dist: Distributed, cfg: Config) -> None:
             if learning_starts <= policy_step < total_steps:
                 # same guard as the serial loop: staging before training can
                 # start would pay a host sample that take() can never use
-                prefetch.stage(ratio.peek((policy_step + rnd.env_steps) / dist.world_size))
+                with telem.span("Time/replay_stage"):
+                    prefetch.stage(ratio.peek((policy_step + rnd.env_steps) / dist.world_size))
             flush_logs()
             maybe_checkpoint()
         policy_step += fleet.shutdown(lambda r: fleet.apply_sliced(r, rb, aggregator))
@@ -821,10 +848,9 @@ def main(dist: Distributed, cfg: Config) -> None:
             ckpt.save(policy_step, _ckpt_state())
     elif engine.enabled:
         # ---- overlapped player/learner loop (engine/overlap.py) ----------
-        def play() -> Packet:
+        def play() -> Packet:  # the engine times it under Time/env_interaction_time
             rec = RecordingSink()
-            with telem.span("Time/env_interaction_time"):
-                interact(rec)
+            interact(rec)
             return Packet(rec, num_envs)
 
         engine.start(play)
@@ -840,13 +866,16 @@ def main(dist: Distributed, cfg: Config) -> None:
             # ack packets in FIFO order, feeding the Ratio ledger exactly as
             # the serial loop would (one call per num_envs env steps)
             gs = []
-            for pkt in packets:
-                pkt.apply(rb, aggregator)
-                policy_step += pkt.env_steps
-                if policy_step >= learning_starts:
-                    g = ratio(policy_step / dist.world_size)
-                    telem.record_grad_steps(g)
-                    gs.append(g)
+            with telem.span(
+                "Time/learner_apply", env_steps=sum(pkt.env_steps for pkt in packets), packets=len(packets)
+            ):
+                for pkt in packets:
+                    pkt.apply(rb, aggregator)
+                    policy_step += pkt.env_steps
+                    if policy_step >= learning_starts:
+                        g = ratio(policy_step / dist.world_size)
+                        telem.record_grad_steps(g)
+                        gs.append(g)
             if _progress and policy_step % _progress < num_envs * len(packets):
                 print(
                     f"[progress] step={policy_step} t={time.perf_counter() - _t0:.1f}s",
@@ -860,7 +889,7 @@ def main(dist: Distributed, cfg: Config) -> None:
             for i, g in enumerate(gs):
                 if g <= 0:
                     continue
-                with telem.span("Time/train_time"):
+                with telem.span("Time/train_time", grad_steps=g, burst=engine.burst):
                     bursting = True
                     batches = prefetch.take(g)  # [G, T, B, ...]
                     root_key, sub = jax.random.split(root_key)
@@ -871,13 +900,15 @@ def main(dist: Distributed, cfg: Config) -> None:
                     pending_metrics.append(metrics)
                 nxt = next((x for x in gs[i + 1 :] if x > 0), 0)
                 if nxt > 0:
-                    prefetch.stage(nxt)
+                    with telem.span("Time/replay_stage"):
+                        prefetch.stage(nxt)
             if bursting:
                 mirror.refresh({"wm": params["wm"], "actor": params["actor"]})
                 run_info.mark_steady(policy_step, sync=lambda: jax.block_until_ready(metrics))
             engine.published()  # release take()'s claim every iteration
             if policy_step < total_steps:
-                prefetch.stage(ratio.peek((policy_step + num_envs) / dist.world_size))
+                with telem.span("Time/replay_stage"):
+                    prefetch.stage(ratio.peek((policy_step + num_envs) / dist.world_size))
             flush_logs()
             maybe_checkpoint()
         # drain: player stops feeding, queued transitions land in the buffer
@@ -889,6 +920,7 @@ def main(dist: Distributed, cfg: Config) -> None:
     else:
         # ---- serial loop (reference semantics) ----------------------------
         sink = BufferOpSink(rb, aggregator)
+        bursts = 0  # train calls so far: the `burst` of Time/train_time
         while policy_step < total_steps:
             telem.tick(policy_step)
             if guard.stop_reached(policy_step, total_steps, _ckpt_state):
@@ -899,7 +931,7 @@ def main(dist: Distributed, cfg: Config) -> None:
                     file=sys.stderr,
                     flush=True,
                 )
-            with telem.span("Time/env_interaction_time"):
+            with telem.span("Time/env_interaction_time", env_steps=num_envs, version=bursts):
                 interact(sink)
             policy_step = p_step
 
@@ -907,13 +939,10 @@ def main(dist: Distributed, cfg: Config) -> None:
                 per_rank_gradient_steps = ratio(policy_step / dist.world_size)
                 telem.record_grad_steps(per_rank_gradient_steps)
                 if per_rank_gradient_steps > 0:
-                    _trace = os.environ.get("SHEEPRL_TPU_TRACE")
-                    with telem.span("Time/train_time"):
-                        _tt = time.perf_counter()
+                    bursts += 1
+                    with telem.span("Time/train_time", grad_steps=per_rank_gradient_steps, burst=bursts):
                         batches = prefetch.take(per_rank_gradient_steps)  # [G, T, B, ...]
-                        _t_take = time.perf_counter()
                         root_key, sub = jax.random.split(root_key)
-                        _t_split = time.perf_counter()
                         params, opt_states, moments, metrics = train(
                             params,
                             opt_states,
@@ -921,34 +950,18 @@ def main(dist: Distributed, cfg: Config) -> None:
                             batches,
                             jax.random.split(sub, per_rank_gradient_steps),
                         )
-                        _t_disp = time.perf_counter()
                     # metrics stay on device until log time — no per-step host sync
                     if not MetricAggregator.disabled:
                         # device refs held until the log-cadence host sync;
                         # skip entirely when metrics are off (bench legs)
                         pending_metrics.append(metrics)
-                    if _trace:
-                        jax.tree.leaves(params)[0].block_until_ready()
-                        _t_exec = time.perf_counter()
                     mirror.refresh({"wm": params["wm"], "actor": params["actor"]})
-                    if _trace:
-                        jax.tree.leaves(mirror._pending or mirror.params)[0].block_until_ready()
-                        _t_done = time.perf_counter()
-                        print(
-                            f"[trace] burst G={per_rank_gradient_steps} take={_t_take - _tt:.3f}"
-                            f" split={_t_split - _t_take:.3f} dispatch={_t_disp - _t_split:.3f}"
-                            f" exec={_t_exec - _t_disp:.3f} refresh={_t_done - _t_exec:.3f}",
-                            file=sys.stderr,
-                            flush=True,
-                        )
                     run_info.mark_steady(policy_step, sync=lambda: jax.block_until_ready(metrics))
                 if policy_step < total_steps:
                     # overlap the next sample + host→HBM transfer with the train
                     # step the device is computing right now
-                    _tt = time.perf_counter()
-                    prefetch.stage(ratio.peek((policy_step + num_envs) / dist.world_size))
-                    if per_rank_gradient_steps > 0 and os.environ.get("SHEEPRL_TPU_TRACE"):
-                        print(f"[trace] stage={time.perf_counter() - _tt:.3f}", file=sys.stderr, flush=True)
+                    with telem.span("Time/replay_stage"):
+                        prefetch.stage(ratio.peek((policy_step + num_envs) / dist.world_size))
 
             flush_logs()
             maybe_checkpoint()

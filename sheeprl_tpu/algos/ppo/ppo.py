@@ -465,11 +465,10 @@ def main(dist: Distributed, cfg: Config) -> None:
         ]
         buf_idx = [0]
 
-        def play() -> Packet:
+        def play() -> Packet:  # the engine times it under Time/env_interaction_time
             buf = bufs[buf_idx[0] % len(bufs)]
             buf_idx[0] += 1
-            with telem.span("Time/env_interaction_time"):
-                local, next_value, ep_stats = rollout(buf)
+            local, next_value, ep_stats = rollout(buf)
             return Packet((local, np.asarray(next_value), ep_stats), policy_steps_per_iter)
 
         engine.start(play)

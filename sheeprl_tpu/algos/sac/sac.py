@@ -372,10 +372,9 @@ def main(dist: Distributed, cfg: Config) -> None:
             ckpt.save(policy_step, _ckpt_state())
     elif engine.enabled:
         # ---- overlapped player/learner loop (engine/overlap.py) ----------
-        def play() -> Packet:
+        def play() -> Packet:  # the engine times it under Time/env_interaction_time
             rec = RecordingSink()
-            with telem.span("Time/env_interaction_time"):
-                interact(rec)
+            interact(rec)
             return Packet(rec, num_envs)
 
         engine.start(play)

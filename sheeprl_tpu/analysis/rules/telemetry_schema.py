@@ -27,6 +27,10 @@ name (``f"worker_{i}"``, ``"stage_" + name``, ``"%s" % x``, ``.format(…)``)
 is an unbounded label set, so the rule flags it at ``emit({"event": …})``
 and ``span(…)`` call sites. A plain variable passed through is allowed —
 the binding site is where the literal lives.
+
+Span registry: a literal span name under one of ``SPAN_PREFIXES`` and the
+counts it carries (keyword arguments of ``span(…)`` / ``Span(…)``) have to be
+in ``SPAN_SCHEMAS``: the capture's readers and howto/telemetry.md key on them.
 """
 from __future__ import annotations
 
@@ -74,9 +78,10 @@ class TelemetrySchemaRule(Rule):
         explodes label cardinality."""
         fn = call.func
         name = fn.attr if isinstance(fn, ast.Attribute) else getattr(fn, "id", "")
-        if name != "span" or not call.args:
+        if name not in ("span", "_span", "Span") or not call.args:
             return
-        if _dynamic_string(call.args[0]):
+        yield from self._check_span_registry(ctx, call)
+        if name == "span" and _dynamic_string(call.args[0]):
             yield Finding(
                 self.rule_id,
                 str(ctx.path),
@@ -89,6 +94,32 @@ class TelemetrySchemaRule(Rule):
                     "event field (worker=..., seq=...) instead"
                 ),
             )
+
+    def _check_span_registry(self, ctx: ModuleContext, call: ast.Call) -> Iterator[Finding]:
+        from ...telemetry.schema import SPAN_PREFIXES, SPAN_SCHEMAS
+
+        arg = call.args[0]
+        if not (isinstance(arg, ast.Constant) and isinstance(arg.value, str) and arg.value.startswith(SPAN_PREFIXES)):
+            return
+        counts = SPAN_SCHEMAS.get(arg.value)
+        if counts is None:
+            yield Finding(
+                self.rule_id,
+                str(ctx.path),
+                call.lineno,
+                f"span {arg.value!r} is not declared in telemetry/schema.py SPAN_SCHEMAS",
+                remediation="declare the span and its counts (the registry moves with the call site)",
+            )
+            return
+        for kw in call.keywords:
+            if kw.arg is not None and kw.arg not in counts + ("tracker", "enabled", "annotate"):
+                yield Finding(
+                    self.rule_id,
+                    str(ctx.path),
+                    call.lineno,
+                    f"span {arg.value!r}: count {kw.arg!r} is not declared in SPAN_SCHEMAS",
+                    remediation="declare the count in telemetry/schema.py SPAN_SCHEMAS",
+                )
 
     # -- per-function linear walk -----------------------------------------
     def _check_function(self, ctx: ModuleContext, fn: ast.FunctionDef) -> Iterator[Finding]:

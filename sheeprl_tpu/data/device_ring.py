@@ -46,6 +46,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..telemetry.spans import Span
 from .buffers import EnvIndependentReplayBuffer, SequentialReplayBuffer
 from .prefetch import StagedPrefetcher
 
@@ -185,32 +186,34 @@ class DeviceRingPrefetcher(_StagedGather):
         rows = self._pending_rows()
         if not rows:
             return
-        size = self._rb.buffer_size
-        n = len(rows)
-        padded = -(-n // self._bucket) * self._bucket
-        t_idx = np.full((padded,), size, dtype=np.int32)  # size ⇒ mode="drop"
-        e_idx = np.zeros((padded,), dtype=np.int32)
-        t_idx[:n] = [r for _, r in rows]
-        e_idx[:n] = [e for e, _ in rows]
-        # one fancy-indexed copy per (env, key) — a resume backlog can be the
-        # whole buffer, where a per-row python loop would stall startup
-        by_env: Dict[int, List[int]] = {}
-        for i, (e, _) in enumerate(rows):
-            by_env.setdefault(e, []).append(i)
-        data: Dict[str, np.ndarray] = {}
-        for k in self._ring:
-            item = self._rb.buffer[0][k].shape[2:]
-            out = np.zeros((padded,) + item, dtype=self._rb.buffer[0][k].dtype)
-            for e, slots in by_env.items():
-                out[slots] = self._rb.buffer[e][k][t_idx[slots], 0]
-            data[k] = out
-        dev = self._device
-        self._ring = _scatter_rows(
-            self._ring,
-            {k: jax.device_put(v, dev) for k, v in data.items()},
-            jax.device_put(t_idx, dev),
-            jax.device_put(e_idx, dev),
-        )
+        with Span("Time/replay_sync", rows=len(rows)) as span:
+            size = self._rb.buffer_size
+            n = len(rows)
+            padded = -(-n // self._bucket) * self._bucket
+            t_idx = np.full((padded,), size, dtype=np.int32)  # size ⇒ mode="drop"
+            e_idx = np.zeros((padded,), dtype=np.int32)
+            t_idx[:n] = [r for _, r in rows]
+            e_idx[:n] = [e for e, _ in rows]
+            # one fancy-indexed copy per (env, key) — a resume backlog can be the
+            # whole buffer, where a per-row python loop would stall startup
+            by_env: Dict[int, List[int]] = {}
+            for i, (e, _) in enumerate(rows):
+                by_env.setdefault(e, []).append(i)
+            data: Dict[str, np.ndarray] = {}
+            for k in self._ring:
+                item = self._rb.buffer[0][k].shape[2:]
+                out = np.zeros((padded,) + item, dtype=self._rb.buffer[0][k].dtype)
+                for e, slots in by_env.items():
+                    out[slots] = self._rb.buffer[e][k][t_idx[slots], 0]
+                data[k] = out
+            span.count(bytes=sum(v.nbytes for v in data.values()) + t_idx.nbytes + e_idx.nbytes)
+            dev = self._device
+            self._ring = _scatter_rows(
+                self._ring,
+                {k: jax.device_put(v, dev) for k, v in data.items()},
+                jax.device_put(t_idx, dev),
+                jax.device_put(e_idx, dev),
+            )
 
     # -- sampling ----------------------------------------------------------
     def _sample_indices(self, g: int) -> Tuple[np.ndarray, np.ndarray]:
@@ -244,15 +247,16 @@ class DeviceRingPrefetcher(_StagedGather):
 
     def _gather(self, g: int) -> Any:
         self.sync()
-        t_idx, env_order = self._sample_indices(g)
-        self._last_idx = (t_idx, env_order)
-        dev = self._device
-        return _gather_batch(
-            self._ring,
-            jax.device_put(t_idx, dev),
-            jax.device_put(env_order, dev),
-            self._f32_keys(),
-        )
+        with Span("Time/replay_sample", grad_steps=g):
+            t_idx, env_order = self._sample_indices(g)
+            self._last_idx = (t_idx, env_order)
+            dev = self._device
+            return _gather_batch(
+                self._ring,
+                jax.device_put(t_idx, dev),
+                jax.device_put(env_order, dev),
+                self._f32_keys(),
+            )
 
     def resync(self) -> None:
         """Forget the mirror and rebuild from host state on next use (after
@@ -505,18 +509,22 @@ class DeviceUniformRingPrefetcher(_StagedGather):
         else:
             steps = [(b._pos - delta + i) % size for i in range(delta)]
         self._synced_added = b._added
-        n = len(steps)
-        padded = -(-n // self._bucket) * self._bucket
-        t_idx = np.full((padded,), size, dtype=np.int32)
-        t_idx[:n] = steps
-        dev = self._device
-        data = {}
-        for k in self._ring:
-            host = b[k]
-            out = np.zeros((padded,) + host.shape[1:], dtype=host.dtype)
-            out[:n] = host[steps]
-            data[k] = jax.device_put(out, dev)
-        self._ring = _scatter_steps(self._ring, data, jax.device_put(t_idx, dev))
+        with Span("Time/replay_sync", rows=len(steps) * b.n_envs) as span:
+            n = len(steps)
+            padded = -(-n // self._bucket) * self._bucket
+            t_idx = np.full((padded,), size, dtype=np.int32)
+            t_idx[:n] = steps
+            dev = self._device
+            data = {}
+            nbytes = t_idx.nbytes
+            for k in self._ring:
+                host = b[k]
+                out = np.zeros((padded,) + host.shape[1:], dtype=host.dtype)
+                out[:n] = host[steps]
+                nbytes += out.nbytes
+                data[k] = jax.device_put(out, dev)
+            span.count(bytes=nbytes)
+            self._ring = _scatter_steps(self._ring, data, jax.device_put(t_idx, dev))
 
     def _f32_keys(self) -> Tuple[str, ...]:
         b = self._rb
@@ -524,19 +532,20 @@ class DeviceUniformRingPrefetcher(_StagedGather):
 
     def _gather(self, g: int) -> Any:
         self.sync()
-        idxs, env_idxs = self._rb.sample_indices(self._batch * g, self._next_obs)
-        self._last_idx = (idxs, env_idxs)
-        next_keys = tuple(k for k in self._rb._obs_keys if k in self._rb.keys()) if self._next_obs else ()
-        dev = self._device
-        return _gather_uniform(
-            self._ring,
-            jax.device_put(idxs.astype(np.int32), dev),
-            jax.device_put(env_idxs.astype(np.int32), dev),
-            g,
-            self._batch,
-            next_keys,
-            self._f32_keys(),
-        )
+        with Span("Time/replay_sample", grad_steps=g):
+            idxs, env_idxs = self._rb.sample_indices(self._batch * g, self._next_obs)
+            self._last_idx = (idxs, env_idxs)
+            next_keys = tuple(k for k in self._rb._obs_keys if k in self._rb.keys()) if self._next_obs else ()
+            dev = self._device
+            return _gather_uniform(
+                self._ring,
+                jax.device_put(idxs.astype(np.int32), dev),
+                jax.device_put(env_idxs.astype(np.int32), dev),
+                g,
+                self._batch,
+                next_keys,
+                self._f32_keys(),
+            )
 
     def resync(self) -> None:
         self._ring = None

@@ -48,10 +48,14 @@ buffer (policy-step counter == buffer content; the replay-ratio controller
 catches up on resume).
 
 Telemetry: the engine emits ``overlap`` JSONL events (player-stall /
-learner-stall / queue-depth / staleness) through the run's event stream,
-and the player times its env slices under the usual
-``Time/env_interaction_time`` span — overlapping the learner's
-``Time/train_time`` span in the same log interval is the visible win.
+learner-stall / queue-depth / staleness) through the run's event stream.
+It times with spans and nothing else, so the same intervals lie in a
+profiler capture: each env slice under the usual
+``Time/env_interaction_time`` (counts: ``env_steps``, and ``version``, the
+published params it started with), the player blocked on a full queue or the
+staleness gate under ``Wait/player_queue``, the learner blocked on an empty
+queue under ``Wait/learner_queue`` (count: ``packets`` it then took). The
+event's busy and stall seconds are the elapsed time of those spans.
 """
 from __future__ import annotations
 
@@ -60,6 +64,8 @@ import time
 from typing import Any, Callable, Dict, List, Optional
 
 import numpy as np
+
+from ..telemetry.spans import Span
 
 __all__ = ["BufferOpSink", "OverlapEngine", "Packet", "RecordingSink", "SpscRing"]
 
@@ -335,6 +341,25 @@ class OverlapEngine:
         g = self.guard
         return g is not None and getattr(g, "preempted", False)
 
+    def _span(self, name: str, **counts: float) -> Span:
+        """The run's span where the engine has its facade (it honours
+        `metric.disable_timer`), else a plain one on the global tracker."""
+        make = getattr(self.telem, "span", None)
+        return make(name, **counts) if make is not None else Span(name, **counts)
+
+    def _book(self, player_busy_s: float = 0.0, player_stall_s: float = 0.0, learner_stall_s: float = 0.0) -> None:
+        """A span's elapsed seconds into the interval's `overlap` event."""
+        with self._stats_lock:
+            self._player_busy_s += player_busy_s
+            self._player_stall_s += player_stall_s
+            self._learner_stall_s += learner_stall_s
+
+    def _player_gated(self) -> bool:
+        return (
+            len(self._ring) >= self._ring.capacity
+            or self._burst_seq - self._pub_seq > self.staleness_bound
+        )
+
     def _player_main(self, play_fn: Callable[[], Optional[Packet]]) -> None:
         try:
             while not self._should_stop() and (
@@ -350,20 +375,20 @@ class OverlapEngine:
                 # behind the latest published params) cannot block with a
                 # synchronous learner and bound 1 — it is the enforced
                 # contract, the queue bound is the steady-state throttle.
-                t0 = time.perf_counter()
-                while (
-                    len(self._ring) >= self._ring.capacity
-                    or self._burst_seq - self._pub_seq > self.staleness_bound
-                ) and not self._should_stop():
-                    time.sleep(_SLEEP_S)
-                gate_s = time.perf_counter() - t0
+                if self._player_gated():
+                    with self._span("Wait/player_queue") as gate:
+                        while self._player_gated() and not self._should_stop():
+                            time.sleep(_SLEEP_S)
+                    self._book(player_stall_s=gate.elapsed)
                 if self._should_stop():
                     break
 
-                t0 = time.perf_counter()
                 t0_wall = time.time()
-                pkt = play_fn()
-                busy_s = time.perf_counter() - t0
+                with self._span("Time/env_interaction_time", version=self._pub_seq) as busy:
+                    pkt = play_fn()
+                    if pkt is not None:
+                        busy.count(env_steps=pkt.env_steps)
+                self._book(player_busy_s=busy.elapsed)
                 if pkt is None:
                     break
                 pkt.version = self._pub_seq
@@ -395,20 +420,21 @@ class OverlapEngine:
                     except Exception:
                         pass
 
-                t0 = time.perf_counter()
                 # sole producer + pre-checked free slot: effectively
                 # immediate (the loop only guards the engine's invariants)
-                while not self._ring.try_put(pkt):
-                    if self._should_stop():
+                put = self._ring.try_put(pkt)
+                if not put:
+                    with self._span("Wait/player_queue") as full:
+                        while not put and not self._should_stop():
+                            time.sleep(_SLEEP_S)
+                            put = self._ring.try_put(pkt)
+                    self._book(player_stall_s=full.elapsed)
+                    if not put:
                         return  # stop requested while blocked on a full queue
-                    time.sleep(_SLEEP_S)
-                stall_s = (time.perf_counter() - t0) + gate_s
 
                 self.produced_steps += pkt.env_steps
                 self.packets_produced += 1
                 with self._stats_lock:
-                    self._player_busy_s += busy_s
-                    self._player_stall_s += stall_s
                     if pkt.staleness > self._staleness_max:
                         self._staleness_max = pkt.staleness
                     if pkt.staleness > self.staleness_seen_max:
@@ -433,32 +459,33 @@ class OverlapEngine:
         bound or the claim — there is no instant where it could start
         acting with pre-update params."""
         out: List[Packet] = []
-        t0 = time.perf_counter()
-        stalled = 0.0
         claimed = False
-        while True:
-            if len(self._ring) > 0:
+
+        def drain() -> None:
+            nonlocal claimed
+            while len(self._ring) > 0 and not (max_packets and len(out) >= max_packets):
                 if not claimed:
                     claimed = True
                     self._burst_seq += 1  # claim BEFORE the pop (see docstring)
                 item = self._ring.try_get()
                 if item is not self._ring:
                     out.append(item)
-                    if max_packets and len(out) >= max_packets:
-                        break
-                    continue
-            if out:
-                break
-            if self._player_exc is not None:
-                raise RuntimeError("overlap player thread crashed") from self._player_exc
-            if self._player_done.is_set() or self._should_stop():
-                break
-            time.sleep(_SLEEP_S)
-            stalled = time.perf_counter() - t0
+
+        def fed_or_ended() -> bool:
+            return bool(out) or self._player_exc is not None or self._player_done.is_set() or self._should_stop()
+
+        drain()
+        if not fed_or_ended():
+            with self._span("Wait/learner_queue") as wait:
+                while not fed_or_ended():
+                    time.sleep(_SLEEP_S)
+                    drain()
+                wait.count(packets=len(out))
+            self._book(learner_stall_s=wait.elapsed)
+        if not out:
+            drain()  # a last packet put just before the player set its done flag
         if self._player_exc is not None and not out:
             raise RuntimeError("overlap player thread crashed") from self._player_exc
-        with self._stats_lock:
-            self._learner_stall_s += stalled
         now_wall = time.time()
         for pkt in out:
             self.acked_steps += pkt.env_steps
@@ -494,6 +521,14 @@ class OverlapEngine:
         after ``mirror.refresh`` when training ran, or bare otherwise —
         once per learner iteration that consumed packets)."""
         self._pub_seq = self._burst_seq
+
+    @property
+    def burst(self) -> int:
+        """The claim the learner holds: the `burst` count of its
+        `Time/train_time` spans, against the `version` of the player's
+        `Time/env_interaction_time` (a slice of version v started before the
+        params of burst v + 1 were published)."""
+        return self._burst_seq
 
     @property
     def queue_len(self) -> int:

@@ -39,6 +39,8 @@ from typing import Any, Optional
 
 import jax
 
+from ..telemetry.spans import Span
+
 
 def host_device() -> Any:
     """The CPU backend device of this process. Raises ``RuntimeError`` where
@@ -70,8 +72,12 @@ def player_device(cfg: Any, accelerator: Optional[Any] = None) -> Any:
 class ParamMirror:
     """Player-side copy of (a subtree of) the learner params.
 
-    ``refresh(new)`` dispatches the device→host transfer (async under JAX's
-    dispatch model); ``current()`` returns the params the player should use
+    ``refresh(new)`` copies them to the mirror's device. Between two backends
+    (learner on the TPU, player on ``cpu:0``) ``device_put`` fetches each
+    leaf to the host first, so the call returns only when the burst that
+    writes the params has ended and the copy is done: ``Time/param_refresh``
+    times it (0.8 s for 0.82 GB after two DreamerV3-XL bursts on a v5e,
+    PERF.md). ``current()`` returns the params the player should use
     this step. In blocking mode that is always the newest copy (the player
     step then waits on the transfer); in async mode the newest copy is
     swapped in only once every leaf ``is_ready()``, so the player never
@@ -121,7 +127,10 @@ class ParamMirror:
         return jax.tree.map(put_leaf, params)
 
     def refresh(self, params: Any) -> None:
-        new = self._put(params)
+        leaves = jax.tree.leaves(params)
+        nbytes = sum(int(getattr(x, "nbytes", 0)) for x in leaves)
+        with Span("Time/param_refresh", bytes=nbytes, leaves=len(leaves)):
+            new = self._put(params)
         if self.async_refresh:
             with self._swap_lock:
                 self._pending = new

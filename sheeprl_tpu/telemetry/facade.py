@@ -316,8 +316,8 @@ class Telemetry:
                 pass
 
     # -- spans / annotations ----------------------------------------------
-    def span(self, name: str) -> Span:
-        return Span(name, tracker=self.tracker, enabled=self._span_enabled, annotate=self.enabled)
+    def span(self, name: str, **counts: float) -> Span:
+        return Span(name, tracker=self.tracker, enabled=self._span_enabled, annotate=self.enabled, **counts)
 
     def tick(self, policy_step: int) -> None:
         """Call at the top of each loop iteration: rotates the

@@ -662,6 +662,31 @@ EVENT_SCHEMAS: Dict[str, Dict[str, Tuple[bool, type]]] = {
     },
 }
 
+# span name → the counts it may carry (keyword arguments of `telem.span` /
+# `Span`, or `Span.count` inside). The prefix says whose time it is, because
+# a capture's reader names an idle gap of the device by the shortest `Time/`
+# span over it on ANY thread: `Time/<phase>` is a phase of the thread that
+# feeds the device and tiles that thread's iteration; `Wait/<what>` is a
+# thread blocked on the other; `Player/<step>` is a sub-step of the player
+# inside `Time/env_interaction_time`. howto/telemetry.md has where each lies.
+SPAN_SCHEMAS: Dict[str, Tuple[str, ...]] = {
+    "Time/env_interaction_time": ("env_steps", "version"),
+    "Time/train_time": ("grad_steps", "burst"),
+    "Time/learner_apply": ("env_steps", "packets"),
+    "Time/replay_sync": ("rows", "bytes"),
+    "Time/replay_sample": ("grad_steps",),
+    "Time/replay_stage": (),
+    "Time/param_refresh": ("bytes", "leaves"),
+    "Time/log_flush": (),
+    "Time/checkpoint": (),
+    "Wait/learner_queue": ("packets",),
+    "Wait/player_queue": (),
+    "Player/act": (),
+    "Player/env_step": (),
+    "Player/record": (),
+}
+SPAN_PREFIXES = ("Time/", "Wait/", "Player/")
+
 
 def validate_event(rec: Any) -> List[str]:
     """Return a list of problems (empty == valid)."""

@@ -98,7 +98,17 @@ def enable_compilation_cache() -> None:
     most of a minute to compile for a TPU). Where `JAX_COMPILATION_CACHE_DIR`
     is set, JAX already keeps its cache there and no other directory is set
     in code; otherwise the cache lives in `DEFAULT_XLA_CACHE_DIR`.
-    `SHEEPRL_NO_COMPILATION_CACHE=1` disables it. Safe to call repeatedly."""
+    `SHEEPRL_NO_COMPILATION_CACHE=1` disables it. Safe to call repeatedly.
+
+    On an accelerator the ops' metadata is part of the key. JAX leaves it out
+    by default, and an executable loaded from the cache keeps the metadata it
+    was compiled with: a capture would then show the `jax.named_scope` names
+    (and source lines) of whatever checkout compiled it first, and a reading
+    of `jit(train)` by part would book ops to scopes that have moved. The
+    price is a compile whenever a line of the traced code moves. A process
+    held to the CPU (`JAX_PLATFORMS=cpu`) keeps JAX's default: nothing reads
+    its op names. That is read from the configuration, because asking for the
+    backend here would initialise it before `jax.distributed.initialize`."""
     if os.environ.get("SHEEPRL_NO_COMPILATION_CACHE"):
         return
     import jax
@@ -106,6 +116,8 @@ def enable_compilation_cache() -> None:
     if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
         jax.config.update("jax_compilation_cache_dir", DEFAULT_XLA_CACHE_DIR)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
+    if (jax.config.jax_platforms or "").split(",")[0] != "cpu":
+        jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
 
 
 def acknowledge_partial_donation() -> None:

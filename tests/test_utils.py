@@ -223,3 +223,25 @@ def test_compilation_cache_dir_from_the_environment_wins(monkeypatch, tmp_path):
         assert jax.config.jax_compilation_cache_dir == "what-jax-read-from-the-environment"
 
     _with_restored_cache_dir(check)
+
+
+@pytest.mark.parametrize("platforms, keyed", [("tpu,cpu", True), (None, True), ("cpu", False)])
+def test_compilation_cache_keys_on_op_metadata_unless_held_to_the_cpu(monkeypatch, platforms, keyed):
+    """A cached executable keeps the `jax.named_scope` names it was compiled
+    with, so where a capture's op names are read the key has to hold them."""
+    from sheeprl_tpu.utils.utils import enable_compilation_cache
+
+    monkeypatch.delenv("SHEEPRL_NO_COMPILATION_CACHE", raising=False)
+
+    def check(jax):
+        was = jax.config.jax_platforms, jax.config.jax_compilation_cache_include_metadata_in_key
+        try:
+            jax.config.update("jax_platforms", platforms)
+            jax.config.update("jax_compilation_cache_include_metadata_in_key", False)
+            enable_compilation_cache()
+            assert jax.config.jax_compilation_cache_include_metadata_in_key is keyed
+        finally:
+            jax.config.update("jax_platforms", was[0])
+            jax.config.update("jax_compilation_cache_include_metadata_in_key", was[1])
+
+    _with_restored_cache_dir(check)
