@@ -165,8 +165,11 @@ class Recorded:
         if self.calls == 0 and self.first is not None:
             self.first(args)
         out = self.fn(*args, **kwargs)
+        leaves = jax.tree.leaves(out)
+        if any(isinstance(leaf, jax.core.Tracer) for leaf in leaves):
+            return out  # the program tracing its own callable (the player's read set): nothing ran
         self.calls += 1
-        for leaf in jax.tree.leaves(out):
+        for leaf in leaves:
             if isinstance(leaf, jax.Array):
                 self.devices |= leaf.devices()
         if self.keep is not None:

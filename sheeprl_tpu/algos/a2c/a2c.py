@@ -118,6 +118,7 @@ def main(dist: Distributed, cfg: Config) -> None:
 
     telem = Telemetry.setup(cfg, log_dir, rank, logger=logger, aggregator_keys=AGGREGATOR_KEYS)
     aggregator = telem.aggregator
+    telem.emit(mirror.placement)
     ckpt = CheckpointManager(log_dir, keep_last=cfg.checkpoint.keep_last, enabled=rank == 0)
     guard = RunGuard.setup(cfg, ckpt, telem, log_dir)
     ckpt = guard.ckpt

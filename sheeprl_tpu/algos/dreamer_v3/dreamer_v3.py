@@ -46,7 +46,7 @@ from ...ops.transforms import unrolled_cumprod
 from ...optim import clipped
 from ...parallel import Distributed
 from ...parallel.mesh import maybe_shard_opt_state, maybe_shard_params
-from ...parallel.placement import make_param_mirror, player_device
+from ...parallel.placement import make_param_mirror, player_device, read_subtree
 from ...telemetry import Telemetry
 from ...telemetry import xla as _xla
 from ...utils.checkpoint import CheckpointManager
@@ -517,6 +517,22 @@ def make_player(wm: WorldModel, actor: Actor, cfg: Config, actions_dim, is_conti
     return init_state, step
 
 
+def player_params_view(player_init, player_step_fn, params, obs_space, cnn_keys, mlp_keys, num_envs: int):
+    """``view(params)``: the leaves of ``params``'s ``wm`` and ``actor`` that
+    the player made by :func:`make_player` reads, found by tracing every call
+    the env loop makes of it (first state, masked reset, step, greedy step)
+    and not by a list of module names (`placement.read_subtree`)."""
+    blank = {k: np.zeros((num_envs,) + tuple(obs_space[k].shape), obs_space[k].dtype) for k in cnn_keys + mlp_keys}
+    obs = prepare_obs(blank, cnn_keys, mlp_keys, num_envs)
+
+    def probe(tree, obs, key):
+        state = player_init(tree)
+        reset = player_init(tree, jnp.zeros((num_envs,), bool), state)
+        return reset, player_step_fn(tree, obs, state, key), player_step_fn(tree, obs, state, key, greedy=True)
+
+    return read_subtree({"wm": params["wm"], "actor": params["actor"]}, probe, obs, jax.random.key(0))
+
+
 @register_algorithm(name="dreamer_v3")
 def main(dist: Distributed, cfg: Config) -> None:
     root_key = dist.seed_everything(cfg.seed)
@@ -590,15 +606,16 @@ def main(dist: Distributed, cfg: Config) -> None:
     )
     player_init, player_step_fn = make_player(wm, actor, cfg, actions_dim, is_continuous, num_envs)
     # Actor/learner split (parallel/placement.py): per-step inference runs on
-    # the player device (host CPU backend when the mesh is an
-    # accelerator); the mirror re-syncs its {wm, actor} subtree after every
-    # train burst — the only place params change.
-    mirror, pdev, player_key, root_key = make_param_mirror(
-        cfg, dist.local_device, {"wm": params["wm"], "actor": params["actor"]}, root_key
-    )
+    # the player device, which `auto` picks by the bytes the player reads; the
+    # mirror holds those leaves of {wm, actor} and no others (the decoder and
+    # the reward and continue heads enter none of the player's programs) and
+    # re-syncs them after every train burst — the only place params change.
+    player_view = player_params_view(player_init, player_step_fn, params, obs_space, cnn_keys, mlp_keys, num_envs)
+    mirror, pdev, player_key, root_key = make_param_mirror(cfg, dist.local_device, player_view(params), root_key)
 
     telem = Telemetry.setup(cfg, log_dir, rank, logger=logger, aggregator_keys=AGGREGATOR_KEYS)
     aggregator = telem.aggregator
+    telem.emit(mirror.placement)
     # the mesh layout is a telemetry artifact: every inferred spec (and the
     # per-chip bytes accounting) lands in the JSONL stream as `sharding`
     # events — doctor's replicated_giant reads them
@@ -833,7 +850,7 @@ def main(dist: Distributed, cfg: Config) -> None:
                     )
                 if not MetricAggregator.disabled:
                     pending_metrics.append(metrics)
-                mirror.refresh({"wm": params["wm"], "actor": params["actor"]})
+                mirror.refresh(player_view(params))
                 fleet.publish(mirror.current())
                 run_info.mark_steady(policy_step, sync=lambda: jax.block_until_ready(metrics))
             if learning_starts <= policy_step < total_steps:
@@ -903,7 +920,7 @@ def main(dist: Distributed, cfg: Config) -> None:
                     with telem.span("Time/replay_stage"):
                         prefetch.stage(nxt)
             if bursting:
-                mirror.refresh({"wm": params["wm"], "actor": params["actor"]})
+                mirror.refresh(player_view(params))
                 run_info.mark_steady(policy_step, sync=lambda: jax.block_until_ready(metrics))
             engine.published()  # release take()'s claim every iteration
             if policy_step < total_steps:
@@ -955,7 +972,7 @@ def main(dist: Distributed, cfg: Config) -> None:
                         # device refs held until the log-cadence host sync;
                         # skip entirely when metrics are off (bench legs)
                         pending_metrics.append(metrics)
-                    mirror.refresh({"wm": params["wm"], "actor": params["actor"]})
+                    mirror.refresh(player_view(params))
                     run_info.mark_steady(policy_step, sync=lambda: jax.block_until_ready(metrics))
                 if policy_step < total_steps:
                     # overlap the next sample + host→HBM transfer with the train

@@ -212,6 +212,7 @@ def main(dist: Distributed, cfg: Config) -> None:
     mirror, pdev, player_key, root_key = make_param_mirror(
         cfg, dist.local_device, {"actor": params["actor"]}, root_key
     )
+    telem.emit(mirror.placement)
 
     obs, _ = envs.reset(seed=cfg.seed)
     obs_vec = flatten_obs(obs, mlp_keys, num_envs)

@@ -520,6 +520,7 @@ def main(dist: Distributed, cfg: Config) -> None:
 
     # Actor/learner split (parallel/placement.py): see dreamer_v3.py
     mirror, pdev, player_key, root_key = make_param_mirror(cfg, dist.local_device, _sp(), root_key)
+    telem.emit(mirror.placement)
 
     obs, _ = envs.reset(seed=cfg.seed)
     player_state = jax.device_put(player_init(), pdev)

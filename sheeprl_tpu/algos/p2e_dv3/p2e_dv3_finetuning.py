@@ -166,6 +166,7 @@ def main(dist: Distributed, cfg: Config, exploration_cfg: Config) -> None:
     mirror, pdev, player_key, root_key = make_param_mirror(
         cfg, dist.local_device, step_params(), root_key
     )
+    telem.emit(mirror.placement)
 
     prefetch = make_sequential_prefetcher(
         cfg,

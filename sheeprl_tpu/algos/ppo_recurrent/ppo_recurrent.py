@@ -233,6 +233,7 @@ def main(dist: Distributed, cfg: Config) -> None:
     mirror, pdev, player_key, root_key = make_param_mirror(
         cfg, dist.local_device, params, root_key, allow_async=False
     )
+    telem.emit(mirror.placement)
 
     obs, _ = envs.reset(seed=cfg.seed)
     carry = jax.device_put(module.initial_states(num_envs), pdev)

@@ -280,6 +280,7 @@ def main(dist: Distributed, cfg: Config) -> None:
     mirror, pdev, player_key, root_key = make_param_mirror(
         cfg, dist.local_device, {"encoder": params["encoder"], "actor": params["actor"]}, root_key
     )
+    telem.emit(mirror.placement)
 
     obs, _ = envs.reset(seed=cfg.seed)
 

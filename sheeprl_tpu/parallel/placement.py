@@ -18,11 +18,36 @@ actor–learner placement, re-derived for a single-controller JAX process):
 
 ``algo.player.device`` picks the player's device: ``host`` is the CPU backend
 of this process (an error where the process has none), ``accelerator`` the
-learner's first device, and ``auto`` the CPU backend whenever the learner is
-an accelerator and the process has one, else the learner's device. Which of
-``host`` and ``accelerator`` is faster on a locally attached chip is not
-measured (ROADMAP, Speed item 4); ``chip_smoke.py`` prints where the player
-ran.
+learner's first device, and ``auto`` decides by what it can observe, the
+bytes of the tree the player reads: with the learner on an accelerator a
+tree of at least ``AUTO_ACCELERATOR_MIN_BYTES`` stays on the learner's first
+device, a smaller one goes to the CPU backend where the process has one;
+with the learner on a CPU the player is on the learner's device.
+
+Why bytes: a batch-1 forward reads every weight once, so on the host it is
+bound by the host's memory and the mirror's device-to-host copy (with the
+learner blocked and the device idle) grows with the tree, while on the chip
+the forward costs 2-3 ms whatever the size. Measured on a v5e, the device
+otherwise idle, float32, the leaves the DreamerV3 player reads; one act =
+``prepare_obs`` + forward + fetch of the action, one refresh = the call
+until the copy is done (PERF.md, PR 29):
+
+============ ======== ============= ============= ================ ===============
+DreamerV3    tree     act on cpu:0  act on tpu:0  refresh to cpu:0 refresh on tpu:0
+============ ======== ============= ============= ================ ===============
+S  (1 env)    32 MB    2.28 ms       2.21 ms        6.2 ms          3.4 ms
+M  (1 env)    69 MB    3.46 ms       2.25 ms       11.7 ms          3.8 ms
+L  (4 envs)  154 MB   10.47 ms       2.25 ms      115.3 ms          4.5 ms
+XL (1 env)   432 MB   13.08 ms       2.85 ms      400.1 ms          5.6 ms
+============ ======== ============= ============= ================ ===============
+
+At S the two tie, and a host player keeps acting while a burst runs, which
+a player on the learner's device cannot (its forward queues behind the
+burst); from M on the host loses on every column. So the constant, 48 MiB,
+lies between S and M. End to end on the two benchmark cells the chip gave
+2.5 x (L) and 1.6 x (XL) the gradient steps a second of the host (PERF.md,
+PR 29). A PPO or SAC MLP is kilobytes and acts on the host in microseconds
+without a device round trip.
 
 The mirror has two refresh modes (``algo.player.async_refresh``):
 
@@ -35,11 +60,19 @@ The mirror has two refresh modes (``algo.player.async_refresh``):
 """
 from __future__ import annotations
 
-from typing import Any, Optional
+import threading
+from typing import Any, Callable, Dict, Optional
 
 import jax
+import jax.numpy as jnp
+from jax.interpreters import partial_eval as pe
 
 from ..telemetry.spans import Span
+
+# `algo.player.device=auto` with the learner on an accelerator: a player whose
+# parameter tree has at least this many bytes acts on the learner's first
+# device, a smaller one on the host CPU backend (numbers: module docstring).
+AUTO_ACCELERATOR_MIN_BYTES = 48 * 2**20
 
 
 def host_device() -> Any:
@@ -48,12 +81,21 @@ def host_device() -> Any:
     return jax.local_devices(backend="cpu")[0]
 
 
-def player_device(cfg: Any, accelerator: Optional[Any] = None) -> Any:
+def tree_bytes(tree: Any) -> int:
+    return sum(int(getattr(x, "nbytes", 0)) for x in jax.tree.leaves(tree))
+
+
+def _mode(cfg: Any) -> str:
+    return (cfg.select("algo.player.device", "auto") or "auto") if cfg is not None else "auto"
+
+
+def player_device(cfg: Any, accelerator: Optional[Any] = None, player_bytes: Optional[int] = None) -> Any:
     """Resolve where per-step policy inference should run (see the module
-    docstring for the three ``algo.player.device`` modes)."""
-    mode = "auto"
-    if cfg is not None:
-        mode = cfg.select("algo.player.device", "auto") or "auto"
+    docstring for the three ``algo.player.device`` modes). ``player_bytes``
+    is the size of the tree the player reads, which is what ``auto`` decides
+    by on an accelerator; a caller that has no tree at hand leaves it out and
+    gets the host."""
+    mode = _mode(cfg)
     default = accelerator if accelerator is not None else jax.local_devices()[0]
     if mode == "accelerator":
         return default
@@ -63,21 +105,42 @@ def player_device(cfg: Any, accelerator: Optional[Any] = None) -> Any:
         raise ValueError(f"algo.player.device must be auto|host|accelerator, got '{mode}'")
     if default.platform == "cpu":
         return default
+    if player_bytes is not None and player_bytes >= AUTO_ACCELERATOR_MIN_BYTES:
+        return default
     try:
         return host_device()
     except RuntimeError:  # accelerator-only process: auto stays on the learner's device
         return default
 
 
+def _local_copy(x: Any, device: Any) -> Optional[Any]:
+    """The copy of ``x`` that already lives on ``device`` (the whole array on
+    a single-device run, this device's copy of a leaf replicated over the
+    mesh), or None where it has none there."""
+    if isinstance(x, jax.Array) and x.is_fully_replicated and device in x.devices():
+        return next(s.data for s in x.addressable_shards if s.device == device)
+    return None
+
+
+@jax.jit
+def _copy_leaves(leaves):
+    return [jnp.copy(x) for x in leaves]
+
+
 class ParamMirror:
     """Player-side copy of (a subtree of) the learner params.
 
-    ``refresh(new)`` copies them to the mirror's device. Between two backends
-    (learner on the TPU, player on ``cpu:0``) ``device_put`` fetches each
-    leaf to the host first, so the call returns only when the burst that
-    writes the params has ended and the copy is done: ``Time/param_refresh``
-    times it (0.8 s for 0.82 GB after two DreamerV3-XL bursts on a v5e,
-    PERF.md). ``current()`` returns the params the player should use
+    ``refresh(new)`` copies them to the mirror's device, in one of two ways,
+    leaf by leaf. A leaf that already has a copy on that device (player and
+    learner share it) is copied there by ONE jitted program over all such
+    leaves: the call dispatches it and returns futures, the device's stream
+    runs it after the burst that writes the parameters and before the next
+    burst, which donates them, and nothing waits (``same_device`` 1 on
+    ``Time/param_refresh``, which then times a dispatch). Any other leaf
+    goes through ``device_put``; between two backends (learner on the TPU,
+    player on ``cpu:0``) that fetches it to the host first, so the call
+    returns only when the burst has ended and the copy is done.
+    ``current()`` returns the params the player should use
     this step. In blocking mode that is always the newest copy (the player
     step then waits on the transfer); in async mode the newest copy is
     swapped in only once every leaf ``is_ready()``, so the player never
@@ -93,13 +156,13 @@ class ParamMirror:
     """
 
     def __init__(self, params: Any, device: Any, async_refresh: bool = False):
-        import threading
-
         self.device = device
         self.async_refresh = bool(async_refresh)
+        self.same_device = False  # whether the newest copy never left the device
         self.params = self._put(params)
         self._pending: Optional[Any] = None
         self._swap_lock = threading.Lock()
+        self.placement: Optional[Dict[str, Any]] = None  # the run's `placement` event, from `make_param_mirror`
 
     def _put(self, params: Any) -> Any:
         """Copy params to the mirror device, every leaf committed to that ONE
@@ -110,27 +173,24 @@ class ParamMirror:
         params' mesh and the second call retraces.
 
         ``device_put`` ALIASES a buffer that already lives on the target
-        device — the whole array on a single-device run where learner and
-        player share the device, or this device's copy of a leaf replicated
-        over the mesh — and the learner's train step donates its param
-        buffers, which would delete the mirror's copy out from under the
-        player. Those leaves get a real on-device copy first."""
-
-        def put_leaf(x: Any) -> Any:
-            if isinstance(x, jax.Array) and x.is_fully_replicated and self.device in x.devices():
-                import jax.numpy as jnp
-
-                local = next(s.data for s in x.addressable_shards if s.device == self.device)
-                x = jnp.copy(local)  # new buffer on the same device
-            return jax.device_put(x, self.device)
-
-        return jax.tree.map(put_leaf, params)
+        device, and the learner's train step donates its param buffers,
+        which would delete the mirror's copy out from under the player.
+        Those leaves get a real on-device copy: new buffers, all from one
+        dispatch."""
+        leaves, treedef = jax.tree.flatten(params)
+        local = [_local_copy(x, self.device) for x in leaves]
+        here = [x for x in local if x is not None]
+        copied = iter(_copy_leaves(here) if here else ())
+        self.same_device = len(here) == len(leaves)
+        return treedef.unflatten(
+            [jax.device_put(x, self.device) if here is None else next(copied) for x, here in zip(leaves, local)]
+        )
 
     def refresh(self, params: Any) -> None:
         leaves = jax.tree.leaves(params)
-        nbytes = sum(int(getattr(x, "nbytes", 0)) for x in leaves)
-        with Span("Time/param_refresh", bytes=nbytes, leaves=len(leaves)):
+        with Span("Time/param_refresh", bytes=tree_bytes(leaves), leaves=len(leaves)) as span:
             new = self._put(params)
+            span.count(same_device=int(self.same_device))
         if self.async_refresh:
             with self._swap_lock:
                 self._pending = new
@@ -161,23 +221,66 @@ def place_for_inference(cfg: Any, params: Any) -> Any:
     return jax.device_put(params, player_device(cfg))
 
 
+def read_subtree(tree: Any, probe: Callable[..., Any], *args: Any) -> Callable[[Any], Any]:
+    """Which leaves of ``tree`` does ``probe(tree, *args)`` read? Traces the
+    probe once (nothing runs; ``args`` may be ``jax.ShapeDtypeStruct``s), drops
+    what its outputs do not depend on, through nested ``jit`` calls too, and
+    returns ``select``: ``select(other)`` holds the leaves of ``other`` (any
+    nested dict with ``tree``'s paths in it) that were read, and none of the
+    dicts that leaves empty. A player whose ``probe`` makes every call the
+    env loop makes can then mirror ``select(params)`` and act bit-identically:
+    the leaves left out enter none of its programs."""
+    from ..telemetry import xla as _xla
+
+    flat, _ = jax.tree_util.tree_flatten_with_path(tree)
+    with _xla.suppress_retrace_accounting():  # a diagnostic trace, not the loop's
+        closed = jax.make_jaxpr(probe)(tree, *args)
+    _, used = pe.dce_jaxpr(closed.jaxpr, [True] * len(closed.jaxpr.outvars))
+    read = [tuple(k.key for k in path) for (path, _), u in zip(flat, used) if u]  # tree's leaves come first
+
+    def select(other: Any) -> Any:
+        out: Dict[str, Any] = {}
+        for path in read:
+            src, dst = other, out
+            for k in path[:-1]:
+                src, dst = src[k], dst.setdefault(k, {})
+            dst[path[-1]] = src[path[-1]]
+        return out
+
+    return select
+
+
 def make_param_mirror(cfg: Any, accelerator: Any, params: Any, root_key: Any, allow_async: bool = True):
     """The per-algorithm player setup, in one place: resolve the player
-    device, mirror the player's param subtree there, and derive a player PRNG
-    key committed next to it (so the env loop never does a host-side split).
+    device from the bytes of ``params`` (the tree the player reads), mirror
+    it there, and derive a player PRNG key committed next to it (so the env
+    loop never does a host-side split).
 
     ``allow_async=False`` pins the mirror to blocking refresh regardless of
     ``algo.player.async_refresh`` — on-policy algorithms (PPO/A2C) must act
     with the params the coming update will be credited to.
 
+    ``mirror.placement`` is the choice as the run's ``placement`` event; the
+    caller emits it once it has its ``telem``.
+
     Returns ``(mirror, pdev, player_key, root_key)`` — the new ``root_key``
     replaces the caller's (one split is consumed).
     """
-    pdev = player_device(cfg, accelerator)
+    nbytes = tree_bytes(params)
+    pdev = player_device(cfg, accelerator, nbytes)
     mirror = ParamMirror(
         params,
         pdev,
         async_refresh=allow_async and bool(cfg.select("algo.player.async_refresh", False)),
     )
+    mirror.placement = {
+        "event": "placement",
+        "player_device": f"{pdev.platform}:{pdev.id}",
+        "learner_device": f"{accelerator.platform}:{accelerator.id}",
+        "mode": str(_mode(cfg)),
+        "tree_bytes": nbytes,
+        "threshold_bytes": AUTO_ACCELERATOR_MIN_BYTES,
+        "same_device": int(mirror.same_device),
+    }
     root_key, pk = jax.random.split(root_key)
     return mirror, pdev, jax.device_put(pk, pdev), root_key

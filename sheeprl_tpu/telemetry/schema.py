@@ -426,6 +426,19 @@ EVENT_SCHEMAS: Dict[str, Dict[str, Tuple[bool, type]]] = {
         "total_bytes": (False, _NUM),
         "replicated_bytes": (False, _NUM),
     },
+    # where the player runs, once at set-up (parallel/placement.py
+    # `make_param_mirror`): the device `algo.player.device` resolved to
+    # ("tpu:0", "cpu:0"), the bytes of the tree the player reads, the bytes
+    # at and over which `auto` keeps it on the learner's accelerator, and
+    # whether a refresh stays on the learner's device (1) or not (0)
+    "placement": {
+        "player_device": (True, _STR),
+        "learner_device": (True, _STR),
+        "mode": (True, _STR),  # auto | host | accelerator
+        "tree_bytes": (True, _NUM),
+        "threshold_bytes": (True, _NUM),
+        "same_device": (True, _NUM),
+    },
     # deterministic fault injection (resilience/chaos.py): faults the
     # SUPERVISOR injects (worker-side faults surface as `fleet` incidents —
     # a chaos crash is indistinguishable from a real one by design)
@@ -676,7 +689,7 @@ SPAN_SCHEMAS: Dict[str, Tuple[str, ...]] = {
     "Time/replay_sync": ("rows", "bytes"),
     "Time/replay_sample": ("grad_steps",),
     "Time/replay_stage": (),
-    "Time/param_refresh": ("bytes", "leaves"),
+    "Time/param_refresh": ("bytes", "leaves", "same_device"),
     "Time/log_flush": (),
     "Time/checkpoint": (),
     "Wait/learner_queue": ("packets",),

@@ -6,11 +6,15 @@ the learner's donated train step would delete the mirror's buffers out from
 under the player (the crash surfaced as "Buffer has been deleted or donated"
 in the DreamerV3 async-refresh bench leg).
 """
+import threading
+import time
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from sheeprl_tpu.parallel import placement
 from sheeprl_tpu.parallel.placement import ParamMirror, host_device, player_device
 
 
@@ -117,6 +121,176 @@ def test_player_device_host_needs_a_cpu_backend_and_auto_stays_on_the_learner(mo
         player_device(_ModeCfg("host"), chip)
     assert player_device(_ModeCfg("auto"), chip) is chip
     assert player_device(_ModeCfg("accelerator"), chip) is chip
+
+
+_MIN = placement.AUTO_ACCELERATOR_MIN_BYTES
+
+
+@pytest.mark.parametrize(
+    "player_bytes,on_learner",
+    [(None, False), (0, False), (_MIN - 1, False), (_MIN, True), (8 * _MIN, True)],
+    ids=["no_tree", "empty", "under", "at", "over"],
+)
+def test_player_device_auto_resolves_by_the_bytes_the_player_reads(player_bytes, on_learner):
+    """On an accelerator `auto` keeps a player of at least the threshold on the
+    learner's device and sends a smaller one (a PPO or SAC MLP, DreamerV3-S)
+    to the host as before; by name nothing changes."""
+    chip = _FakeChip()
+    assert (player_device(_ModeCfg("auto"), chip, player_bytes) is chip) == on_learner
+    if not on_learner:
+        assert player_device(_ModeCfg("auto"), chip, player_bytes) is host_device()
+    assert player_device(_ModeCfg("host"), chip, player_bytes) is host_device()
+    assert player_device(_ModeCfg("accelerator"), chip, player_bytes) is chip
+
+
+@pytest.mark.parametrize("player_bytes", [None, 0, _MIN, 8 * _MIN])
+def test_player_device_auto_with_a_cpu_learner_is_the_learner_whatever_the_bytes(player_bytes):
+    learner = jax.local_devices(backend="cpu")[1]  # not the default device, so that the two can be told apart
+    assert player_device(_ModeCfg("auto"), learner, player_bytes) is learner
+    assert player_device(None, None, player_bytes) is jax.local_devices()[0]
+
+
+def _slow_burst(seconds_worth: int = 400):
+    """A donating 'train burst' that keeps one CPU core busy for a second or so."""
+
+    def burst(params, x):
+        x = jax.lax.fori_loop(0, seconds_worth, lambda _, y: jnp.tanh(y @ y) + 1e-3, x)
+        return jax.tree.map(lambda p: p + 1.0 + 0.0 * x[0, 0], params), x
+
+    return jax.jit(burst, donate_argnums=(0,))
+
+
+def test_same_device_refresh_is_one_dispatch_that_waits_for_nothing(monkeypatch):
+    """Learner and player on one device: `refresh` copies every leaf with ONE
+    jitted program, returns futures while the burst that writes the
+    parameters is still running, and never calls `block_until_ready`."""
+    dev = host_device()
+    params = jax.device_put({"w": jnp.ones((4, 4)), "b": jnp.zeros((4,)), "deep": {"k": jnp.ones((2,))}}, dev)
+    x = jax.device_put(jnp.eye(384) * 0.5, dev)
+    burst = _slow_burst()
+    jax.block_until_ready(burst(jax.tree.map(jnp.copy, params), x))  # compiled, outside the timing
+    mirror = ParamMirror(params, dev)
+    assert mirror.same_device
+
+    calls = []
+    copy_leaves = placement._copy_leaves
+    monkeypatch.setattr(placement, "_copy_leaves", lambda leaves: calls.append(len(leaves)) or copy_leaves(leaves))
+
+    def never(*_a, **_k):
+        raise AssertionError("a same-device refresh must not block")
+
+    monkeypatch.setattr(jax, "block_until_ready", never)
+    t0 = time.perf_counter()
+    params, x = burst(params, x)
+    mirror.refresh(params)
+    returned = time.perf_counter() - t0
+    still_running = not x.is_ready()
+    monkeypatch.undo()
+    jax.block_until_ready(x)
+    finished = time.perf_counter() - t0
+    assert calls == [3]  # one dispatch, all three leaves in it
+    assert still_running and returned < 0.5 * finished, (returned, finished)
+    np.testing.assert_allclose(np.asarray(mirror.current()["w"]), 2 * np.ones((4, 4)))
+    np.testing.assert_allclose(np.asarray(mirror.current()["deep"]["k"]), 2 * np.ones((2,)))
+
+
+@pytest.mark.parametrize("async_refresh", [False, True], ids=["blocking", "async"])
+def test_same_device_mirror_survives_donation_with_a_player_thread_reading(async_refresh):
+    """Several bursts, each donating the parameters the mirror was refreshed
+    from, while a player thread reads `current()` all the time: it never sees
+    a deleted buffer, and what it reads only ever moves forward."""
+    dev = host_device()
+    params = jax.device_put({"w": jnp.ones((8, 8)), "b": jnp.ones((8,))}, dev)
+    mirror = ParamMirror(params, dev, async_refresh=async_refresh)
+    consume = _donating_consumer()
+    seen, errors, stop = [], [], threading.Event()
+
+    def player():
+        try:
+            while not stop.is_set():
+                cur = mirror.current()
+                seen.append((float(np.asarray(cur["w"])[0, 0]), float(np.asarray(cur["b"])[0])))
+        except Exception as e:  # "Buffer has been deleted or donated"
+            errors.append(e)
+
+    th = threading.Thread(target=player)
+    th.start()
+    try:
+        for _ in range(8):
+            params = consume(params)  # donates what the mirror last copied from
+            mirror.refresh(params)
+            time.sleep(0.01)
+    finally:
+        stop.set()
+        th.join()
+    assert not errors, errors
+    assert all(w == b for w, b in seen)  # never half of one tree and half of another
+    values = [w for w, _ in seen]
+    assert values == sorted(values) and set(values) <= {float(i) for i in range(1, 10)}
+    jax.block_until_ready(params)
+    assert float(np.asarray(mirror.current()["w"])[0, 0]) == 9.0
+
+
+def test_read_subtree_keeps_what_a_probe_reads_through_nested_jits():
+    tree = {"a": {"w": jnp.ones((3, 3)), "unused": jnp.ones((2,))}, "b": {"w": jnp.ones((3,))}, "c": {"w": jnp.ones((5,))}}
+    inner = jax.jit(lambda t, x: x @ t["a"]["w"] + t["b"]["w"])
+    select = placement.read_subtree(tree, lambda t, x: (inner(t, x), 0.0 * t["c"]["w"].sum() * 0.0), jnp.ones((3,)))
+    other = jax.tree.map(lambda x: x + 1, {**tree, "d": {"w": jnp.zeros((1,))}})
+    picked = select(other)
+    assert jax.tree.structure(picked) == jax.tree.structure({"a": {"w": 0}, "b": {"w": 0}, "c": {"w": 0}})
+    assert picked["a"]["w"] is other["a"]["w"]
+
+
+def test_dreamer_v3_player_acts_bit_identically_from_the_leaves_it_reads():
+    """The DreamerV3 mirror holds the leaves of {wm, actor} that `make_player`'s
+    programs read: first state, masked reset, actions, state and key are
+    bit-identical from that subset and from the whole tree, and the decoder and
+    the reward and continue heads are not in it."""
+    import gymnasium as gym
+
+    from sheeprl_tpu.algos.dreamer_v3 import dreamer_v3 as dv3
+    from sheeprl_tpu.algos.dreamer_v3.agent import build_agent
+    from sheeprl_tpu.config import compose
+    from sheeprl_tpu.parallel import Distributed
+    from tests.dreamer_tiny import N_ACT, TINY_DV3
+
+    cfg = compose("config", TINY_DV3 + ["algo.mlp_keys.encoder=[state]"])
+    n = 3
+    space = gym.spaces.Dict(
+        {"rgb": gym.spaces.Box(0, 255, (64, 64, 3), np.uint8), "state": gym.spaces.Box(-1, 1, (5,), np.float32)}
+    )
+    wm, actor, _, params = build_agent(Distributed(devices=1), cfg, space, [N_ACT], False, jax.random.key(0))
+    whole = {"wm": params["wm"], "actor": params["actor"]}
+    init, step = dv3.make_player(wm, actor, cfg, [N_ACT], False, n)
+    view = dv3.player_params_view(init, step, params, space, ("rgb",), ("state",), n)
+    subset = view(params)
+
+    assert {"encoder", "rssm"} <= set(subset["wm"])
+    assert not set(subset["wm"]) & {"observation_model", "reward", "continue"}
+    # `WorldModel.initial_states` draws z0 from the transition model, so it stays (ISSUE 29 counted it among the unread)
+    assert set(subset["wm"]["rssm"]) == set(whole["wm"]["rssm"])
+    assert set(subset["actor"]) == set(whole["actor"])
+    assert placement.tree_bytes(subset) < 0.6 * placement.tree_bytes(whole)
+    assert all(a is b for a, b in zip(jax.tree.leaves(subset), jax.tree.leaves(view(whole))))
+
+    rng = np.random.default_rng(0)
+    frames = rng.integers(0, 255, (4, n, 64, 64, 3), np.uint8)
+
+    def rollout(tree):
+        key = jax.random.key(5)
+        state = init(tree)
+        outs = [state]
+        for t in range(4):
+            obs = {"rgb": frames[t], "state": np.full((n, 5), 0.1 * t, np.float32)}
+            env_actions, cat, state, key = step(tree, obs, state, key, greedy=(t == 3))
+            outs += [env_actions, cat, state, jax.random.key_data(key)]
+            if t == 1:
+                state = init(tree, np.array([True, False, True]), state)
+                outs.append(state)
+        return jax.tree.leaves(outs)
+
+    for a, b in zip(rollout(whole), rollout(subset), strict=True):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
 def test_param_mirror_commits_every_leaf_to_its_one_device():

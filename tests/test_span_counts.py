@@ -119,17 +119,37 @@ def test_lint_wants_a_span_and_its_counts_in_the_registry(tmp_path):
     assert "count 'tokens' is not declared" in findings[1].message
 
 
-def test_param_mirror_refresh_is_a_span_with_the_bytes_it_copied():
+@pytest.mark.parametrize("same_device", [1, 0], ids=["learners_device", "another_device"])
+def test_param_mirror_refresh_is_a_span_with_the_bytes_it_copied(same_device):
+    """`same_device` says whether the copy stayed on the device the parameters
+    live on (the span then times one dispatch) or crossed to another."""
     from sheeprl_tpu.parallel.placement import ParamMirror
 
     params = {"w": jnp.ones((4, 8)), "b": jnp.zeros((8,))}
-    mirror = ParamMirror(params, jax.devices()[0])
+    mirror = ParamMirror(params, jax.devices()[0 if same_device else 1])
     GLOBAL_TRACKER.compute(reset=True)
     mirror.refresh(params)
     mirror.refresh(params)
     assert GLOBAL_TRACKER.counts()["Time/param_refresh"] == 2
-    assert GLOBAL_TRACKER.sums()["Time/param_refresh"] == {"bytes": 2 * (32 + 8) * 4, "leaves": 4}
+    assert GLOBAL_TRACKER.sums()["Time/param_refresh"] == {
+        "bytes": 2 * (32 + 8) * 4, "leaves": 4, "same_device": 2 * same_device,
+    }
+    assert set(SPAN_SCHEMAS["Time/param_refresh"]) == {"bytes", "leaves", "same_device"}
     GLOBAL_TRACKER.compute(reset=True)
+
+
+def test_make_param_mirror_leaves_a_placement_event_the_schema_accepts():
+    from sheeprl_tpu.config import Config
+    from sheeprl_tpu.parallel import placement
+    from sheeprl_tpu.telemetry.schema import validate_event
+
+    params = {"w": jnp.ones((4, 8)), "b": jnp.zeros((8,))}
+    mirror, pdev, _, _ = placement.make_param_mirror(Config({"algo": {}}), jax.devices()[0], params, jax.random.key(0))
+    rec = mirror.placement
+    assert validate_event(rec) == []
+    assert rec["tree_bytes"] == (32 + 8) * 4 and rec["threshold_bytes"] == placement.AUTO_ACCELERATOR_MIN_BYTES
+    assert rec["player_device"] == rec["learner_device"] == f"cpu:{pdev.id}" and rec["mode"] == "auto"
+    assert rec["same_device"] == 1
 
 
 def test_ring_sync_counts_the_rows_it_ships_and_sampling_its_gradient_steps():
