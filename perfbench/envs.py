@@ -1,19 +1,34 @@
-"""The benchmark's traffic: one seeded synthetic gymnasium env, driven by a mix file.
+"""The benchmark's traffic: seeded synthetic gymnasium envs, driven by a mix file.
 
-The program reaches it through ``env.wrapper._target_=perfbench.envs.SyntheticEnv``.
-Everything that makes a mix (observation keys, shapes and dtypes, the action
-space, episode lengths, scene length, rewards) is data in
-``perfbench/traffic/<mix>.json``; this file is the one general generator.
+A mix (``<home>/traffic/<mix>.json``) names its generator under
+``"generator"``, a dotted class path, and the program reaches it through
+``env.wrapper._target_=<that path>``. Everything that makes a mix (observation
+keys, shapes and dtypes, the action space, episode lengths, scene length,
+rewards) is data in the mix's file; a generator is general code.
 
-Every ``step()`` call is stamped with the host clock on entry and on return.
-Those stamps are taken outside the program and are the source of the
-end-to-end metrics. The env also keeps its own log of every observation it
-emitted (which frame, reward, flags, and the action that answered it), so the
-batch the replay ring gathers can be checked row by row against what the
-generator really produced.
+What `run.py` and the adapters need of ANY generator (checked by
+tests/perfbench/test_pb_envs.py for every class a mix names):
+
+* the constructor takes ``mix, seed, rank, bench_seed``: the mix's name (or
+  its file's path from the root of the checkout), the program's seed (not
+  used), the env's index, and the benchmark's ``--seed``;
+* it registers itself in ``envs.REGISTRY`` under its env index, in this
+  process (`env.sync_env=True`);
+* every ``step()`` call is stamped with the host clock on entry and on return
+  in the lists ``t_enter`` / ``t_exit`` (and ``self_s``, their difference).
+  Those stamps are taken outside the program and are the source of the
+  end-to-end metrics;
+* the same ``bench_seed`` and ``rank`` give the same emissions.
+
+The two generators here also keep their own log of every observation they
+emitted (which frame, reward, flags, and the action that answered it), and
+every emission names itself (`decode`), so that the rows a replay ring gathers
+or a rollout holds can be checked one by one against what the generator really
+produced.
 """
 from __future__ import annotations
 
+import importlib
 import json
 import os
 import time
@@ -29,8 +44,17 @@ REGISTRY: Dict[int, "SyntheticEnv"] = {}
 
 
 def load_mix(name: str) -> Dict[str, Any]:
-    with open(os.path.join(HERE, "traffic", f"{name}.json")) as f:
+    """A mix by its name (`perfbench/traffic/<name>.json`) or, for a mix that
+    lies elsewhere, by its file's path from the root of the checkout."""
+    path = os.path.join(os.path.dirname(HERE), name) if name.endswith(".json") else os.path.join(HERE, "traffic", f"{name}.json")
+    with open(path) as f:
         return json.load(f)
+
+
+def generator_of(mix: Dict[str, Any]) -> type:
+    """The class a mix names under `generator`."""
+    module, _, cls = str(mix["generator"]).rpartition(".")
+    return getattr(importlib.import_module(module), cls)
 
 
 def reset_registry() -> None:
@@ -195,3 +219,25 @@ class SyntheticEnv(gym.Env):
 
     def close(self):
         pass
+
+
+class VectorEnv(SyntheticEnv):
+    """The same generator for a mix without an image: every vector key's
+    first two entries are the env's index and the emission's number (float32
+    holds whole numbers up to 2**24 exactly), the rest seeded normals, so that
+    a row of a rollout names the emission it claims to be."""
+
+    def vector(self, key: str, n: int) -> np.ndarray:
+        vec = super().vector(key, n)
+        flat = vec.reshape(-1)
+        flat[0], flat[1] = float(self.index), float(n)
+        return vec
+
+    @staticmethod
+    def decode(vec: np.ndarray) -> tuple:
+        """(env index, emission) stamped into a vector."""
+        flat = np.asarray(vec).reshape(-1)
+        return int(flat[0]), int(flat[1])
+
+    def render(self):
+        return np.zeros((8, 8, 3), np.uint8)

@@ -4,4 +4,4 @@ from perfbench import span_reduce
 
 
 def read(ctx):
-    return span_reduce.idle_unattributed_pct()
+    return span_reduce.idle_unattributed_pct(ctx)

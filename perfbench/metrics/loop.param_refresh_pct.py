@@ -5,4 +5,4 @@ from perfbench import span_reduce
 
 
 def read(ctx):
-    return span_reduce.span_share_pct("Time/param_refresh")
+    return span_reduce.span_share_pct(ctx, "Time/param_refresh")

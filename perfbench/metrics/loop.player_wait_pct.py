@@ -4,4 +4,4 @@ from perfbench import span_reduce
 
 
 def read(ctx):
-    return span_reduce.span_share_pct("Wait/player_queue")
+    return span_reduce.span_share_pct(ctx, "Wait/player_queue")

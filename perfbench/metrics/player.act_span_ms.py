@@ -5,4 +5,4 @@ from perfbench import span_reduce
 
 
 def read(ctx):
-    return span_reduce.span_median_ms("Player/act")
+    return span_reduce.span_median_ms(ctx, "Player/act")

@@ -5,5 +5,4 @@ from perfbench import span_reduce
 
 
 def read(ctx):
-    return span_reduce.spans_ms_per_grad_step(
-        ("Time/learner_apply", "Time/replay_sync", "Time/replay_sample"), ctx["window"]["grad_steps"])
+    return span_reduce.spans_ms_per_grad_step(ctx, ("Time/learner_apply", "Time/replay_sync", "Time/replay_sample"))

@@ -1,9 +1,7 @@
-"""Device time of jit(train) in the traced window, per gradient step."""
+"""Device time of the step's programs (the adapter's `step_programs`) per
+gradient step, over their whole executions in the traced window."""
+from perfbench import span_reduce
 
 
 def read(ctx):
-    prog = ctx["trace"]["programs"].get("jit_train")
-    g = ctx["window"]["grad_steps"]
-    if not prog or g <= 0:
-        return None
-    return 1e3 * prog["seconds"] / g
+    return span_reduce.step_ms(ctx)

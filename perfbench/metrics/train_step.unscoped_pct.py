@@ -4,4 +4,4 @@ from perfbench import span_reduce
 
 
 def read(ctx):
-    return span_reduce.unscoped_pct()
+    return span_reduce.unscoped_pct(ctx)

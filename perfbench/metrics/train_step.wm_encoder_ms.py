@@ -1,7 +1,8 @@
-"""Device time, per gradient step, of the ops of jit(train) under the
-`jax.named_scope` "wm_encoder" (forward and backward) in the traced window."""
+"""Device time, per gradient step, of the ops of the step's programs under the
+`jax.named_scope` "wm_encoder" (forward and backward), over their whole executions
+in the traced window."""
 from perfbench import span_reduce
 
 
 def read(ctx):
-    return span_reduce.part_ms("wm_encoder", ctx["window"]["grad_steps"])
+    return span_reduce.part_ms(ctx, "wm_encoder")

@@ -1,4 +1,5 @@
-"""Overrides every cell gets, and the ones only the CPU rehearsal adds."""
+"""Overrides every cell gets. The ones only the CPU rehearsal adds are its
+algorithm's: `rehearsal_overrides` of the configuration's adapter."""
 
 # what keeps a run short and the checkout clean; none of it is in the steady loop
 COMMON_OVERRIDES = [
@@ -10,20 +11,13 @@ COMMON_OVERRIDES = [
     "algo.total_steps=1000000000",
     "algo.max_wall_time_s=330",  # a net under the run; the window closes it far sooner
 ]
-# the CPU rehearsal only: the same program at widths a CPU compiles in seconds
-REHEARSAL_OVERRIDES = [
-    "algo.per_rank_batch_size=4",
-    "algo.per_rank_sequence_length=8",
-    "algo.horizon=3",
-    "algo.dense_units=16",
-    "algo.mlp_layers=2",
-    "algo.world_model.encoder.cnn_channels_multiplier=2",
-    "algo.world_model.recurrent_model.recurrent_state_size=8",
-    "algo.world_model.transition_model.hidden_size=16",
-    "algo.world_model.representation_model.hidden_size=16",
-    "algo.world_model.discrete_size=4",
-    "algo.world_model.stochastic_size=4",
-    "buffer.size=4096",
-    "buffer.device_cache=true",
-    "algo.learning_starts=128",
-]
+
+
+def __getattr__(name: str):
+    # `tests/test_train_scopes.py` (outside the benchmark's own directories, so no benchmark PR may edit it) still
+    # imports DreamerV3's rehearsal overrides from here; they live with their adapter
+    if name == "REHEARSAL_OVERRIDES":
+        from .adapters.dreamer_v3 import rehearsal_overrides
+
+        return rehearsal_overrides
+    raise AttributeError(f"module {__name__!r} has no attribute {name!r}")

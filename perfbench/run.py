@@ -5,8 +5,13 @@
 Drives `sheeprl_tpu.cli.run` in this process with the cell's configuration
 and traffic mix, measures a window on the benchmark's own clock, compares what
 the timed path produced with the plain reference, and prints one JSON line.
-Everything that belongs to one configuration, one mix or one per-layer metric
-is in a file of its own, found by the name in BENCHMARK.json.
+Everything that belongs to one configuration, one mix, one per-layer metric or
+one algorithm is in a file of its own, found by name: the configuration's file
+by the benchmark file, the mix, the limits and the adapter by the
+configuration (`<home>/traffic/<mix>.json`, `<home>/limits/<config>.json`,
+`perfbench/adapters/<adapter>.py`; `<home>` is the directory above the
+configuration's), the generator by the mix. `--benchmark` names another
+benchmark file than the root's, for the tests.
 """
 from __future__ import annotations
 
@@ -30,7 +35,8 @@ ROOT = os.path.dirname(HERE)
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
-from perfbench.overrides import COMMON_OVERRIDES, REHEARSAL_OVERRIDES  # noqa: E402
+from perfbench import adapters  # noqa: E402
+from perfbench.overrides import COMMON_OVERRIDES  # noqa: E402
 
 
 def log(msg: str) -> None:
@@ -42,27 +48,32 @@ def load_json(*parts: str) -> Dict[str, Any]:
         return json.load(f)
 
 
-def load_cell(name: str) -> Dict[str, Any]:
-    bench = load_json(ROOT, "BENCHMARK.json")
+def load_cell(name: str, benchmark: str = "BENCHMARK.json") -> Dict[str, Any]:
+    bench = load_json(ROOT, benchmark)
     cell = next((w for w in bench["workloads"] if w["name"] == name), None)
     if cell is None:
-        raise SystemExit(f"no workload {name!r} in BENCHMARK.json")
+        raise SystemExit(f"no workload {name!r} in {benchmark}")
     config = next(c for c in bench["configs"] if c["name"] == cell["config"])
+    home = os.path.dirname(os.path.dirname(os.path.join(ROOT, config["file"])))
+    mix_file = os.path.join(home, "traffic", f"{cell['traffic']}.json")
     return {
         "bench": bench,
         "cell": cell,
         "config": load_json(ROOT, config["file"]),
-        "mix": load_json(HERE, "traffic", f"{cell['traffic']}.json"),
+        "mix": load_json(mix_file),
+        # how the generator is told its mix: by name under perfbench/, by path from the root elsewhere
+        "mix_ref": cell["traffic"] if home == HERE else os.path.relpath(mix_file, ROOT),
+        "limits_file": os.path.join(home, "limits", f"{config['name']}.json"),
     }
 
 
 def overrides_for(spec: Dict[str, Any], seed: int, rehearse: bool) -> List[str]:
     mix = spec["cell"]["traffic"]
-    wrapper = "{" + f"_target_: perfbench.envs.SyntheticEnv, mix: {mix}, seed: 0, rank: 0, bench_seed: {int(seed)}" + "}"
+    wrapper = "{" + f"_target_: {spec['mix']['generator']}, mix: {spec['mix_ref']}, seed: 0, rank: 0, bench_seed: {int(seed)}" + "}"
     out = list(spec["config"]["overrides"]) + list(spec["mix"]["overrides"]) + COMMON_OVERRIDES
     out += [f"env.wrapper={wrapper}", f"env.id=perfbench_{mix}", f"seed={int(seed) % 2147483647}"]
     if rehearse:
-        out += REHEARSAL_OVERRIDES
+        out += adapters.load(spec["config"]["adapter"]).rehearsal_overrides
     return out
 
 
@@ -110,9 +121,10 @@ def main(argv: Optional[List[str]] = None) -> int:
     ap.add_argument("--seed", type=int, required=True)
     ap.add_argument("--seconds", type=float, required=True)
     ap.add_argument("--trace", type=int, default=0, choices=(0, 1))
+    ap.add_argument("--benchmark", default="BENCHMARK.json", help="the benchmark file, from the root of the checkout")
     ap.add_argument("--rehearse-cpu", action="store_true",
                     help="tiny widths on whatever backend there is; prints no device metric")
-    ap.add_argument("--fault", default="", help="tests only: break the timed path (see tests/perfbench)")
+    ap.add_argument("--fault", default="", help="tests only: break the timed path through the adapter's `faults` (see tests/perfbench)")
     ap.add_argument("--control", default="", help="calibration only: run the program at this `fabric.precision` (its own lower-"
                     "precision path) in the configuration's place; PERF.md has what it then reads")
     ap.add_argument("--keep", default="", help="write the compared numbers and their detail into this directory")
@@ -121,7 +133,7 @@ def main(argv: Optional[List[str]] = None) -> int:
     if args.keep:
         args.keep = os.path.abspath(args.keep)
 
-    spec = load_cell(args.workload)
+    spec = load_cell(args.workload, args.benchmark)
     os.environ.setdefault("JAX_COMPILATION_CACHE_DIR", os.path.join(ROOT, ".xla_cache"))
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
 
@@ -138,6 +150,8 @@ def main(argv: Optional[List[str]] = None) -> int:
 
     from perfbench import check, envs, peaks, taps, work
     from sheeprl_tpu.cli import run as cli_run
+
+    adapter = adapters.load(spec["config"]["adapter"])
 
     if not args.rehearse_cpu:
         peaks.lookup(dev.device_kind)  # an unknown device kind is an error before any work
@@ -157,10 +171,8 @@ def main(argv: Optional[List[str]] = None) -> int:
     try:
         with contextlib.ExitStack() as stack:
             if args.fault:  # planted first, so that it lies beneath the harness's own wrappers
-                from perfbench import faults
-
-                stack.enter_context(faults.planted(args.fault))
-            stack.enter_context(run.installed())
+                stack.enter_context(adapter.faults(args.fault))
+            stack.enter_context(adapter.installed(run))
             stack.enter_context(contextlib.redirect_stdout(sys.stderr))
             cli_run(overrides)
         log("run returned")
@@ -168,12 +180,11 @@ def main(argv: Optional[List[str]] = None) -> int:
             raise RuntimeError("the run ended before the window closed")
         stats = dev.memory_stats() or {}
         peak_bytes = int(stats.get("peak_bytes_in_use", 0))
-        ring = type(run.prefetcher).__name__
         live_envs = dict(envs.REGISTRY)
         win = window_numbers(run, live_envs)
         num = {
             "window_s": win["seconds"], "env_steps": win["env_steps"], "grad_steps": win["grad_steps"],
-            "train_calls": win["train_calls"], "gaps": int(len(win["gaps_ms"])), "ring": ring,
+            "train_calls": win["train_calls"], "gaps": int(len(win["gaps_ms"])), **run.notes,
             "call_gap_s": win["call_gap_s"],
             "setup_copies_s": run.check_s,
         }
@@ -181,7 +192,6 @@ def main(argv: Optional[List[str]] = None) -> int:
 
         # the program's state goes before the reference comes
         run.guard = None
-        run.prefetcher = None
         gc.collect()
 
         metrics: Dict[str, Dict[str, Any]] = {}
@@ -205,10 +215,12 @@ def main(argv: Optional[List[str]] = None) -> int:
                 if e2e.get(m["name"]) is not None:
                     metrics[m["name"]] = {"value": e2e[m["name"]], "unit": m["unit"]}
         else:
-            from perfbench import trace_reduce
+            from perfbench import span_reduce, trace_reduce
 
             t0 = time.perf_counter()
-            reduced = trace_reduce.reduce_dir(trace_dir)
+            planes = trace_reduce.read_dir(trace_dir)  # the one parse: both reducers read it
+            reduced = trace_reduce.reduce_events(planes)
+            capture = span_reduce.Capture(planes, adapter.step_programs)
             log(f"trace reduced in {time.perf_counter() - t0:.1f}s: {reduced['n_device_events']} device events")
             if args.keep and args.keep_trace:
                 os.makedirs(args.keep, exist_ok=True)
@@ -217,7 +229,8 @@ def main(argv: Optional[List[str]] = None) -> int:
             ctx = {
                 "trace": reduced, "window": win, "envs": live_envs, "device_kind": dev.device_kind,
                 "peak_bytes": peak_bytes, "spec": spec, "work": work, "peaks": peaks,
-                "shapes": run.shapes, "rehearse": args.rehearse_cpu,
+                "shapes": run.shapes, "rehearse": args.rehearse_cpu, "adapter": adapter,
+                "trace_dir": trace_dir, "capture": capture,
             }
             for m in bench["per_layer"]:
                 if m.get("workloads") and args.workload not in m["workloads"]:
@@ -236,7 +249,7 @@ def main(argv: Optional[List[str]] = None) -> int:
             breakdown = None
 
         t0 = time.perf_counter()
-        compared, detail = check.decide(run, live_envs, spec)
+        compared, detail = check.decide(run, live_envs, spec, adapter)
         log(f"compared in {time.perf_counter() - t0:.1f}s")
         correct = all(c["ok"] for c in compared.values())
         if args.keep:

@@ -1,39 +1,36 @@
-"""What `trace_reduce` throws away, for the readers that name a host thread or
-a part of `jit(train)`: read from the same `*.xplane.pb` with
-`jax.profiler.ProfileData`, nothing else.
+"""For the readers that name a host thread or a part of the train step: a
+second look at the capture `trace_reduce.read_planes` parsed (once).
 
 Kept here: for every host event whose name starts with ``Time/``, ``Wait/``
 or ``Player/`` the line (thread) it lies on and its stats (the counts a
 program span carries: ``grad_steps``, ``burst``, ``version``, ``bytes``);
-and for every `XLA Ops` event that starts inside an execution of `jit_train`
-the part of the step it belongs to. PERF.md (section 3) says where a v5e
+and for every `XLA Ops` event that starts inside an execution of the step's
+programs (the adapter's `step_programs`; `jit_train` for DreamerV3) the part
+of the step it belongs to. PERF.md (section 3) says where a v5e
 capture keeps the HLO `op_name` (the stat `tf_op` of the event's metadata,
 which `ProfileData` does not hand out: `read_tf_ops` below) and how a fusion
 over two parts is named.
 
-`run.py` gives the readers no `trace_dir`; it calls them with the run's
-temporary directory as the working directory and the capture under
-``./trace``. `load()` looks there, once per process, and returns ``None``
-where there is no capture, so that every reader returns ``None`` too.
+`run.py` puts the `Capture` into the readers' `ctx` under ``"capture"``
+(``None`` where there is no capture, so that every reader returns ``None``
+too) beside ``"trace_dir"``. Times per gradient step divide by the executions
+of the step's programs that lie wholly inside the window, times the gradient
+steps one call takes: the train calls that RETURNED in the window are one
+fewer than the executions in some windows (PERF.md, Findings of PR 31).
 """
 from __future__ import annotations
 
-import os
 import re
-from typing import Any, Dict, List, Optional, Tuple
+from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
 from perfbench import trace_reduce as tr
 
-HOST_PREFIXES = ("Time/", "Wait/", "Player/")
-TRAIN_PROGRAM = "jit_train"
-# the `jax.named_scope` names of `make_train_fn`'s `one_step`
+# the `jax.named_scope` names of DreamerV3's `make_train_fn`'s `one_step`; a step without them reads as unscoped
 PARTS = ("wm_encoder", "wm_rssm", "wm_decoder", "wm_heads", "imagination", "actor", "critic", "optimizer")
 _PARTS = frozenset(PARTS)
 _WRAPPED = re.compile(r"[A-Za-z_]+\((.*)\)")  # jvp(..), transpose(..), jit(..)
-
-_CACHE: Dict[str, Optional["Capture"]] = {}
 
 
 def part_of(op_name: str) -> Optional[str]:
@@ -103,10 +100,11 @@ def _text(view: memoryview) -> str:
     return bytes(view).decode("utf-8", "replace")
 
 
-def read_tf_ops(path: str) -> Dict[str, str]:
+def read_tf_ops(path: str, prefer: Tuple[str, ...] = ("jit(train)",)) -> Dict[str, str]:
     """HLO text of an instruction (an `XLA Ops` event's name) -> its `tf_op`
     (`<op_name>:<op type>`), over the device planes of one capture. Where two
-    programs hold the same text, `jit(train)`'s entry is the one kept."""
+    programs hold the same text, the entry of `prefer` (the step's program,
+    as `jit(train)`) is the one kept."""
     with open(path, "rb") as f:
         data = memoryview(f.read())
     out: Dict[str, str] = {}
@@ -129,81 +127,50 @@ def read_tf_ops(path: str) -> Dict[str, str]:
                         stat = {snum: sval for snum, _, sval in _fields(val)}
                         if stat.get(1) in tf_op_ids:
                             tf_op = _text(stat[5]) if 5 in stat else stat_names.get(stat.get(7), "")
-                if tf_op and (text not in out or tf_op.startswith("jit(train)")):
+                if tf_op and (text not in out or tf_op.startswith(prefer)):
                     out[text] = tf_op
     return out
 
 
 class Capture:
-    """The window, the program's host spans by thread, and the ops of `jit_train` by part."""
+    """The window, the program's host spans by thread, and the ops of the step's programs by part."""
 
-    def __init__(self, path: str):
-        from jax.profiler import ProfileData
-
-        data = ProfileData.from_file(path)
-        self.host: List[Tuple[str, str, float, float, Dict[str, Any]]] = []  # name, thread, start, end, stats
-        marks: Dict[str, List[float]] = {tr.OPEN_MARK: [], tr.CLOSE_MARK: []}
-        dev_s: List[float] = []
-        dev_e: List[float] = []
-        train_runs: List[Tuple[float, float]] = []
-        ops: List[Tuple[str, float, float]] = []
-        for plane in data.planes:
-            is_device = plane.name.startswith("/device:TPU:") or plane.name.startswith("/device:GPU:")
-            is_host = plane.name.startswith("/host:CPU")
-            if not (is_device or is_host):
-                continue
-            for i, line in enumerate(plane.lines):
-                if is_host:
-                    thread = f"{line.name}#{i}"  # thread names repeat ("python3"): the line's place tells them apart
-                    for ev in line.events:
-                        name = ev.name
-                        if name in marks:
-                            marks[name].append(ev.start_ns)
-                        elif name.startswith(HOST_PREFIXES):
-                            self.host.append((name, thread, ev.start_ns, ev.start_ns + ev.duration_ns, dict(ev.stats)))
-                elif line.name == "XLA Modules":
-                    for ev in line.events:
-                        s, e = ev.start_ns, ev.start_ns + ev.duration_ns
-                        dev_s.append(s)
-                        dev_e.append(e)
-                        if tr.program_name(ev.name) == TRAIN_PROGRAM:
-                            train_runs.append((s, e))
-                elif line.name == "XLA Ops":
-                    for ev in line.events:
-                        s, e = ev.start_ns, ev.start_ns + ev.duration_ns
-                        dev_s.append(s)
-                        dev_e.append(e)
-                        ops.append((ev.name, s, e))
-        every = dev_s + dev_e + [t for _, _, s, e, _ in self.host for t in (s, e)]
-        self.w0 = min(marks[tr.OPEN_MARK]) if marks[tr.OPEN_MARK] else (min(every) if every else 0.0)
-        self.w1 = max(marks[tr.CLOSE_MARK]) if marks[tr.CLOSE_MARK] else (max(every) if every else 0.0)
+    def __init__(self, planes: Dict[str, Any], step_programs: Sequence[str] = ("jit_train",)):
+        self.step_programs = tuple(step_programs)
+        self.host = [ev for ev in planes["host"] if ev[0] not in (tr.OPEN_MARK, tr.CLOSE_MARK)]  # name, thread, start, end, stats
+        self.w0, self.w1, _ = tr.window_of(planes)
         self.window_s = (self.w1 - self.w0) * 1e-9
 
-        # device busy intervals in the window, as trace_reduce takes them
-        s = np.clip(np.asarray(dev_s, float), self.w0, self.w1)
-        e = np.clip(np.asarray(dev_e, float), self.w0, self.w1)
-        keep = e > s
-        _, self.busy_s, self.busy_e = tr.union_length(s[keep], e[keep])
+        # device busy intervals in the window, as trace_reduce takes them: those of the fullest plane
+        busy = tr.busy_by_plane(planes, self.w0, self.w1)
+        full = tr.fullest(busy)
+        _, self.busy_s, self.busy_e = busy[full] if full is not None else (0.0, np.zeros(0), np.zeros(0))
+        dev = planes["devices"].get(full, {"modules": [], "ops": []})
 
-        # ops of jit_train inside the window, by part; wrappers only hold the ops of a body
-        train_runs.sort()
+        # ops of the step's programs, by part, over the executions that lie wholly inside
+        # the window; wrappers only hold the ops of a body
+        train_runs = sorted((s, e) for n, s, e in dev["modules"] if tr.program_name(n) in self.step_programs)
         run_s = np.asarray([a for a, _ in train_runs], float)
         run_e = np.asarray([b for _, b in train_runs], float)
-        self.train_ops: List[Tuple[Optional[str], str, float]] = []  # part, op's short name, seconds in the window
+        whole = (run_s >= self.w0) & (run_e <= self.w1)
+        self.step_executions = int(whole.sum())
+        self.step_seconds = float(np.sum((run_e - run_s)[whole])) * 1e-9
+        self.train_ops: List[Tuple[Optional[str], str, float]] = []  # part, op's short name, seconds
         part_by_name: Dict[str, Optional[str]] = {}
-        tf_ops = read_tf_ops(path) if train_runs else {}
-        for name, s0, e0 in ops:
-            if not len(run_s) or e0 <= self.w0 or s0 >= self.w1:
-                continue
+        prefer = tuple("jit(" + p[len("jit_"):] + ")" for p in self.step_programs if p.startswith("jit_"))
+        tf_ops = read_tf_ops(planes["path"], prefer) if train_runs and planes.get("path") else {}
+        for name, s0, e0 in dev["ops"]:
+            if not len(run_s):
+                break
             j = int(np.searchsorted(run_s, s0, side="right")) - 1
-            if j < 0 or s0 >= run_e[j]:
+            if j < 0 or s0 >= run_e[j] or not whole[j]:
                 continue
             short = tr.short_name(name)
             if short.split(".", 1)[0] in tr.WRAPPERS:
                 continue
             if name not in part_by_name:
                 part_by_name[name] = part_of(tf_ops.get(name, ""))
-            self.train_ops.append((part_by_name[name], short, (min(e0, self.w1) - max(s0, self.w0)) * 1e-9))
+            self.train_ops.append((part_by_name[name], short, (e0 - s0) * 1e-9))
         self.scoped = any(p is not None for p, _, _ in self.train_ops)
         # a program that has the layer-boundary spans shows some in any capture; one of
         # them that did not occur in the window then reads 0, not "nothing to read"
@@ -243,34 +210,51 @@ class Capture:
         return out
 
 
-def load() -> Optional[Capture]:
-    """The capture under ./trace, parsed once per process; None where there is none."""
-    trace_dir = os.path.join(os.getcwd(), "trace")
-    if trace_dir not in _CACHE:
-        files = tr.find_xplanes(trace_dir)
-        _CACHE[trace_dir] = Capture(files[-1]) if files else None
-    return _CACHE[trace_dir]
+def steps_per_call(ctx: Dict[str, Any]) -> float:
+    """Gradient steps one train call takes in this window (1 in both accepted cells)."""
+    win = ctx["window"]
+    return win["grad_steps"] / win["train_calls"] if win.get("train_calls") else 1.0
 
 
 # -- what the metric files call ------------------------------------------------
-def span_share_pct(name: str) -> Optional[float]:
-    cap = load()
+def _idle_under(cap: Capture, intervals: List[Tuple[float, float]]) -> Tuple[float, float]:
+    """(the window's idle nanoseconds, those of them that the union of `intervals` covers), by interval intersection."""
+    gs, ge = cap.idle_intervals()
+    idle = float(np.sum(ge - gs))
+    clipped = [(max(s, cap.w0), min(e, cap.w1)) for s, e in intervals]
+    spanned, ms, me = tr.union_length(np.asarray([a for a, _ in clipped], float), np.asarray([b for _, b in clipped], float))
+    either, _, _ = tr.union_length(np.concatenate([gs, ms]), np.concatenate([ge, me]))
+    return idle, idle + spanned - either  # |idle and spanned| = |idle| + |spanned| - |idle or spanned|
+
+
+def span_share_pct(ctx: Dict[str, Any], name: str) -> Optional[float]:
+    cap = ctx.get("capture")
     if cap is None or cap.window_s <= 0:
         return None
     sec = cap.span_seconds(name)
     return None if sec is None else 100.0 * sec / cap.window_s
 
 
-def span_median_ms(name: str) -> Optional[float]:
-    cap = load()
+def span_idle_share_pct(ctx: Dict[str, Any], name: str) -> Optional[float]:
+    """Share of the window in which a span of that name is open AND the device
+    is idle, by interval intersection: who waits while nothing runs."""
+    cap = ctx.get("capture")
+    if cap is None or cap.window_s <= 0 or cap.span_seconds(name) is None:
+        return None
+    _, covered = _idle_under(cap, [(s, e) for _, s, e, _ in cap.spans(name)])
+    return 100.0 * covered * 1e-9 / cap.window_s
+
+
+def span_median_ms(ctx: Dict[str, Any], name: str) -> Optional[float]:
+    cap = ctx.get("capture")
     if cap is None:
         return None
     durs = [(e - s) * 1e-6 for _, s, e, _ in cap.spans(name) if s >= cap.w0 and e <= cap.w1]
     return float(np.median(durs)) if durs else None
 
 
-def spans_ms_per_grad_step(names: Tuple[str, ...], grad_steps: int) -> Optional[float]:
-    cap = load()
+def spans_ms_per_grad_step(ctx: Dict[str, Any], names: Tuple[str, ...]) -> Optional[float]:
+    cap, grad_steps = ctx.get("capture"), ctx["window"]["grad_steps"]
     if cap is None or grad_steps <= 0:
         return None
     found = [cap.span_seconds(n) for n in names]
@@ -279,17 +263,25 @@ def spans_ms_per_grad_step(names: Tuple[str, ...], grad_steps: int) -> Optional[
     return 1e3 * sum(f for f in found if f is not None) / grad_steps
 
 
-def part_ms(part: str, grad_steps: int) -> Optional[float]:
-    """Device time of `jit_train`'s ops under that scope, per gradient step;
-    None where the program has no scopes (the parent) or no capture."""
-    cap = load()
-    if cap is None or not cap.scoped or grad_steps <= 0:
+def step_ms(ctx: Dict[str, Any]) -> Optional[float]:
+    """Device time of the step's programs per gradient step, over their whole executions in the window."""
+    cap = ctx.get("capture")
+    if cap is None or cap.step_executions <= 0:
         return None
-    return 1e3 * cap.part_seconds().get(part, 0.0) / grad_steps
+    return 1e3 * cap.step_seconds / (cap.step_executions * steps_per_call(ctx))
 
 
-def unscoped_pct() -> Optional[float]:
-    cap = load()
+def part_ms(ctx: Dict[str, Any], part: str) -> Optional[float]:
+    """Device time of the step's ops under that scope, per gradient step;
+    None where the program has no scopes (the parent) or no capture."""
+    cap = ctx.get("capture")
+    if cap is None or not cap.scoped or cap.step_executions <= 0:
+        return None
+    return 1e3 * cap.part_seconds().get(part, 0.0) / (cap.step_executions * steps_per_call(ctx))
+
+
+def unscoped_pct(ctx: Dict[str, Any]) -> Optional[float]:
+    cap = ctx.get("capture")
     if cap is None or not cap.scoped:
         return None
     by_part = cap.part_seconds()
@@ -297,20 +289,13 @@ def unscoped_pct() -> Optional[float]:
     return 100.0 * by_part.get(None, 0.0) / total if total > 0 else None
 
 
-def idle_unattributed_pct() -> Optional[float]:
+def idle_unattributed_pct(ctx: Dict[str, Any]) -> Optional[float]:
     """Share of the window's idle time that no `Time/`, `Wait/` or `Player/`
     span on any thread covers, by interval intersection. None for a program
     without the layer-boundary spans: its two old ones would read a share too,
     but of another question (the parent of the PR that brought the rest)."""
-    cap = load()
+    cap = ctx.get("capture")
     if cap is None or not cap.instrumented:
         return None
-    gs, ge = cap.idle_intervals()
-    idle = float(np.sum(ge - gs))
-    if idle <= 0:
-        return None
-    inside = [(max(s, cap.w0), min(e, cap.w1)) for _, _, s, e, _ in cap.host if e > cap.w0 and s < cap.w1]
-    spanned, ms, me = tr.union_length(np.asarray([a for a, _ in inside], float), np.asarray([b for _, b in inside], float))
-    either, _, _ = tr.union_length(np.concatenate([gs, ms]), np.concatenate([ge, me]))
-    covered = idle + spanned - either  # |idle and spanned| = |idle| + |spanned| - |idle or spanned|
-    return 100.0 * (1.0 - covered / idle)
+    idle, covered = _idle_under(cap, [(s, e) for _, _, s, e, _ in cap.host if e > cap.w0 and s < cap.w1])
+    return 100.0 * (1.0 - covered / idle) if idle > 0 else None

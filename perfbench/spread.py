@@ -51,7 +51,8 @@ def main(argv) -> int:
                 if len(vals) >= 2:
                     q = statistics.quantiles(vals, n=4)
                     med = statistics.median(vals)
-                    print(f"  {k}: median {med:.6g} spread {(q[2] - q[0]) / med:.4%} min {min(vals):.6g} max {max(vals):.6g} n={len(vals)}")
+                    spread = f"{(q[2] - q[0]) / med:.4%}" if med else "-"  # a metric that reads 0 in every run has none
+                    print(f"  {k}: median {med:.6g} spread {spread} min {min(vals):.6g} max {max(vals):.6g} n={len(vals)}")
                 else:
                     print(f"  {k}: {vals}")
             worst = {}

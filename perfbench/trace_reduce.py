@@ -12,6 +12,13 @@ starts are nanoseconds on one clock.
 
 The window is what lies between the marks ``perfbench.window_open`` and
 ``perfbench.window_close``; without marks it is the whole capture.
+
+A capture is parsed ONCE (`read_planes`); `reduce_events` here and
+`span_reduce.Capture` both read that. Busy and idle time are taken per device
+plane. ``busy_s`` is the mean over the planes (what the result line's `device`
+reports); whatever belongs to one device (``busy_fullest_s``, the idle gaps,
+the programs' and ops' times) is that of the fullest plane, the device that
+was busy longest, which with one chip is the only one.
 """
 from __future__ import annotations
 
@@ -24,6 +31,7 @@ import numpy as np
 OPEN_MARK = "perfbench.window_open"
 CLOSE_MARK = "perfbench.window_close"
 SPAN_PREFIX = "Time/"
+HOST_PREFIXES = ("Time/", "Wait/", "Player/")  # what is kept of the host plane, with thread and stats
 WRAPPERS = ("while", "conditional", "call")  # ops that only wrap the ops of a body
 
 
@@ -55,53 +63,79 @@ def program_name(name: str) -> str:
 
 
 def read_planes(path: str) -> Dict[str, Any]:
+    """The one parse of a capture: per device plane the `XLA Modules` and
+    `XLA Ops` events as (name, start, end), and of the host plane every
+    `Time/`, `Wait/` and `Player/` span and the window marks as (name, thread,
+    start, end, stats)."""
     from jax.profiler import ProfileData
 
     data = ProfileData.from_file(path)
-    modules: List[Tuple[str, float, float]] = []
-    ops: List[Tuple[str, float, float]] = []
-    spans: List[Tuple[str, float, float]] = []
-    device_planes = 0
+    devices: Dict[str, Dict[str, List[Tuple[str, float, float]]]] = {}
+    host: List[Tuple[str, str, float, float, Dict[str, Any]]] = []
     for plane in data.planes:
         is_device = plane.name.startswith("/device:TPU:") or plane.name.startswith("/device:GPU:")
         is_host = plane.name.startswith("/host:CPU")
         if not (is_device or is_host):
             continue
-        device_planes += int(is_device)
-        for line in plane.lines:
-            if is_device and line.name not in ("XLA Modules", "XLA Ops"):
-                continue
-            target = modules if line.name == "XLA Modules" else ops
-            for ev in line.events:
-                name = ev.name
-                if is_host:
-                    if name.startswith(SPAN_PREFIX) or name in (OPEN_MARK, CLOSE_MARK):
-                        spans.append((name, ev.start_ns, ev.start_ns + ev.duration_ns))
-                else:
-                    target.append((name, ev.start_ns, ev.start_ns + ev.duration_ns))
-    return {"modules": modules, "ops": ops, "spans": spans, "device_planes": max(device_planes, 1)}
+        for i, line in enumerate(plane.lines):
+            if is_host:
+                thread = f"{line.name}#{i}"  # thread names repeat ("python3"): the line's place tells them apart
+                for ev in line.events:
+                    name = ev.name
+                    if name.startswith(HOST_PREFIXES):
+                        host.append((name, thread, ev.start_ns, ev.start_ns + ev.duration_ns, dict(ev.stats)))
+                    elif name in (OPEN_MARK, CLOSE_MARK):
+                        host.append((name, thread, ev.start_ns, ev.start_ns + ev.duration_ns, {}))
+            elif line.name in ("XLA Modules", "XLA Ops"):
+                dev = devices.setdefault(plane.name, {"modules": [], "ops": []})
+                target = dev["modules"] if line.name == "XLA Modules" else dev["ops"]
+                for ev in line.events:
+                    target.append((ev.name, ev.start_ns, ev.start_ns + ev.duration_ns))
+    return {"devices": devices, "host": host, "path": path}
+
+
+def window_of(planes: Dict[str, Any]) -> Tuple[float, float, bool]:
+    """(start, end, whether both marks were there) of the measured window."""
+    opens = [s for n, _, s, _, _ in planes["host"] if n == OPEN_MARK]
+    closes = [s for n, _, s, _, _ in planes["host"] if n == CLOSE_MARK]
+    every = [t for dev in planes["devices"].values() for _, s, e in dev["modules"] + dev["ops"] for t in (s, e)]
+    every += [t for n, _, s, e, _ in planes["host"] if n not in (OPEN_MARK, CLOSE_MARK) for t in (s, e)]
+    if not every and not (opens and closes):
+        return 0.0, 0.0, False
+    return (min(opens) if opens else min(every)), (max(closes) if closes else max(every)), bool(opens and closes)
+
+
+def busy_by_plane(planes: Dict[str, Any], w0: float, w1: float) -> Dict[str, Tuple[float, np.ndarray, np.ndarray]]:
+    """Per device plane: nanoseconds in which an op or a program ran inside the window, and the merged intervals."""
+    out = {}
+    for name, dev in planes["devices"].items():
+        events = [(s, e) for _, s, e in dev["ops"] + dev["modules"] if e > w0 and s < w1]
+        starts = np.clip(np.array([s for s, _ in events], float), w0, w1)
+        ends = np.clip(np.array([e for _, e in events], float), w0, w1)
+        out[name] = union_length(starts, ends)
+    return out
+
+
+def fullest(busy: Dict[str, Tuple[float, np.ndarray, np.ndarray]]) -> Optional[str]:
+    """The plane that was busy longest (the first of equals, by name)."""
+    return max(sorted(busy), key=lambda name: busy[name][0]) if busy else None
 
 
 def reduce_events(planes: Dict[str, Any]) -> Dict[str, Any]:
-    spans = planes["spans"]
-    opens = [s for n, s, _ in spans if n == OPEN_MARK]
-    closes = [s for n, s, _ in spans if n == CLOSE_MARK]
-    every = [t for _, s, e in planes["modules"] + planes["ops"] + spans for t in (s, e)]
-    if not every:
-        return {"window_s": 0.0, "busy_s": 0.0, "n_device_events": 0, "programs": {}, "top_ops": [],
-                "idle_gaps": [], "idle_by_span": {}, "spans_s": {}, "marked": False}
-    w0 = min(opens) if opens else min(every)
-    w1 = max(closes) if closes else max(every)
+    spans = [(n, s, e) for n, _, s, e, _ in planes["host"] if n.startswith(SPAN_PREFIX)]
+    w0, w1, marked = window_of(planes)
+    busy = busy_by_plane(planes, w0, w1)
+    full = fullest(busy)
+    if w1 <= w0 or (full is None and not spans):
+        return {"window_s": 0.0, "busy_s": 0.0, "busy_fullest_s": 0.0, "busy_by_plane_s": {}, "n_device_events": 0, "programs": {},
+                "top_ops": [], "idle_gaps": [], "idle_by_span": {}, "spans_s": {}, "marked": False}
 
     def clip(events):
-        out = [(n, max(s, w0), min(e, w1)) for n, s, e in events if e > w0 and s < w1]
-        return out
+        return [(n, max(s, w0), min(e, w1)) for n, s, e in events if e > w0 and s < w1]
 
-    modules, ops = clip(planes["modules"]), clip(planes["ops"])
-    starts = np.array([s for _, s, _ in ops] + [s for _, s, _ in modules], float)
-    ends = np.array([e for _, _, e in ops] + [e for _, _, e in modules], float)
-    busy_ns, ms, me = union_length(starts, ends)
-    n_dev = planes["device_planes"]
+    dev = planes["devices"].get(full, {"modules": [], "ops": []})
+    modules, ops = clip(dev["modules"]), clip(dev["ops"])
+    busy_ns, ms, me = busy[full] if full is not None else (0.0, np.zeros(0), np.zeros(0))
 
     programs: Dict[str, Dict[str, float]] = {}
     for n, s, e in modules:
@@ -118,7 +152,7 @@ def reduce_events(planes: Dict[str, Any]) -> Dict[str, Any]:
     top_ops = sorted(([k, v] for k, v in by_op.items()), key=lambda kv: -kv[1])
 
     # idle gaps inside the window, named by the program's host span over their middle
-    named = sorted(((n, s, e) for n, s, e in spans if n.startswith(SPAN_PREFIX)), key=lambda x: x[1])
+    named = sorted(spans, key=lambda x: x[1])
     gaps: List[Tuple[float, float]] = []
     edges_s = np.concatenate([[w0], me]) if len(me) else np.array([w0])
     edges_e = np.concatenate([ms, [w1]]) if len(ms) else np.array([w1])
@@ -151,14 +185,16 @@ def reduce_events(planes: Dict[str, Any]) -> Dict[str, Any]:
 
     return {
         "window_s": (w1 - w0) * 1e-9,
-        "busy_s": busy_ns * 1e-9 / n_dev,
-        "n_device_events": len(ops) + len(modules),
+        "busy_s": float(np.mean([b[0] for b in busy.values()])) * 1e-9 if busy else 0.0,
+        "busy_fullest_s": busy_ns * 1e-9,
+        "busy_by_plane_s": {name: b[0] * 1e-9 for name, b in busy.items()},
+        "n_device_events": sum(1 for d in planes["devices"].values() for _, s, e in d["ops"] + d["modules"] if e > w0 and s < w1),
         "programs": programs,
         "top_ops": top_ops[:20],
         "idle_gaps": gap_list[:20],
         "idle_by_span": idle_by_span,
         "spans_s": spans_s,
-        "marked": bool(opens and closes),
+        "marked": marked,
     }
 
 
@@ -166,8 +202,9 @@ def reduce_file(path: str) -> Dict[str, Any]:
     return reduce_events(read_planes(path))
 
 
-def reduce_dir(trace_dir: str) -> Dict[str, Any]:
+def read_dir(trace_dir: str) -> Dict[str, Any]:
+    """The parsed capture under a directory `jax.profiler` wrote."""
     files = find_xplanes(trace_dir)
     if not files:
         raise FileNotFoundError(f"no .xplane.pb under {trace_dir}")
-    return reduce_file(files[-1])
+    return read_planes(files[-1])

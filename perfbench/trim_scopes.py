@@ -16,40 +16,32 @@ from __future__ import annotations
 
 import json
 import os
-import shutil
 import sys
-import tempfile
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 NAME_CHARS, TF_OP_CHARS = 100, 110  # the instruction's own name and the scope components come first in both
 
 
-def readings(path: str) -> dict:
+def readings(path: str, step_programs=("jit_train",)) -> dict:
     """Every metric of BENCHMARK.json whose file reads through `span_reduce`, on one capture file."""
     from perfbench import span_reduce, trace_reduce
     from perfbench.run import load_json, metric_reader
 
-    executions = trace_reduce.reduce_file(path)["programs"].get(span_reduce.TRAIN_PROGRAM, {}).get("executions", 0)
-    ctx = {"window": {"grad_steps": executions}}  # one gradient step per execution in both cells
-    here = os.getcwd()
-    tmp = tempfile.mkdtemp(prefix="trim_scopes_")
-    try:
-        os.makedirs(os.path.join(tmp, "trace"))
-        shutil.copy(path, os.path.join(tmp, "trace", "cut.xplane.pb"))
-        os.chdir(tmp)
-        out = {}
-        for m in load_json(ROOT, "BENCHMARK.json")["per_layer"]:
-            with open(os.path.join(ROOT, "perfbench", "metrics", m["name"] + ".py")) as f:
-                if "span_reduce" in f.read():
-                    out[m["name"]] = metric_reader(m["name"])(ctx)
-        cap = span_reduce.load()
-        by_part = cap.part_seconds()
-        return {"grad_steps": executions, "window_s": cap.window_s, "metrics": out,
-                "part_seconds": {str(k): v for k, v in by_part.items()}}
-    finally:
-        os.chdir(here)
-        shutil.rmtree(tmp, ignore_errors=True)
+    planes = trace_reduce.read_planes(path)
+    reduced = trace_reduce.reduce_events(planes)
+    cap = span_reduce.Capture(planes, step_programs)
+    executions = sum(reduced["programs"].get(p, {}).get("executions", 0) for p in step_programs)
+    # one gradient step per execution in both cells
+    ctx = {"window": {"grad_steps": executions, "train_calls": executions}, "capture": cap, "trace": reduced}
+    out = {}
+    for m in load_json(ROOT, "BENCHMARK.json")["per_layer"]:
+        with open(os.path.join(ROOT, "perfbench", "metrics", m["name"] + ".py")) as f:
+            if "span_reduce" in f.read():
+                out[m["name"]] = metric_reader(m["name"])(ctx)
+    by_part = cap.part_seconds()
+    return {"grad_steps": executions, "whole_executions": cap.step_executions, "window_s": cap.window_s, "metrics": out,
+            "part_seconds": {str(k): v for k, v in by_part.items()}}
 
 
 def main(argv) -> int:
@@ -104,7 +96,7 @@ def main(argv) -> int:
                 t = start_ns(line, ev)
                 if t_open is not None and not (t_open - 0.05e9 <= t <= t_open + seconds * 1e9):
                     continue
-                if host and not (name.startswith(sr.HOST_PREFIXES) or name in (tr.OPEN_MARK, tr.CLOSE_MARK)):
+                if host and not (name.startswith(tr.HOST_PREFIXES) or name in (tr.OPEN_MARK, tr.CLOSE_MARK)):
                     continue
                 kept.append(ev)
             if line.name == "XLA Ops":

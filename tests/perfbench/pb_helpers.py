@@ -15,6 +15,24 @@ def bench():
 
 
 CELLS = [w["name"] for w in bench()["workloads"]]
+# a second algorithm through the harness's seam, as files alone: a benchmark file of the tests, in no benchmark
+PPO_BENCH = "tests/perfbench/fixtures/ppo_bench.json"
+PPO_CELL = "ppo_tiny.vec8"
+
+
+def mix_files():
+    """Every mix file there is: the benchmark's and the tests' fixtures'."""
+    import glob
+
+    return sorted(glob.glob(os.path.join(ROOT, "perfbench", "traffic", "*.json"))
+                  + glob.glob(os.path.join(ROOT, "tests", "perfbench", "fixtures", "traffic", "*.json")))
+
+
+def config_files():
+    import glob
+
+    return sorted(glob.glob(os.path.join(ROOT, "perfbench", "configs", "*.json"))
+                  + glob.glob(os.path.join(ROOT, "tests", "perfbench", "fixtures", "configs", "*.json")))
 
 
 def run_harness(*args, timeout=900):

@@ -1,8 +1,13 @@
 """The traffic generator: seeded, stamped, and its own log is enough to rebuild any row."""
+import inspect
+import json
+import os
+
 import numpy as np
 import pytest
 
-from perfbench.envs import REGISTRY, SyntheticEnv, load_mix
+from pb_helpers import ROOT, mix_files
+from perfbench.envs import REGISTRY, SyntheticEnv, VectorEnv, generator_of, load_mix, reset_registry
 
 
 @pytest.mark.parametrize("mix", ["crafter", "navigate4"])
@@ -55,3 +60,33 @@ def test_mix_files_state_what_the_cells_why_says(benchmark_json):
         mix = load_mix(cell["traffic"])
         assert f"{mix['num_envs']} env" in cell["why"]
         assert mix["episode_steps"] == 500 and mix["warmup_train_calls"] >= 4
+
+
+@pytest.mark.parametrize("path", mix_files(), ids=os.path.basename)
+def test_every_mix_names_a_generator_that_keeps_what_run_py_needs_of_it(path):
+    """The contract in `envs.py`'s docstring: constructor arguments, registration by env index, stamps per step(), seeded."""
+    with open(path) as f:
+        mix = json.load(f)
+    cls = generator_of(mix)
+    assert f"{cls.__module__}.{cls.__qualname__}" == mix["generator"]
+    assert list(inspect.signature(cls.__init__).parameters)[1:5] == ["mix", "seed", "rank", "bench_seed"]
+    reset_registry()
+    ref = os.path.relpath(path, ROOT) if "fixtures" in path else mix["name"]  # as run.py tells a generator its mix
+    a, b = cls(mix=ref, seed=0, rank=1, bench_seed=3000000019), cls(mix=ref, seed=5, rank=1, bench_seed=3000000019)
+    assert REGISTRY[1] is b and a.observation_space == b.observation_space
+    (oa, _), (ob, _) = a.reset(), b.reset()
+    for _ in range(3):
+        action = a.action_space.sample()
+        ra, rb = a.step(action), b.step(action)
+        assert all(np.array_equal(ra[0][k], rb[0][k]) for k in ra[0]) and ra[1:4] == rb[1:4]
+    assert len(a.t_enter) == len(a.t_exit) == len(a.self_s) == 3 and all(x <= y for x, y in zip(a.t_enter, a.t_exit))
+    assert all(np.array_equal(oa[k], ob[k]) for k in oa)
+
+
+def test_a_vector_emission_names_itself_and_is_rebuilt_from_the_log():
+    env = VectorEnv("tests/perfbench/fixtures/traffic/vec8.json", bench_seed=2**31 + 5, rank=1)
+    obs = [env.reset()[0]["state"]] + [env.step(0)[0]["state"] for _ in range(30)]
+    assert [VectorEnv.decode(o) for o in obs] == [(1, n) for n in range(31)]
+    assert all(o.dtype == np.float32 and o.shape == (8,) for o in obs)
+    assert all(np.array_equal(env.vector("state", n), obs[n]) for n in (0, 7, 30)) and len({o.tobytes() for o in obs}) == 31
+    assert env.log_final.index(True) == 23  # the mix's first episode of env 1

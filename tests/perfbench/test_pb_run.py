@@ -4,7 +4,7 @@ import json
 
 import pytest
 
-from pb_helpers import CELLS, run_harness
+from pb_helpers import CELLS, PPO_BENCH, PPO_CELL, run_harness
 
 CONTRACT_KEYS = ["correct", "attempted", "failed", "metrics", "device"]
 
@@ -61,4 +61,51 @@ def test_a_run_that_finds_no_tpu_exits_nonzero_and_prints_no_result():
 
 def test_an_unknown_workload_is_an_error():
     rc, out, _ = run_harness("--workload", "no.such", "--seed", "1", "--seconds", "1", "--trace", "0", timeout=120)
+    assert rc != 0 and out == []
+
+
+# -- a second algorithm through the same harness: `exp=ppo` through `cli.run`, as files alone --
+@pytest.fixture(scope="module")
+def ppo_rehearsal():
+    return run_harness("--benchmark", PPO_BENCH, "--workload", PPO_CELL, "--seed", "1", "--seconds", "2", "--trace", "1", "--rehearse-cpu")
+
+
+def test_a_cell_of_another_algorithm_runs_through_the_seam_and_is_correct(ppo_rehearsal):
+    rc, out, err = ppo_rehearsal
+    assert rc == 0, err[-3000:]
+    line = json.loads(out[-1])
+    assert list(line)[: len(CONTRACT_KEYS)] == CONTRACT_KEYS and list(line)[-1] == "compared"
+    assert line["correct"] is True and line["attempted"] > 0
+    assert {"rollout_wrong_rows", "update_early_steps", "update_late_steps", "update_gap", "advantages_gap", "values_gap",
+            "logprobs_gap", "loss_gap_policy", "loss_gap_value", "loss_gap_entropy"} == set(line["compared"])
+    assert "algo=ppo" in err and "weights from the seed: 18 leaves" in err
+
+
+def test_the_other_algorithms_window_is_the_same_window(ppo_rehearsal):
+    """One update a rollout of 16 steps x 2 envs, G = 1 epoch x 1 minibatch: the stamps, the counts and the capture agree."""
+    _, _, err = ppo_rehearsal
+    window = json.loads(next(ln for ln in err.splitlines() if "s window {" in ln).split("window ", 1)[1])
+    assert window["grad_steps"] == window["train_calls"] > 3 and (window["minibatches"], window["minibatch_rows"]) == (1, 32)
+    assert abs(window["env_steps"] - 32 * window["train_calls"]) <= 32 and 2.0 <= window["window_s"] < 4.0
+    assert "window opens after 3 train calls" in err and "trace written" in err and "trace reduced" in err
+
+
+def test_the_other_algorithm_agrees_with_its_reference_to_float32_rounding(ppo_rehearsal):
+    _, out, _ = ppo_rehearsal
+    gaps = {k: c["value"] for k, c in json.loads(out[-1])["compared"].items() if "_gap" in k}
+    assert len(gaps) == 7 and max(gaps.values()) < 1e-4, gaps
+
+
+def test_parameters_left_unchanged_make_the_other_algorithms_run_incorrect():
+    rc, out, err = run_harness("--benchmark", PPO_BENCH, "--workload", PPO_CELL, "--seed", "3000000019", "--seconds", "2",
+                               "--trace", "0", "--rehearse-cpu", "--fault", "unchanged")
+    assert rc == 0, err[-3000:]
+    line = json.loads(out[-1])
+    failed = [k for k, c in line["compared"].items() if not c["value"] <= c["limit"]]
+    assert line["correct"] is False and failed == ["update_gap"] and line["compared"]["update_gap"]["value"] == 1.0
+    assert "<-- FAILS" in err
+
+
+def test_a_cell_of_the_fixture_is_not_in_the_benchmark_and_the_default_file_is_the_roots():
+    rc, out, _ = run_harness("--workload", PPO_CELL, "--seed", "1", "--seconds", "1", "--trace", "0", timeout=120)
     assert rc != 0 and out == []

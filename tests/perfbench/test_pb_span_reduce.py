@@ -4,7 +4,6 @@ read on it kept beside it) and the accepted fixture of the parent's run, which
 has neither scopes nor the layer-boundary spans."""
 import json
 import os
-import shutil
 
 import pytest
 
@@ -20,46 +19,91 @@ with open(os.path.join(HERE, "fixtures", "chip_v5e_scopes.json")) as _f:
 NEW = sorted(WANT["metrics"])
 
 
-def as_run_py_leaves_it(capture, tmp_path):
-    """The capture under ./trace of the working directory, which is all a reader is told."""
-    os.makedirs(tmp_path / "trace" / "plugins" / "profile" / "run")
-    shutil.copy(capture, tmp_path / "trace" / "plugins" / "profile" / "run" / "host.xplane.pb")
+def ctx_of(path, grad_steps):
+    """What `run.py` hands a reader of a capture: the one parse, reduced both ways."""
+    planes = trace_reduce.read_planes(path)
+    return {"window": {"grad_steps": grad_steps, "train_calls": grad_steps}, "trace": trace_reduce.reduce_events(planes),
+            "capture": span_reduce.Capture(planes, ("jit_train",)), "trace_dir": os.path.dirname(path)}
 
 
-def test_the_fixture_names_every_metric_this_pr_added():
+@pytest.fixture(scope="module")
+def scopes_ctx():
+    return ctx_of(SCOPES, WANT["grad_steps"])
+
+
+@pytest.fixture(scope="module")
+def parent_ctx():
+    return ctx_of(PARENT, 3)
+
+
+def test_the_fixture_names_every_metric_that_reads_through_span_reduce():
     entries = {m["name"]: m for m in bench()["per_layer"]}
-    assert len(NEW) == 14 and set(NEW) <= set(entries)
+    # PR 28's fourteen, `loop.learner_wait_pct` replaced by `loop.learner_wait_idle_pct`, and `train_step.device_ms`
+    assert len(NEW) == 15 and set(NEW) <= set(entries) and "loop.learner_wait_pct" not in entries
     assert all(entries[n]["workloads"] == ["dv3_xl.crafter", "dv3_l.navigate4"] for n in NEW)
     assert os.path.getsize(SCOPES) < 400_000
 
 
 @pytest.mark.parametrize("metric", NEW)
-def test_reader_reads_the_recorded_number_on_the_cut_of_this_prs_chip_run(metric, tmp_path):
-    as_run_py_leaves_it(SCOPES, tmp_path)
-    got = metric_reader(metric)({"window": {"grad_steps": WANT["grad_steps"]}})
+def test_reader_reads_the_recorded_number_on_the_cut_of_this_prs_chip_run(metric, scopes_ctx):
+    got = metric_reader(metric)(scopes_ctx)
     assert got == pytest.approx(WANT["metrics"][metric], rel=1e-9, abs=1e-12)
 
 
 @pytest.mark.parametrize("metric", NEW)
-def test_reader_returns_none_on_the_parents_capture_and_where_there_is_no_capture(metric, tmp_path):
-    assert metric_reader(metric)({"window": {"grad_steps": 3}}) is None  # no ./trace at all
-    other = tmp_path / "parent"
-    other.mkdir()
-    os.chdir(other)  # another working directory: the capture is looked up and memoised by it
-    as_run_py_leaves_it(PARENT, other)
-    assert metric_reader(metric)({"window": {"grad_steps": 3}}) is None
+def test_reader_returns_none_on_the_parents_capture_and_where_there_is_no_capture(metric, parent_ctx):
+    assert metric_reader(metric)({"window": {"grad_steps": 3, "train_calls": 3}, "capture": None}) is None
+    if metric == "train_step.device_ms":  # the step's program is in any capture of a run; PR 27's reader read it there too
+        assert metric_reader(metric)(parent_ctx) == pytest.approx(175.97, abs=0.05)
+    else:
+        assert metric_reader(metric)(parent_ctx) is None
 
 
-def test_the_capture_is_parsed_once_per_process_and_working_directory(tmp_path):
-    as_run_py_leaves_it(SCOPES, tmp_path)
-    assert span_reduce.load() is span_reduce.load()
+def test_the_capture_is_parsed_once_and_both_reducers_read_that(monkeypatch):
+    from jax.profiler import ProfileData
+
+    parses = []
+    real = ProfileData.from_file
+    monkeypatch.setattr(ProfileData, "from_file", staticmethod(lambda path: parses.append(path) or real(path)))
+    ctx = ctx_of(SCOPES, WANT["grad_steps"])
+    for name in NEW + ["device.idle_pct", "replay.ring_ms", "loop.train_span_pct"]:  # every reader of the capture
+        metric_reader(name)(ctx)
+    assert parses == [SCOPES]
+
+
+def test_learner_wait_counts_only_while_the_device_is_idle(scopes_ctx):
+    cap = scopes_ctx["capture"]
+    idle_pct = metric_reader("device.idle_pct")(scopes_ctx)
+    for name in ("Wait/learner_queue", "Wait/player_queue", "Time/param_refresh"):
+        both, alone = span_reduce.span_idle_share_pct(scopes_ctx, name), span_reduce.span_share_pct(scopes_ctx, name)
+        assert 0.0 <= both <= min(alone, idle_pct) + 1e-9, name
+    # the refresh of that run held the device idle (PERF.md, PR 28): nearly all of its idle time lies under it
+    assert span_reduce.span_idle_share_pct(scopes_ctx, "Time/param_refresh") > 0.9 * idle_pct
+    assert cap.window_s == pytest.approx(WANT["window_s"])
+
+
+def test_times_per_step_divide_by_the_whole_executions_in_the_window(scopes_ctx):
+    cap = scopes_ctx["capture"]
+    assert cap.step_executions == WANT["whole_executions"] == 3
+    assert metric_reader("train_step.device_ms")(scopes_ctx) == pytest.approx(1e3 * cap.step_seconds / 3)
+    # a window that cuts an execution: neither its time nor its ops count, and the reading stays the step's
+    planes = trace_reduce.read_planes(SCOPES)
+    runs = sorted((s, e) for n, s, e in next(iter(planes["devices"].values()))["modules"] if n.startswith("jit_train"))
+    cut = 0.5 * (runs[-1][0] + runs[-1][1])
+    planes["host"] = [ev for ev in planes["host"] if ev[0] != trace_reduce.CLOSE_MARK] + [(trace_reduce.CLOSE_MARK, "t#0", cut, cut, {})]
+    short = span_reduce.Capture(planes)
+    assert short.step_executions == 2
+    ctx = {**scopes_ctx, "capture": short, "window": {"grad_steps": 2, "train_calls": 2}}
+    assert metric_reader("train_step.device_ms")(ctx) == pytest.approx(WANT["metrics"]["train_step.device_ms"], rel=0.01)
+    # (the fixture keeps every k-th op, so an execution's share of the kept ops varies; half an execution more would read 1.25x)
+    assert metric_reader("train_step.wm_rssm_ms")(ctx) == pytest.approx(WANT["metrics"]["train_step.wm_rssm_ms"], rel=0.1)
 
 
 def test_ops_are_booked_to_parts_by_the_tf_op_of_their_metadata():
     tf_ops = span_reduce.read_tf_ops(SCOPES)
     assert sum(v.startswith("jit(train)/") for v in tf_ops.values()) > 400  # the gather's and the scatter's few beside them
     assert any("transpose(jvp(wm_rssm))" in v for v in tf_ops.values())  # the backward of a part keeps its name
-    cap = span_reduce.Capture(SCOPES)
+    cap = span_reduce.Capture(trace_reduce.read_planes(SCOPES))
     by_part = cap.part_seconds()
     assert cap.scoped and set(by_part) == set(span_reduce.PARTS) | {None}
     assert by_part == pytest.approx({(None if k == "None" else k): v for k, v in WANT["part_seconds"].items()}, rel=1e-9)
@@ -71,7 +115,7 @@ def test_ops_are_booked_to_parts_by_the_tf_op_of_their_metadata():
 
 
 def test_host_spans_keep_their_thread_and_their_counts():
-    cap = span_reduce.Capture(SCOPES)
+    cap = span_reduce.Capture(trace_reduce.read_planes(SCOPES))
     learner = cap.learner_thread()
     threads = {th for _, th, *_ in cap.host}
     assert len(threads) == 2 and learner in threads  # two lines, both named python3
@@ -80,7 +124,7 @@ def test_host_spans_keep_their_thread_and_their_counts():
     refresh = cap.spans("Time/param_refresh")
     assert refresh and all(st["bytes"] == 822949460 and st["leaves"] == 125 for *_, st in refresh)
     assert all("grad_steps" in st and "burst" in st for *_, st in cap.spans("Time/train_time"))
-    assert cap.instrumented and not span_reduce.Capture(PARENT).instrumented
+    assert cap.instrumented and not span_reduce.Capture(trace_reduce.read_planes(PARENT)).instrumented
     # the accepted reducer still finds its two spans under their bare names, counts and all
     assert {"Time/train_time", "Time/env_interaction_time"} <= set(trace_reduce.reduce_file(SCOPES)["spans_s"])
 

@@ -26,7 +26,8 @@ def rehearsal(tmp_path_factory):
     path = glob.glob(os.path.join(keep, "*.xplane.pb"))[0]
     with open(glob.glob(os.path.join(keep, "*_t1.json"))[0]) as f:
         window = json.load(f)["window"]
-    return span_reduce.Capture(path), trace_reduce.reduce_file(path), window
+    planes = trace_reduce.read_planes(path)
+    return span_reduce.Capture(planes), trace_reduce.reduce_events(planes), window
 
 
 def test_the_steady_loops_spans_are_all_in_the_window_under_their_bare_names(rehearsal):
@@ -70,14 +71,16 @@ def test_counts_on_the_spans_add_up_to_the_windows_work(rehearsal):
     assert all(st["rows"] >= 1 and st["bytes"] > 0 for *_, st in cap.spans("Time/replay_sync"))
 
 
-def test_new_readers_read_the_rehearsals_capture(rehearsal, tmp_path, monkeypatch):
+def test_new_readers_read_the_rehearsals_capture(rehearsal):
     cap, _, window = rehearsal
-    monkeypatch.setitem(span_reduce._CACHE, os.path.join(str(tmp_path), "trace"), cap)
-    assert 0 < span_reduce.span_share_pct("Time/param_refresh") <= 100
-    assert span_reduce.span_median_ms("Player/act") > 0
-    assert span_reduce.spans_ms_per_grad_step(("Time/learner_apply", "Time/replay_sync", "Time/replay_sample"), window["grad_steps"]) > 0
-    assert 0 <= span_reduce.span_share_pct("Wait/learner_queue") <= 100  # 0.0 where the learner never waited
-    assert span_reduce.part_ms("wm_encoder", window["grad_steps"]) is None  # the CPU's plane has no `XLA Ops` line
+    ctx = {"capture": cap, "window": window}
+    assert 0 < span_reduce.span_share_pct(ctx, "Time/param_refresh") <= 100
+    assert span_reduce.span_median_ms(ctx, "Player/act") > 0
+    assert span_reduce.spans_ms_per_grad_step(ctx, ("Time/learner_apply", "Time/replay_sync", "Time/replay_sample")) > 0
+    assert 0 <= span_reduce.span_share_pct(ctx, "Wait/learner_queue") <= 100  # 0.0 where the learner never waited
+    # no device plane in a CPU capture: the whole window is idle, so the wait while idle is the wait
+    assert span_reduce.span_idle_share_pct(ctx, "Wait/learner_queue") == pytest.approx(span_reduce.span_share_pct(ctx, "Wait/learner_queue"))
+    assert span_reduce.part_ms(ctx, "wm_encoder") is None and span_reduce.step_ms(ctx) is None  # the CPU's plane has no `XLA Ops` line
 
 
 def test_every_span_of_the_table_occurs_and_the_engines_stalls_are_the_trackers():
@@ -103,7 +106,7 @@ def test_every_span_of_the_table_occurs_and_the_engines_stalls_are_the_trackers(
         ])
     finally:
         jax.profiler.stop_trace()
-    cap = span_reduce.load()
+    cap = span_reduce.Capture(trace_reduce.read_dir("trace"))
     assert sorted(set(n for n, *_ in cap.host)) == sorted(SPAN_SCHEMAS)
     learner = cap.learner_thread()
     player = {th for n, th, *_ in cap.host if n.startswith("Player/")}

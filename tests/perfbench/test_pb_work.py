@@ -52,14 +52,38 @@ def test_ring_rows_are_what_the_issue_reckoned():
 @pytest.mark.parametrize("cell", CELLS)
 def test_cell_keeps_more_than_the_floor_by_eval_shape(cell):
     """state + ring >= floor, over `jax.eval_shape` of the program's own build_agent."""
-    from perfbench.check import program_shapes
+    from perfbench import adapters
     from perfbench.run import load_cell
 
     spec = load_cell(cell)
     mix = spec["mix"]
-    cfg, shapes = program_shapes(spec)
+    adapter = adapters.load(spec["config"]["adapter"])
+    cfg, shapes = adapter.program_shapes(spec)
     actions = int(mix["action"]["n"])
-    kept = work.kept_bytes(shapes, mix, int(cfg.buffer.size), actions)
+    kept = adapter.kept_bytes(shapes, spec)
+    assert kept == work.kept_bytes(shapes, mix, int(cfg.buffer.size), actions)
     assert kept["ring"] == int(cfg.buffer.size) * mix["num_envs"] * work.row_bytes(work.ring_items(mix, actions))
     assert kept["ring"] <= float(cfg.buffer.device_cache_max_bytes)  # so that `auto` puts the ring on the chip
     assert kept["total"] >= 1.05 * FLOOR_BYTES, kept
+
+
+def test_the_whole_steps_flops_and_kept_bytes_come_from_the_cells_adapter():
+    """`train_step.mfu` stands in every cell under the one name: each adapter counts its own step."""
+    from pb_helpers import PPO_BENCH, PPO_CELL
+    from perfbench import adapters
+    from perfbench.run import load_cell
+
+    spec = load_cell(PPO_CELL, PPO_BENCH)
+    adapter = adapters.load(spec["config"]["adapter"])
+    _, shapes = adapter.program_shapes(spec)
+    # encoder 8x64 + 64x64 + 64x64, two trunks of 64x64 + 64x64, the critic's 64x1, the head's 64x4: multiply-adds a row
+    macs = 8 * 64 + 2 * 64 * 64 + 2 * (2 * 64 * 64) + 64 + 64 * 4
+    assert adapter.step_flops(shapes, spec) == {"total": 6.0 * macs * 32}
+    values = sum(int(np.prod(s)) for s, _ in shapes.values())
+    assert adapter.kept_bytes(shapes, spec) == {"params": 4.0 * values, "adam": 8.0 * values, "total": 12.0 * values}
+    dv3 = adapters.load("dreamer_v3")
+    spec = load_cell(CELLS[0])
+    _, shapes = dv3.program_shapes(spec)
+    w = spec["config"]["widths"]
+    assert dv3.step_flops(shapes, spec) == work.train_step_flops(shapes, w["per_rank_sequence_length"], w["per_rank_batch_size"], w["horizon"])
+    assert dv3.step_flops(shapes, spec)["total"] == pytest.approx(8.86e12, rel=0.005)  # PERF.md section 4
