@@ -6,7 +6,7 @@ import importlib
 from types import ModuleType
 
 CONTRACT = ("installed", "program_shapes", "seed_weights", "decide", "step_flops", "kept_bytes", "step_programs",
-            "rehearsal_overrides", "rehearse", "faults")
+            "rehearsal_overrides", "rehearse", "faults", "widths_of", "compared_numbers", "fault_kinds")
 
 
 def load(name: str) -> ModuleType:

@@ -50,6 +50,17 @@ from ..taps import Run, flat_names
 
 CHECK_STEPS = 3  # the reference follows the first three gradient steps
 GROUPS = ("wm", "actor", "critic")
+# every name `decide` may return: a limits file names none but these
+compared_numbers = frozenset(
+    {f"{kind}_{g}" for g in GROUPS for kind in ("loss1_gap", "loss_gap", "grad_gap", "grad_mid", "update_gap", "update_mid")}
+    | {"replay_wrong_rows", "ratio_early_steps", "ratio_late_steps"})
+# the faults `faults(kind)` plants, each with the prefixes of the numbers of which one has to fail
+fault_kinds = {
+    "unchanged": ("update_",),                 # a step that returns its state unchanged
+    "unchanged_actor": ("update_gap_actor",),  # the same of the actor alone: no other group's number sees it
+    "half_batch": ("grad_", "loss"),           # half of the batch left out, the mean taken over the rest
+    "altered_batch": ("replay_wrong_rows",),   # a gathered row altered where it is produced
+}
 step_programs = ("jit_train",)  # the device programs that are the train step
 # the CPU rehearsal only: the same program at widths a CPU compiles in seconds
 rehearsal_overrides = [
@@ -190,6 +201,22 @@ def sizes_for(cfg: Any, mix: Dict[str, Any]) -> reference.Sizes:
     )
 
 
+def widths_of(cfg: Any) -> Dict[str, int]:
+    """The keys of a configuration file's `widths`, from the composed config the program runs with."""
+    a = cfg.algo
+    wm = a.world_model
+    return {
+        "dense_units": int(a.dense_units), "mlp_layers": int(a.mlp_layers),
+        "recurrent_state_size": int(wm.recurrent_model.recurrent_state_size),
+        "cnn_channels_multiplier": int(wm.encoder.cnn_channels_multiplier),
+        "stochastic_size": int(wm.stochastic_size), "discrete_size": int(wm.discrete_size),
+        "transition_hidden_size": int(wm.transition_model.hidden_size),
+        "representation_hidden_size": int(wm.representation_model.hidden_size),
+        "horizon": int(a.horizon), "per_rank_sequence_length": int(a.per_rank_sequence_length),
+        "per_rank_batch_size": int(a.per_rank_batch_size),
+        "reward_bins": int(wm.reward_model.bins), "critic_bins": int(a.critic.bins),
+    }
+
 
 def _spaces(mix: Dict[str, Any]):
     import gymnasium as gym
@@ -222,9 +249,13 @@ def program_shapes(spec: Dict[str, Any], rehearse: bool = False) -> Tuple[Any, D
 
 
 def step_flops(shapes: Dict[str, Any], spec: Dict[str, Any]) -> Dict[str, float]:
-    """FLOPs of one gradient step at the configuration's stated [T, B] and horizon, by part and under 'total'."""
+    """FLOPs of one gradient step at the configuration's stated [T, B] and horizon, by part and under 'total';
+    under 'per_env_step' the player's forward for one env step of one env (0.014 % of the window's FLOPs at
+    `dv3_xl.crafter`, 0.5 % at `dv3_l.navigate4`, 64 env steps a gradient step)."""
     w = spec["config"]["widths"]
-    return work.train_step_flops(shapes, int(w["per_rank_sequence_length"]), int(w["per_rank_batch_size"]), int(w["horizon"]))
+    out = work.train_step_flops(shapes, int(w["per_rank_sequence_length"]), int(w["per_rank_batch_size"]), int(w["horizon"]))
+    out["per_env_step"] = work.act_flops(shapes)
+    return out
 
 
 def kept_bytes(shapes: Dict[str, Any], spec: Dict[str, Any]) -> Dict[str, float]:

@@ -38,6 +38,12 @@ CHECK_CALLS = 1  # the reference follows the first update: the only rollout acte
 step_programs = ("jit_update",)  # the device program that is the train step
 # the CPU rehearsal only: widths a CPU compiles in seconds
 rehearsal_overrides = ["algo.dense_units=16", "algo.mlp_layers=2", "algo.encoder.mlp_features_dim=16"]
+# every name `decide` may return: a limits file names none but these
+compared_numbers = frozenset({
+    "rollout_wrong_rows", "update_early_steps", "update_late_steps", "values_gap", "logprobs_gap", "advantages_gap",
+    "loss_gap_policy", "loss_gap_value", "loss_gap_entropy", "update_gap", "update_mid"})
+# the fault `faults(kind)` plants, with the prefixes of the numbers of which one has to fail
+fault_kinds = {"unchanged": ("update_gap",)}  # an update that returns its parameters unchanged
 
 
 # -- the taps ------------------------------------------------------------------------
@@ -125,6 +131,15 @@ def sizes_for(cfg: Any, mix: Dict[str, Any], minibatches: int, minibatch_rows: i
     )
 
 
+def widths_of(cfg: Any) -> Dict[str, int]:
+    """The keys of a configuration file's `widths`, from the composed config the program runs with."""
+    a = cfg.algo
+    return {
+        "dense_units": int(a.dense_units), "mlp_layers": int(a.mlp_layers), "mlp_features_dim": int(a.encoder.mlp_features_dim),
+        "rollout_steps": int(a.rollout_steps), "per_rank_batch_size": int(a.per_rank_batch_size), "update_epochs": int(a.update_epochs),
+    }
+
+
 def program_shapes(spec: Dict[str, Any], rehearse: bool = False) -> Tuple[Any, Dict[str, Tuple[Tuple[int, ...], Any]]]:
     """(composed config, {leaf name: (shape, dtype)}) of a cell, by
     `jax.eval_shape` over the program's own `build_agent`."""
@@ -143,12 +158,14 @@ def program_shapes(spec: Dict[str, Any], rehearse: bool = False) -> Tuple[Any, D
 
 
 def step_flops(shapes: Dict[str, Any], spec: Dict[str, Any]) -> Dict[str, float]:
-    """FLOPs of one gradient step (one minibatch): every kernel is a matmul
-    over the minibatch's rows, forward 2 FLOP a multiply-add and backward
-    twice the forward."""
+    """FLOPs of one gradient step (one minibatch) under 'total': every kernel
+    is a matmul over the minibatch's rows, forward 2 FLOP a multiply-add and
+    backward twice the forward. Under 'per_env_step' the player's forward for
+    one env step, one row through every kernel: an on-policy loop acts for
+    every row it trains on, so the whole step's share of the peak counts it."""
     rows = int(spec["config"]["widths"]["per_rank_batch_size"])
     macs = sum(float(np.prod(shape)) for name, (shape, _) in shapes.items() if name.endswith("/kernel"))
-    return {"total": 6.0 * macs * rows}
+    return {"total": 6.0 * macs * rows, "per_env_step": 2.0 * macs}
 
 
 def kept_bytes(shapes: Dict[str, Any], spec: Dict[str, Any]) -> Dict[str, float]:

@@ -178,8 +178,8 @@ def main(argv: Optional[List[str]] = None) -> int:
         log("run returned")
         if run.t_close is None:
             raise RuntimeError("the run ended before the window closed")
-        stats = dev.memory_stats() or {}
-        peak_bytes = int(stats.get("peak_bytes_in_use", 0))
+        # the fullest of the chips the cell asks for: what the driver's floor reads
+        peak_bytes = max(int((d.memory_stats() or {}).get("peak_bytes_in_use", 0)) for d in devices[:chips])
         live_envs = dict(envs.REGISTRY)
         win = window_numbers(run, live_envs)
         num = {

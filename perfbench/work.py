@@ -18,6 +18,10 @@ and the losses are not counted. Which rows go through which weights:
 * critic loss: backward over horizon * T*B rows (its forward is the one
   counted above).
 
+`act_flops` counts the player's forward for one env step of one env: one row
+through the encoder, the recurrent model, the posterior head and the actor
+(what `make_player`'s step applies), 2 FLOP a multiply-add.
+
 `gather_bytes` is what a [G, T, B] gather must move: every row read once from
 the ring and written once into the batch.
 """
@@ -74,6 +78,15 @@ def train_step_flops(shapes: Dict[str, Any], T: int, B: int, horizon: int, image
             add("critic", 2.0 * macs * horizon * rows)
     out["total"] = float(sum(out.values()))
     return out
+
+
+PLAYER_READS = ("wm/encoder/", "wm/rssm/recurrent_model/", "wm/rssm/representation/", "actor/")
+
+
+def act_flops(shapes: Dict[str, Any], image_side: int = 64) -> float:
+    """FLOPs of the player's forward for one env step: one row through every kernel it reads."""
+    return float(sum(2.0 * _macs(name, tuple(shape), image_side)[0] for name, (shape, _) in shapes.items()
+                     if name.endswith("/kernel") and name.startswith(PLAYER_READS)))
 
 
 def row_bytes(batch_items: Dict[str, Tuple[Tuple[int, ...], Any]]) -> int:

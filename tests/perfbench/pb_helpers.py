@@ -4,20 +4,31 @@ import os
 import subprocess
 import sys
 
+import pytest
+
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
 
+# the benchmark file every test here reads: the root's, or the one `PB_BENCHMARK` names (a path from the root or an
+# absolute one). `test_pb_addition.py` sets it to run these very tests over a file that has grown by a cell
+BENCH_FILE = os.environ.get("PB_BENCHMARK", "BENCHMARK.json")
+
+
 def bench():
-    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+    with open(os.path.join(ROOT, BENCH_FILE)) as f:
         return json.load(f)
 
 
 CELLS = [w["name"] for w in bench()["workloads"]]
+# the cells a test drives are found by name, never by their place in the list: a later PR may add one anywhere
+XL_CELL, L_CELL = "dv3_xl.crafter", "dv3_l.navigate4"
 # a second algorithm through the harness's seam, as files alone: a benchmark file of the tests, in no benchmark
 PPO_BENCH = "tests/perfbench/fixtures/ppo_bench.json"
 PPO_CELL = "ppo_tiny.vec8"
+# `parametrize("cell,bench_file", ANY_CELLS)`: every cell the any-cell checks of `pb_checks.py` run over
+ANY_CELLS = [pytest.param(c, BENCH_FILE, id=c) for c in CELLS] + [pytest.param(PPO_CELL, PPO_BENCH, id=PPO_CELL)]
 
 
 def mix_files():

@@ -6,7 +6,6 @@ import numpy as np
 import pytest
 
 
-
 @pytest.fixture(scope="module")
 def sides():
     import jax

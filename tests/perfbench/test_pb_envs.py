@@ -6,7 +6,8 @@ import os
 import numpy as np
 import pytest
 
-from pb_helpers import ROOT, mix_files
+import pb_checks
+from pb_helpers import ANY_CELLS, ROOT, mix_files
 from perfbench.envs import REGISTRY, SyntheticEnv, VectorEnv, generator_of, load_mix, reset_registry
 
 
@@ -55,11 +56,9 @@ def test_episode_ends_are_logged_and_stamps_cover_every_step():
     assert all(b >= a for a, b in zip(env.t_enter, env.t_exit)) and REGISTRY[0] is env
 
 
-def test_mix_files_state_what_the_cells_why_says(benchmark_json):
-    for cell in benchmark_json["workloads"]:
-        mix = load_mix(cell["traffic"])
-        assert f"{mix['num_envs']} env" in cell["why"]
-        assert mix["episode_steps"] == 500 and mix["warmup_train_calls"] >= 4
+@pytest.mark.parametrize("cell,bench_file", ANY_CELLS)
+def test_mix_files_state_what_the_cells_why_says(cell, bench_file):
+    pb_checks.why_names_the_mixs_envs(cell, bench_file)
 
 
 @pytest.mark.parametrize("path", mix_files(), ids=os.path.basename)

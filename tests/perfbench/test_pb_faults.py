@@ -5,19 +5,16 @@ import json
 
 import pytest
 
-from pb_helpers import CELLS, run_harness
+from pb_checks import spec_and_adapter
+from pb_helpers import L_CELL, XL_CELL, run_harness
 
-FAILS = {
-    "unchanged": ("update_",),         # a step that returns its state unchanged
-    "unchanged_actor": ("update_gap_actor",),  # the same of the actor alone: no other group's number sees it
-    "half_batch": ("grad_", "loss"),   # half of the batch left out, the mean taken over the rest
-    "altered_batch": ("replay_wrong_rows",),  # a gathered row altered where it is produced
-}
+# the faults the cell's adapter plants, each with the numbers of which one has to fail
+FAILS = spec_and_adapter(L_CELL)[1].fault_kinds
 
 
 @pytest.mark.parametrize("fault", list(FAILS))
 def test_fault_makes_the_run_incorrect(fault):
-    rc, out, err = run_harness("--workload", CELLS[-1], "--seed", "2147483659", "--seconds", "1", "--trace", "0",
+    rc, out, err = run_harness("--workload", L_CELL, "--seed", "2147483659", "--seconds", "1", "--trace", "0",
                                "--rehearse-cpu", "--fault", fault)
     assert rc == 0, err[-3000:]
     line = json.loads(out[-1])
@@ -33,7 +30,7 @@ def test_the_programs_own_lower_precision_path_comes_out_incorrect():
     in float32, that is a step down and the limits see it. On the chip it is
     none (the default matmul precision rounds operands to bfloat16 already)
     and it reads as sound runs do: PERF.md section 6."""
-    rc, out, err = run_harness("--workload", CELLS[0], "--seed", "3000000021", "--seconds", "1", "--trace", "0",
+    rc, out, err = run_harness("--workload", XL_CELL, "--seed", "3000000021", "--seconds", "1", "--trace", "0",
                                "--rehearse-cpu", "--control", "bf16-mixed")
     assert rc == 0, err[-3000:]
     assert json.loads(out[-1])["correct"] is False and "<-- FAILS" in err

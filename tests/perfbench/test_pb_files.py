@@ -6,9 +6,15 @@ import re
 
 import pytest
 
-from pb_helpers import CELLS, PPO_BENCH, PPO_CELL, ROOT, config_files, mix_files
+import pb_checks
+from pb_helpers import ANY_CELLS, CELLS, PPO_BENCH, PPO_CELL, ROOT, bench, config_files, mix_files
 
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
+
+
+def adapter_names():
+    """Every adapter there is: `perfbench/adapters/<name>.py`."""
+    return sorted(n[:-3] for n in os.listdir(os.path.join(ROOT, "perfbench", "adapters")) if n.endswith(".py") and n != "__init__.py")
 
 
 def test_benchmark_json_has_exactly_the_contract_keys(benchmark_json):
@@ -38,47 +44,31 @@ def test_four_chip_cells_stay_within_their_share(benchmark_json):
     assert len(four) <= max(1, len(benchmark_json["workloads"]) // 4)
 
 
-@pytest.mark.parametrize("metric", [m["name"] for m in json.load(open(os.path.join(ROOT, "BENCHMARK.json")))["per_layer"]])
+@pytest.mark.parametrize("metric", [m["name"] for m in bench()["per_layer"]])
 def test_every_per_layer_metric_has_a_reader_of_its_own(metric):
     from perfbench.run import metric_reader
 
     assert callable(metric_reader(metric))
 
 
-@pytest.mark.parametrize("cell", CELLS)
-def test_cell_files_load_and_compose(cell):
-    from perfbench.run import load_cell, overrides_for
-    from sheeprl_tpu.config import compose
+@pytest.mark.parametrize("cell,bench_file", ANY_CELLS)
+def test_cell_files_load_and_compose(cell, bench_file):
+    pb_checks.files_load_and_compose(cell, bench_file)
 
-    spec = load_cell(cell)
-    conf, mix = spec["config"], spec["mix"]
-    assert conf["source"].startswith("https://") and conf["precision"].startswith("32-true")
-    assert set(conf["reduced"]) == set(conf["reduced_why"])
-    cfg = compose("config", overrides_for(spec, 3000000019, False))
-    w = conf["widths"]
-    assert int(cfg.algo.dense_units) == w["dense_units"] and int(cfg.algo.mlp_layers) == w["mlp_layers"]
-    assert int(cfg.algo.world_model.recurrent_model.recurrent_state_size) == w["recurrent_state_size"]
-    assert int(cfg.algo.world_model.encoder.cnn_channels_multiplier) == w["cnn_channels_multiplier"]
-    assert (int(cfg.algo.per_rank_sequence_length), int(cfg.algo.per_rank_batch_size)) == (64, 16)
-    assert int(cfg.algo.horizon) == w["horizon"] and str(cfg.fabric.precision) == "32-true"
-    assert int(cfg.buffer.size) == conf["buffer.size"] and str(cfg.buffer.device_cache) == "auto"
-    assert int(cfg.env.num_envs) == mix["num_envs"] and float(cfg.algo.replay_ratio) == mix["replay_ratio"]
-    assert int(cfg.algo.learning_starts) == mix["learning_starts"] and bool(cfg.env.sync_env)
-    assert str(cfg.env.wrapper._target_) == "perfbench.envs.SyntheticEnv" == mix["generator"]
-    assert not bool(cfg.buffer.checkpoint) and not bool(cfg.checkpoint.save_last) and not bool(cfg.algo.run_test)
-    assert 0 <= int(cfg.seed) < 2**31
+
+@pytest.mark.parametrize("cell,bench_file", ANY_CELLS)
+def test_cell_has_limits_and_every_limit_names_a_compared_number(cell, bench_file):
+    pb_checks.limits_name_compared_numbers(cell, bench_file)
+
+
+@pytest.mark.parametrize("cell,bench_file", ANY_CELLS)
+def test_cell_counts_the_flops_of_training_and_of_acting(cell, bench_file):
+    pb_checks.counts_training_and_acting(cell, bench_file)
 
 
 @pytest.mark.parametrize("cell", CELLS)
-def test_cell_has_limits_and_every_limit_names_a_compared_number(cell):
-    from perfbench.adapters.dreamer_v3 import GROUPS
-    from perfbench.check import load_limits
-    from perfbench.run import load_cell
-
-    limits = load_limits(load_cell(cell)["limits_file"])
-    known = {f"{kind}_{g}" for g in GROUPS for kind in ("loss1_gap", "loss_gap", "grad_gap", "grad_mid", "update_gap", "update_mid")}
-    assert limits and set(limits) <= known
-    assert any(k.startswith("update_") for k in limits)  # a state left unchanged has to fail something
+def test_cell_keeps_more_than_the_floor_by_eval_shape(cell):
+    pb_checks.keeps_more_than_the_floor(cell)
 
 
 def test_peaks_lookup_by_device_kind_and_unknown_kind_raises():
@@ -101,9 +91,30 @@ def test_every_configuration_names_an_adapter_that_exports_the_whole_contract(pa
     adapter = adapters.load(conf["adapter"])
     assert os.path.isfile(os.path.join(ROOT, "perfbench", "adapters", conf["adapter"] + ".py"))
     assert all(hasattr(adapter, name) for name in adapters.CONTRACT)
-    assert all(callable(getattr(adapter, name)) for name in adapters.CONTRACT if name not in ("step_programs", "rehearsal_overrides"))
+    data = ("step_programs", "rehearsal_overrides", "compared_numbers", "fault_kinds")
+    assert all(callable(getattr(adapter, name)) for name in adapters.CONTRACT if name not in data)
     assert adapter.step_programs and all(p.startswith("jit_") for p in adapter.step_programs)
     assert all("=" in o for o in adapter.rehearsal_overrides)
+
+
+@pytest.mark.parametrize("name", adapter_names())
+def test_every_adapter_names_the_numbers_it_compares_and_the_faults_it_plants(name):
+    """The two names the tests ask any cell's adapter for: what `decide` may
+    return, and per fault `faults(kind)` plants the numbers of which one must fail."""
+    from perfbench import adapters
+
+    adapter = adapters.load(name)
+    assert {"widths_of", "compared_numbers", "fault_kinds"} <= set(adapters.CONTRACT)
+    assert adapter.compared_numbers and all(NAME.match(n) for n in adapter.compared_numbers)
+    assert "unchanged" in adapter.fault_kinds  # the one fault every training cell can have
+    for kind, fails in adapter.fault_kinds.items():
+        assert isinstance(fails, tuple) and fails, kind
+        assert all(any(n.startswith(p) for n in adapter.compared_numbers) for p in fails), (kind, fails)
+        with adapter.faults(kind):  # planted and taken out again: the kind is one `faults` knows
+            pass
+    with pytest.raises(ValueError):
+        with adapter.faults("no_such_fault"):
+            pass
 
 
 def test_an_adapter_that_lacks_part_of_the_contract_is_refused(tmp_path, monkeypatch):
@@ -135,22 +146,31 @@ def perfbench_sources():
                     yield os.path.relpath(path, ROOT), f.read()
 
 
-def test_only_its_adapter_its_reference_and_nothing_else_of_perfbench_mentions_ppo():
-    mentions = sorted(p for p, text in perfbench_sources() if re.search(r"ppo", text, re.I))
-    assert mentions == ["perfbench/adapters/ppo.py", "perfbench/references/ppo.py"]
+# where an adapter's algorithm is named besides its adapter and its reference under `references/`: DreamerV3's reference
+# stays `perfbench/reference.py` (accepted before the seam), and overrides.py keeps one lazy name for
+# `tests/test_train_scopes.py`, which lies outside the benchmark's directories. A new adapter gets no entry here.
+ELSEWHERE = {"dreamer_v3": {"perfbench/reference.py", "perfbench/overrides.py"}}
+# the names of an algorithm's own modules beside the adapter's name
+ALSO = {"dreamer_v3": "|world_model"}
 
 
-def test_only_its_adapter_and_its_reference_name_dreamer_v3s_modules():
-    """ISSUE 31's acceptance: whatever belongs to the DreamerV3 family is behind
-    the seam. The data files name their recipe (`exp=dreamer_v3_...`) and their
-    adapter; no other code does."""
-    # overrides.py: one lazy name kept for `tests/test_train_scopes.py`, which lies outside the benchmark's directories
-    allowed = {"perfbench/adapters/dreamer_v3.py", "perfbench/reference.py", "perfbench/overrides.py"}
-    code = sorted(p for p, text in perfbench_sources() if p.endswith(".py") and re.search(r"dreamer_v3|world_model", text))
-    assert set(code) <= allowed, code
-    for name in ("run.py", "check.py", "taps.py", "rehearse.py", "calibrate.py", "span_reduce.py", "trace_reduce.py", "envs.py", "overrides.py"):
-        with open(os.path.join(ROOT, "perfbench", name)) as f:
-            assert "sheeprl_tpu.algos" not in f.read(), name
+@pytest.mark.parametrize("name", adapter_names())
+def test_only_its_adapter_and_its_reference_name_an_algorithm(name):
+    """ISSUE 31's acceptance, for every adapter there is: an algorithm is named
+    by its adapter and its reference alone. The data files name their recipe
+    (`exp=dreamer_v3_...`) and their adapter; no other code of `perfbench/` does."""
+    allowed = {f"perfbench/adapters/{name}.py", f"perfbench/references/{name}.py"} | ELSEWHERE.get(name, set())
+    pattern = re.compile(name + ALSO.get(name, ""), re.I)
+    sources = list(perfbench_sources())
+    code = sorted(p for p, text in sources if p.endswith(".py") and pattern.search(text))
+    assert f"perfbench/adapters/{name}.py" in code and set(code) <= allowed, code
+    # a data file names the algorithm only where some configuration of the benchmark runs it
+    data = sorted(p for p, text in sources if p.endswith(".json") and pattern.search(text))
+    runs_it = any(json.loads(text).get("adapter") == name for p, text in sources if p.startswith("perfbench/configs/"))
+    assert runs_it or not data, data
+    for shared in ("run.py", "check.py", "taps.py", "rehearse.py", "calibrate.py", "span_reduce.py", "trace_reduce.py", "envs.py", "overrides.py"):
+        with open(os.path.join(ROOT, "perfbench", shared)) as f:
+            assert "sheeprl_tpu.algos" not in f.read(), shared
 
 
 def test_the_fixture_of_a_second_algorithm_is_files_alone(benchmark_json):
@@ -159,7 +179,7 @@ def test_the_fixture_of_a_second_algorithm_is_files_alone(benchmark_json):
 
     with open(os.path.join(ROOT, PPO_BENCH)) as f:
         fixture = json.load(f)
-    assert set(fixture) == set(benchmark_json) and PPO_CELL not in CELLS
+    assert set(fixture) == set(benchmark_json)  # (that no benchmark holds the fixture's cell: test_pb_run.py, by a run)
     spec = load_cell(PPO_CELL, PPO_BENCH)
     assert spec["config"]["adapter"] == "ppo" and spec["mix"]["generator"] == "perfbench.envs.VectorEnv"
     assert spec["limits_file"].endswith("tests/perfbench/fixtures/limits/ppo_tiny.json") and os.path.isfile(spec["limits_file"])

@@ -4,14 +4,15 @@ import json
 
 import pytest
 
-from pb_helpers import CELLS, PPO_BENCH, PPO_CELL, run_harness
+from pb_checks import spec_and_adapter
+from pb_helpers import PPO_BENCH, PPO_CELL, XL_CELL, run_harness
 
 CONTRACT_KEYS = ["correct", "attempted", "failed", "metrics", "device"]
 
 
 @pytest.fixture(scope="module")
 def rehearsal():
-    rc, out, err = run_harness("--workload", CELLS[0], "--seed", "3000000019", "--seconds", "1", "--trace", "1", "--rehearse-cpu")
+    rc, out, err = run_harness("--workload", XL_CELL, "--seed", "3000000019", "--seconds", "1", "--trace", "1", "--rehearse-cpu")
     return rc, out, err
 
 
@@ -42,6 +43,8 @@ def test_rehearsal_agrees_with_the_reference_to_float32_rounding(rehearsal):
     reads = {ln.split()[1]: float(ln.split()[3]) for ln in err.splitlines() if ln.startswith(("[compared]", "[read]"))}
     gaps = {k: v for k, v in reads.items() if "_gap_" in k or "_mid_" in k}
     assert len(gaps) == 18 and max(gaps.values()) < 2e-3, gaps
+    # every number the program's `decide` returned, with a limit or without, is one its adapter names
+    assert set(reads) <= spec_and_adapter(XL_CELL)[1].compared_numbers, set(reads)
     assert reads["replay_wrong_rows"] == 0.0
 
 
@@ -55,7 +58,7 @@ def test_rehearsal_leaves_nothing_in_the_checkout(rehearsal):
 
 
 def test_a_run_that_finds_no_tpu_exits_nonzero_and_prints_no_result():
-    rc, out, err = run_harness("--workload", CELLS[0], "--seed", "1", "--seconds", "1", "--trace", "0", timeout=300)
+    rc, out, err = run_harness("--workload", XL_CELL, "--seed", "1", "--seconds", "1", "--trace", "0", timeout=300)
     assert rc != 0 and out == [] and "no result" in err
 
 
@@ -76,8 +79,10 @@ def test_a_cell_of_another_algorithm_runs_through_the_seam_and_is_correct(ppo_re
     line = json.loads(out[-1])
     assert list(line)[: len(CONTRACT_KEYS)] == CONTRACT_KEYS and list(line)[-1] == "compared"
     assert line["correct"] is True and line["attempted"] > 0
-    assert {"rollout_wrong_rows", "update_early_steps", "update_late_steps", "update_gap", "advantages_gap", "values_gap",
-            "logprobs_gap", "loss_gap_policy", "loss_gap_value", "loss_gap_entropy"} == set(line["compared"])
+    from perfbench.check import load_limits
+
+    spec, adapter = spec_and_adapter(PPO_CELL, PPO_BENCH)
+    assert set(load_limits(spec["limits_file"])) < set(line["compared"]) <= adapter.compared_numbers  # the file's and the structural
     assert "algo=ppo" in err and "weights from the seed: 18 leaves" in err
 
 

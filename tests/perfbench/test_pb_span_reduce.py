@@ -7,7 +7,7 @@ import os
 
 import pytest
 
-from pb_helpers import bench
+from pb_helpers import CELLS, L_CELL, XL_CELL, bench
 from perfbench import span_reduce, trace_reduce
 from perfbench.run import metric_reader
 
@@ -40,7 +40,8 @@ def test_the_fixture_names_every_metric_that_reads_through_span_reduce():
     entries = {m["name"]: m for m in bench()["per_layer"]}
     # PR 28's fourteen, `loop.learner_wait_pct` replaced by `loop.learner_wait_idle_pct`, and `train_step.device_ms`
     assert len(NEW) == 15 and set(NEW) <= set(entries) and "loop.learner_wait_pct" not in entries
-    assert all(entries[n]["workloads"] == ["dv3_xl.crafter", "dv3_l.navigate4"] for n in NEW)
+    # both accepted cells report each of them; a later cell adds its name to the lists of those it reports
+    assert all({XL_CELL, L_CELL} <= set(entries[n]["workloads"]) <= set(CELLS) for n in NEW)
     assert os.path.getsize(SCOPES) < 400_000
 
 

@@ -9,7 +9,7 @@ import os
 
 import pytest
 
-from pb_helpers import CELLS, run_harness
+from pb_helpers import XL_CELL, run_harness
 from perfbench import span_reduce, trace_reduce
 from sheeprl_tpu.telemetry.schema import SPAN_SCHEMAS
 
@@ -20,7 +20,7 @@ STEADY = ["Time/train_time", "Time/env_interaction_time", "Time/learner_apply", 
 @pytest.fixture(scope="module")
 def rehearsal(tmp_path_factory):
     keep = str(tmp_path_factory.mktemp("keep"))
-    rc, out, err = run_harness("--workload", CELLS[0], "--seed", "3000000021", "--seconds", "2", "--trace", "1",
+    rc, out, err = run_harness("--workload", XL_CELL, "--seed", "3000000021", "--seconds", "2", "--trace", "1",
                                "--rehearse-cpu", "--keep", keep, "--keep-trace", "1")
     assert rc == 0, err[-3000:]
     path = glob.glob(os.path.join(keep, "*.xplane.pb"))[0]
