@@ -386,6 +386,7 @@ def main(dist: Distributed, cfg: Config) -> None:
         cnn_keys=cnn_keys,
         host_sample_fn=_host_sample,
         row_bytes_hint=estimate_row_bytes(obs_space, sum(actions_dim)),
+        emit=telem.emit,
     )
     pending_metrics: list = []
 

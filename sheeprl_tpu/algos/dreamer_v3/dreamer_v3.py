@@ -460,6 +460,19 @@ def make_train_fn(
     return train
 
 
+@partial(jax.jit, static_argnames="g")
+def burst_keys(root_key: jax.Array, g: int):
+    """The next root key and the ``g`` keys of one burst, the values of
+    ``root_key, sub = split(root_key)`` and ``split(sub, g)``, in ONE
+    dispatch. Eagerly they are three (two splits and the unstack between
+    them), 2.2 ms of the learner's chain from a packet to the train step's
+    start on the device, and since the ring's copies no longer hide that
+    chain the act that waits behind the train step waits that much longer
+    (PERF.md, PR 33)."""
+    root_key, sub = jax.random.split(root_key)
+    return root_key, jax.random.split(sub, g)
+
+
 _PLAYER_TAG = iter(range(1 << 30))  # unique retrace-detector tags per player
 
 
@@ -651,6 +664,7 @@ def main(dist: Distributed, cfg: Config) -> None:
         seq_len,
         cnn_keys=cnn_keys,
         row_bytes_hint=estimate_row_bytes(obs_space, act_total),
+        emit=telem.emit,
     )
     pending_metrics: list = []
 
@@ -844,10 +858,8 @@ def main(dist: Distributed, cfg: Config) -> None:
                 bursts += 1
                 with telem.span("Time/train_time", grad_steps=g, burst=bursts):
                     batches = prefetch.take(g)  # [G, T, B, ...]
-                    root_key, sub = jax.random.split(root_key)
-                    params, opt_states, moments, metrics = train(
-                        params, opt_states, moments, batches, jax.random.split(sub, g)
-                    )
+                    root_key, keys = burst_keys(root_key, g)
+                    params, opt_states, moments, metrics = train(params, opt_states, moments, batches, keys)
                 if not MetricAggregator.disabled:
                     pending_metrics.append(metrics)
                 mirror.refresh(player_view(params))
@@ -909,10 +921,8 @@ def main(dist: Distributed, cfg: Config) -> None:
                 with telem.span("Time/train_time", grad_steps=g, burst=engine.burst):
                     bursting = True
                     batches = prefetch.take(g)  # [G, T, B, ...]
-                    root_key, sub = jax.random.split(root_key)
-                    params, opt_states, moments, metrics = train(
-                        params, opt_states, moments, batches, jax.random.split(sub, g)
-                    )
+                    root_key, keys = burst_keys(root_key, g)
+                    params, opt_states, moments, metrics = train(params, opt_states, moments, batches, keys)
                 if not MetricAggregator.disabled:
                     pending_metrics.append(metrics)
                 nxt = next((x for x in gs[i + 1 :] if x > 0), 0)
@@ -959,14 +969,8 @@ def main(dist: Distributed, cfg: Config) -> None:
                     bursts += 1
                     with telem.span("Time/train_time", grad_steps=per_rank_gradient_steps, burst=bursts):
                         batches = prefetch.take(per_rank_gradient_steps)  # [G, T, B, ...]
-                        root_key, sub = jax.random.split(root_key)
-                        params, opt_states, moments, metrics = train(
-                            params,
-                            opt_states,
-                            moments,
-                            batches,
-                            jax.random.split(sub, per_rank_gradient_steps),
-                        )
+                        root_key, keys = burst_keys(root_key, per_rank_gradient_steps)
+                        params, opt_states, moments, metrics = train(params, opt_states, moments, batches, keys)
                     # metrics stay on device until log time — no per-step host sync
                     if not MetricAggregator.disabled:
                         # device refs held until the log-cadence host sync;

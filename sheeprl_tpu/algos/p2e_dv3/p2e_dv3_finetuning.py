@@ -176,6 +176,7 @@ def main(dist: Distributed, cfg: Config, exploration_cfg: Config) -> None:
         seq_len,
         cnn_keys=cnn_keys,
         row_bytes_hint=estimate_row_bytes(obs_space, sum(actions_dim)),
+        emit=telem.emit,
     )
     pending_metrics: list = []
 

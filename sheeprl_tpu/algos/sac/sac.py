@@ -217,6 +217,7 @@ def main(dist: Distributed, cfg: Config) -> None:
         rb,
         batch_size,
         row_bytes_hint=estimate_row_bytes(obs_space, act_dim),
+        emit=telem.emit,
     )
     pending_metrics: list = []
     # per-step inference on the player device (host CPU when the mesh is a

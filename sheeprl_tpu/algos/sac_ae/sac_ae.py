@@ -273,6 +273,7 @@ def main(dist: Distributed, cfg: Config) -> None:
         batch_size,
         cnn_keys=cnn_keys + tuple(f"next_{k}" for k in cnn_keys),
         row_bytes_hint=2 * estimate_row_bytes(obs_space, act_dim),
+        emit=telem.emit,
     )
 
     # per-step inference on the player device (host CPU when the mesh is a

@@ -10,10 +10,24 @@ once per sampled batch. This module keeps a device-side mirror of the
 sequential replay buffer (whether it beats host staging on a locally
 attached chip is not measured: ROADMAP, Design item 3):
 
-* ``ring[key]`` is a ``[buffer_size, n_envs, ...]`` jax.Array in HBM laid
-  out exactly like the host :class:`EnvIndependentReplayBuffer` (env ``e``'s
-  sub-buffer row ``t`` lives at ``ring[key][t, e]``), dtypes preserved
+* ``ring[key]`` is a ``[buffer_size, n_envs, *stored item]`` jax.Array in HBM
+  indexed exactly like the host :class:`EnvIndependentReplayBuffer` (env
+  ``e``'s sub-buffer row ``t`` lives at ``ring[key][t, e]``), dtypes preserved
   (rgb stays uint8 — 4× fewer bytes than f32 in the transfer *and* in HBM);
+* how a row's item is stored (:func:`stored_item_shape`, the one place that
+  knows): an item whose element count is a whole number of the TPU's native
+  tiles (8 sublanes of 32 bits × 128 lanes: 4096 uint8, 1024 float32) is kept
+  as ``[elements // 128, 128]``, every other item in its own shape. A TPU
+  tiles the two minor-most dimensions of an array, and for
+  ``u8[rows, n_envs, 64, 64, 3]`` a minor dimension of 3 would pad 42×, so
+  XLA's default layout made the ROW axis minor-most: one 12288-byte row lay
+  strewn over the whole buffer, and every gather copied the ring into a
+  row-major layout first and every scatter copied it there and back (83 ms
+  and 114 ms a gradient step at 2.7 and 3.7 GB: PERF.md, PR 33). Stored as
+  ``u8[rows, n_envs, 96, 128]`` the same bytes tile exactly, the default
+  layout is row-major, and both programs touch only the rows they name.
+  The gather reshapes back to the item's shape inside its own program, so a
+  batch is shape for shape and bit for bit what the host buffer would give;
 * ``sync()`` ships only the rows added since the last sync — ``O(new
   transitions)``, a few KB per burst — and scatters them into the ring with
   a donated jitted update (index vectors padded to a fixed bucket so the
@@ -51,20 +65,94 @@ from .buffers import EnvIndependentReplayBuffer, SequentialReplayBuffer
 from .prefetch import StagedPrefetcher
 
 
+_LANES = 128  # a TPU tile is 8 sublanes of 32 bits by 128 lanes
+
+
+def stored_item_shape(item: Sequence[int], dtype: Any) -> Tuple[int, ...]:
+    """Shape in which the ring keeps one row's item of logical shape ``item``:
+    ``[elements // 128, 128]`` where the elements fill whole native tiles (the
+    leaf's default TPU layout is then row-major and unpadded: module
+    docstring), else ``item`` itself. Reads shape and dtype, nothing else."""
+    item = tuple(int(d) for d in item)
+    n = int(np.prod(item, dtype=np.int64))
+    tile = _LANES * 8 * max(1, 4 // np.dtype(dtype).itemsize)
+    return (n // _LANES, _LANES) if n and n % tile == 0 else item
+
+
+def as_stored(x: Any, item: Sequence[int]) -> Any:
+    """``x[*lead, *item]`` (numpy or jax) in the shape the ring stores it."""
+    item = tuple(item)
+    return x.reshape(x.shape[: x.ndim - len(item)] + stored_item_shape(item, x.dtype))
+
+
+def as_logical(x: Any, item: Sequence[int]) -> Any:
+    """The inverse of :func:`as_stored`: a stored leaf or gathered batch back
+    in its item's own shape."""
+    item = tuple(item)
+    return x.reshape(x.shape[: x.ndim - len(stored_item_shape(item, x.dtype))] + item)
+
+
+# (key, logical item shape) of the leaves stored in another shape than their
+# own: the static argument that tells a gather what to restore
+_Items = Tuple[Tuple[str, Tuple[int, ...]], ...]
+
+
+def _allocate(
+    host: Any, size: int, n_envs: int, device: Any, emit: Optional[Any]
+) -> Tuple[Dict[str, jax.Array], _Items]:
+    """Zeroed ring leaves on ``device`` for a host buffer whose leaves are
+    ``[size, n_envs, *item]``, each in its stored shape, and the gathers'
+    ``items`` argument; the run's ``ring_layout`` event goes to ``emit``
+    (``telem.emit``) where one is given."""
+    layout: Dict[str, Any] = {}
+    ring: Dict[str, jax.Array] = {}
+    items = []
+    total = contiguous = 0
+    for k in host.keys():
+        item, dtype = tuple(host[k].shape[2:]), host[k].dtype
+        stored = stored_item_shape(item, dtype)
+        ring[k] = jax.device_put(jnp.zeros((size, n_envs) + stored, dtype=dtype), device)
+        nbytes = ring[k].nbytes
+        layout[k] = {"logical": list(item), "stored": list(stored), "dtype": str(np.dtype(dtype)), "bytes": nbytes}
+        total += nbytes
+        if stored != item:
+            items.append((k, item))
+            contiguous += nbytes
+    if emit is not None:
+        emit(
+            {
+                "event": "ring_layout",
+                "device": f"{device.platform}:{device.id}",
+                "rows": size,
+                "n_envs": n_envs,
+                "keys": layout,
+                "total_bytes": total,
+                "contiguous_bytes": contiguous,
+                "contiguous_bytes_share": contiguous / total if total else 0.0,
+            }
+        )
+    return ring, tuple(items)
+
+
 @functools.partial(jax.jit, donate_argnums=0)
 def _scatter_rows(ring: Dict[str, jax.Array], rows: Dict[str, jax.Array],
                   t_idx: jax.Array, e_idx: jax.Array) -> Dict[str, jax.Array]:
-    # padding entries carry t_idx == buffer_size → dropped, not clipped
+    # rows[k] is [n, *item], stored or logical: written in the ring's own item
+    # shape. Padding entries carry t_idx == buffer_size → dropped, not clipped
     return {
-        k: ring[k].at[t_idx, e_idx].set(rows[k], mode="drop") for k in ring
+        k: ring[k].at[t_idx, e_idx].set(rows[k].reshape(rows[k].shape[:1] + ring[k].shape[2:]), mode="drop")
+        for k in ring
     }
 
 
-@functools.partial(jax.jit, static_argnames=("f32_keys",))
+@functools.partial(jax.jit, static_argnames=("f32_keys", "items"))
 def _gather_batch(ring: Dict[str, jax.Array], t_idx: jax.Array, e_idx: jax.Array,
-                  f32_keys: Tuple[str, ...]) -> Dict[str, jax.Array]:
-    # t_idx [G, L, B] with e_idx [B] broadcasts to [G, L, B, *item]
+                  f32_keys: Tuple[str, ...], items: _Items = ()) -> Dict[str, jax.Array]:
+    # t_idx [G, L, B] with e_idx [B] broadcasts to [G, L, B, *stored item];
+    # `items` restores the item's own shape on the batch alone
     out = {k: ring[k][t_idx, e_idx] for k in ring}
+    for k, item in items:
+        out[k] = as_logical(out[k], item)
     return {k: v.astype(jnp.float32) if k in f32_keys else v for k, v in out.items()}
 
 
@@ -104,6 +192,7 @@ class DeviceRingPrefetcher(_StagedGather):
         cnn_keys: Sequence[str] = (),
         device: Optional[Any] = None,
         bucket: int = 8,
+        emit: Optional[Any] = None,
     ):
         for b in rb.buffer:
             if not isinstance(b, SequentialReplayBuffer):
@@ -117,7 +206,9 @@ class DeviceRingPrefetcher(_StagedGather):
         self._cnn_keys = tuple(cnn_keys)
         self._device = device if device is not None else jax.local_devices()[0]
         self._bucket = int(bucket)
+        self._emit = emit  # telem.emit: takes the `ring_layout` event at allocation
         self._ring: Optional[Dict[str, jax.Array]] = None
+        self._items: _Items = ()  # the leaves stored in another shape than their own
         # per-env monotonic added-row count at the last sync (sub-buffer
         # _added never wraps, so a >= buffer_size backlog is detectable)
         self._synced_added: List[int] = [0] * rb.n_envs
@@ -141,14 +232,9 @@ class DeviceRingPrefetcher(_StagedGather):
         proto = self._rb.buffer[0]
         if proto.empty:
             raise ValueError("No data in the buffer, cannot mirror")
-        size, n_envs = self._rb.buffer_size, self._rb.n_envs
-        self._ring = {
-            k: jax.device_put(
-                jnp.zeros((size, n_envs) + proto[k].shape[2:], dtype=proto[k].dtype),
-                self._device,
-            )
-            for k in proto.keys()
-        }
+        self._ring, self._items = _allocate(
+            proto, self._rb.buffer_size, self._rb.n_envs, self._device, self._emit
+        )
 
     def _pending_rows(self) -> List[Tuple[int, int]]:
         """(env, row) pairs added or edited since the last sync, oldest
@@ -205,7 +291,7 @@ class DeviceRingPrefetcher(_StagedGather):
                 out = np.zeros((padded,) + item, dtype=self._rb.buffer[0][k].dtype)
                 for e, slots in by_env.items():
                     out[slots] = self._rb.buffer[e][k][t_idx[slots], 0]
-                data[k] = out
+                data[k] = as_stored(out, item)  # a view: the rows cross in the ring's shape
             span.count(bytes=sum(v.nbytes for v in data.values()) + t_idx.nbytes + e_idx.nbytes)
             dev = self._device
             self._ring = _scatter_rows(
@@ -256,6 +342,7 @@ class DeviceRingPrefetcher(_StagedGather):
                 jax.device_put(t_idx, dev),
                 jax.device_put(env_order, dev),
                 self._f32_keys(),
+                items=self._items,
             )
 
     def resync(self) -> None:
@@ -400,6 +487,7 @@ class ShardedDeviceRingPrefetcher(_ShardedRing):
         cnn_keys: Sequence[str] = (),
         dist: Any = None,
         bucket: int = 8,
+        emit: Optional[Any] = None,
     ):
         devs = list(dist.mesh.devices.flatten())
         D = len(devs)
@@ -418,6 +506,7 @@ class ShardedDeviceRingPrefetcher(_ShardedRing):
                 cnn_keys=cnn_keys,
                 device=devs[d],
                 bucket=bucket,
+                emit=emit,
             )
             for d in range(D)
         ]
@@ -432,19 +521,25 @@ class ShardedDeviceRingPrefetcher(_ShardedRing):
 @functools.partial(jax.jit, donate_argnums=0)
 def _scatter_steps(ring: Dict[str, jax.Array], rows: Dict[str, jax.Array],
                    t_idx: jax.Array) -> Dict[str, jax.Array]:
-    # one scatter row covers all envs of a time step; padding is OOB-dropped
-    return {k: ring[k].at[t_idx].set(rows[k], mode="drop") for k in ring}
+    # one scatter row covers all envs of a time step, written in the ring's
+    # own item shape; padding is OOB-dropped
+    return {
+        k: ring[k].at[t_idx].set(rows[k].reshape(rows[k].shape[:1] + ring[k].shape[1:]), mode="drop")
+        for k in ring
+    }
 
 
-@functools.partial(jax.jit, static_argnames=("g", "batch", "next_keys", "f32_keys"))
+@functools.partial(jax.jit, static_argnames=("g", "batch", "next_keys", "f32_keys", "items"))
 def _gather_uniform(ring: Dict[str, jax.Array], t_idx: jax.Array, e_idx: jax.Array,
                     g: int, batch: int, next_keys: Tuple[str, ...],
-                    f32_keys: Tuple[str, ...]) -> Dict[str, jax.Array]:
+                    f32_keys: Tuple[str, ...], items: _Items = ()) -> Dict[str, jax.Array]:
     size = next(iter(ring.values())).shape[0]
-    out = {k: ring[k][t_idx, e_idx].reshape((g, batch) + ring[k].shape[2:]) for k in ring}
+    logical = dict(items)
+    shape = {k: (g, batch) + logical.get(k, ring[k].shape[2:]) for k in ring}
+    out = {k: ring[k][t_idx, e_idx].reshape(shape[k]) for k in ring}
     nxt = (t_idx + 1) % size
     for k in next_keys:
-        out[f"next_{k}"] = ring[k][nxt, e_idx].reshape((g, batch) + ring[k].shape[2:])
+        out[f"next_{k}"] = ring[k][nxt, e_idx].reshape(shape[k])
     def _f32(k: str) -> bool:
         return k in f32_keys or (k.startswith("next_") and k[5:] in f32_keys)
 
@@ -465,6 +560,7 @@ class DeviceUniformRingPrefetcher(_StagedGather):
         sample_next_obs: bool = False,
         device: Optional[Any] = None,
         bucket: int = 8,
+        emit: Optional[Any] = None,
     ):
         self._rb = rb
         self._batch = int(batch_size)
@@ -472,7 +568,9 @@ class DeviceUniformRingPrefetcher(_StagedGather):
         self._next_obs = bool(sample_next_obs)
         self._device = device if device is not None else jax.local_devices()[0]
         self._bucket = int(bucket)
+        self._emit = emit  # telem.emit: takes the `ring_layout` event at allocation
         self._ring: Optional[Dict[str, jax.Array]] = None
+        self._items: _Items = ()  # the leaves stored in another shape than their own
         self._synced_added = 0
         self._staged: Optional[tuple] = None
         self._last_idx: Optional[tuple] = None  # (t_idx, e_idx) — tests
@@ -487,13 +585,7 @@ class DeviceUniformRingPrefetcher(_StagedGather):
         b = self._rb
         if b.empty:
             raise ValueError("No data in the buffer, cannot mirror")
-        self._ring = {
-            k: jax.device_put(
-                jnp.zeros((b.buffer_size, b.n_envs) + b[k].shape[2:], dtype=b[k].dtype),
-                self._device,
-            )
-            for k in b.keys()
-        }
+        self._ring, self._items = _allocate(b, b.buffer_size, b.n_envs, self._device, self._emit)
 
     def sync(self) -> None:
         b = self._rb
@@ -522,7 +614,7 @@ class DeviceUniformRingPrefetcher(_StagedGather):
                 out = np.zeros((padded,) + host.shape[1:], dtype=host.dtype)
                 out[:n] = host[steps]
                 nbytes += out.nbytes
-                data[k] = jax.device_put(out, dev)
+                data[k] = jax.device_put(as_stored(out, host.shape[2:]), dev)
             span.count(bytes=nbytes)
             self._ring = _scatter_steps(self._ring, data, jax.device_put(t_idx, dev))
 
@@ -545,6 +637,7 @@ class DeviceUniformRingPrefetcher(_StagedGather):
                 self._batch,
                 next_keys,
                 self._f32_keys(),
+                items=self._items,
             )
 
     def resync(self) -> None:
@@ -619,6 +712,7 @@ class ShardedDeviceUniformRingPrefetcher(_ShardedRing):
         sample_next_obs: bool = False,
         dist: Any = None,
         bucket: int = 8,
+        emit: Optional[Any] = None,
     ):
         devs = list(dist.mesh.devices.flatten())
         D = len(devs)
@@ -636,6 +730,7 @@ class ShardedDeviceUniformRingPrefetcher(_ShardedRing):
                 sample_next_obs=sample_next_obs,
                 device=devs[d],
                 bucket=bucket,
+                emit=emit,
             )
             for d in range(D)
         ]
@@ -743,6 +838,7 @@ def make_sequential_prefetcher(
     cnn_keys: Sequence[str] = (),
     host_sample_fn: Optional[Any] = None,
     row_bytes_hint: Optional[int] = None,
+    emit: Optional[Any] = None,
 ):
     """Prefetcher for the sequential-replay (Dreamer-family) train loops.
 
@@ -752,7 +848,8 @@ def make_sequential_prefetcher(
     fits ``buffer.device_cache_max_bytes`` per device. Multi-device meshes
     get the dp-sharded ring (:class:`ShardedDeviceRingPrefetcher`) when
     n_envs and batch_size divide the mesh; otherwise the host path runs
-    (with a stderr note — no silent layout surprises)."""
+    (with a stderr note — no silent layout surprises). ``emit``
+    (``telem.emit``) takes a ring's ``ring_layout`` event when it allocates."""
     supported = isinstance(rb, EnvIndependentReplayBuffer) and all(
         isinstance(b, SequentialReplayBuffer) for b in rb.buffer
     )
@@ -768,12 +865,12 @@ def make_sequential_prefetcher(
     ):
         if dist.world_size == 1:
             return DeviceRingPrefetcher(
-                rb, batch_size, sequence_length, cnn_keys=cnn_keys, device=dist.local_device
+                rb, batch_size, sequence_length, cnn_keys=cnn_keys, device=dist.local_device, emit=emit
             )
         sharded = _sharded_or_fallback(
             cfg, dist, rb, batch_size,
             lambda: ShardedDeviceRingPrefetcher(
-                rb, batch_size, sequence_length, cnn_keys=cnn_keys, dist=dist
+                rb, batch_size, sequence_length, cnn_keys=cnn_keys, dist=dist, emit=emit
             ),
         )
         if sharded is not None:
@@ -792,11 +889,13 @@ def make_uniform_prefetcher(
     sample_next_obs: bool = False,
     host_sample_fn: Optional[Any] = None,
     row_bytes_hint: Optional[int] = None,
+    emit: Optional[Any] = None,
 ):
     """Prefetcher for the uniform-replay (SAC-family) train loops: the HBM
     ring under the same ``buffer.device_cache`` policy as the sequential
     path (incl. the dp-sharded variant on multi-device meshes), else host
-    sampling staged one burst ahead ([G, B, ...] batches)."""
+    sampling staged one burst ahead ([G, B, ...] batches). ``emit`` as in
+    :func:`make_sequential_prefetcher`."""
     if host_sample_fn is None:
         def host_sample_fn(g):  # noqa: F811 — default uniform host sample
             s = rb.sample(batch_size * g, sample_next_obs=sample_next_obs, n_samples=1)
@@ -812,6 +911,7 @@ def make_uniform_prefetcher(
                 cnn_keys=cnn_keys,
                 sample_next_obs=sample_next_obs,
                 device=dist.local_device,
+                emit=emit,
             )
         sharded = _sharded_or_fallback(
             cfg, dist, rb, batch_size,
@@ -821,6 +921,7 @@ def make_uniform_prefetcher(
                 cnn_keys=cnn_keys,
                 sample_next_obs=sample_next_obs,
                 dist=dist,
+                emit=emit,
             ),
         )
         if sharded is not None:

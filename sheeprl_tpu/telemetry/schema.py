@@ -439,6 +439,19 @@ EVENT_SCHEMAS: Dict[str, Dict[str, Tuple[bool, type]]] = {
         "threshold_bytes": (True, _NUM),
         "same_device": (True, _NUM),
     },
+    # how a device replay ring keeps its rows, once per ring when it is
+    # allocated (data/device_ring.py `_allocate`): per key the item's own
+    # (`logical`) and its `stored` shape, dtype and bytes, and the share of
+    # the ring's bytes stored row-contiguous (`stored_item_shape`'s rule)
+    "ring_layout": {
+        "device": (True, _STR),
+        "rows": (True, _NUM),
+        "n_envs": (True, _NUM),
+        "keys": (True, _DICT),
+        "total_bytes": (True, _NUM),
+        "contiguous_bytes": (True, _NUM),
+        "contiguous_bytes_share": (True, _NUM),
+    },
     # deterministic fault injection (resilience/chaos.py): faults the
     # SUPERVISOR injects (worker-side faults surface as `fleet` incidents —
     # a chaos crash is indistinguishable from a real one by design)

@@ -99,36 +99,86 @@ def test_dreamer_v3(standard_args, env_id):
     )
 
 
+# DreamerV3-XS at toy widths on the dummy env, with the device ring forced on
+DV3_RING_ARGS = [
+    "exp=dreamer_v3",
+    "env=dummy",
+    "env.id=discrete_dummy",
+    "algo=dreamer_v3_XS",
+    "algo.per_rank_batch_size=2",
+    "algo.per_rank_sequence_length=2",
+    "algo.learning_starts=0",
+    "algo.horizon=4",
+    "algo.dense_units=16",
+    "algo.world_model.encoder.cnn_channels_multiplier=2",
+    "algo.world_model.recurrent_model.recurrent_state_size=16",
+    "algo.world_model.transition_model.hidden_size=16",
+    "algo.world_model.representation_model.hidden_size=16",
+    "algo.world_model.discrete_size=4",
+    "algo.world_model.stochastic_size=4",
+    "algo.cnn_keys.encoder=[rgb]",
+    "algo.mlp_keys.encoder=[state]",
+    "buffer.size=64",
+    "buffer.device_cache=true",
+]
+
+
 def test_dreamer_v3_device_ring(standard_args, devices):
     """HBM-resident replay ring (buffer.device_cache=true forces it on the
     CPU backend): the bench-critical path where batches gather on device.
     devices=2 exercises the dp-SHARDED ring (per-device env sub-rings,
     batches assembled pre-sharded — VERDICT r4 #3)."""
-    _run(
-        [
-            f"fabric.devices={devices}",
-            "exp=dreamer_v3",
-            "env=dummy",
-            "env.id=discrete_dummy",
-            "algo=dreamer_v3_XS",
-            "algo.per_rank_batch_size=2",
-            "algo.per_rank_sequence_length=2",
-            "algo.learning_starts=0",
-            "algo.horizon=4",
-            "algo.dense_units=16",
-            "algo.world_model.encoder.cnn_channels_multiplier=2",
-            "algo.world_model.recurrent_model.recurrent_state_size=16",
-            "algo.world_model.transition_model.hidden_size=16",
-            "algo.world_model.representation_model.hidden_size=16",
-            "algo.world_model.discrete_size=4",
-            "algo.world_model.stochastic_size=4",
-            "algo.cnn_keys.encoder=[rgb]",
-            "algo.mlp_keys.encoder=[state]",
-            "buffer.size=64",
-            "buffer.device_cache=true",
-        ],
-        standard_args,
-    )
+    _run([f"fabric.devices={devices}"] + DV3_RING_ARGS, standard_args)
+
+
+def test_dreamer_v3_device_ring_emits_its_layout_once(standard_args):
+    """The run's `ring_layout` event: one per ring allocated, valid against
+    the schema, with the 64x64x3 uint8 frames stored row-contiguous as
+    [96, 128] and everything else (vectors, the action, four scalars) in
+    its own shape."""
+    import glob
+    import json
+
+    from sheeprl_tpu.telemetry import validate_jsonl
+
+    run(DV3_RING_ARGS + standard_args + ["metric.log_level=1", "metric.log_every=1000"])
+    streams = glob.glob("logs/runs/**/telemetry.jsonl", recursive=True)
+    assert len(streams) == 1, streams
+    assert validate_jsonl(streams[0]) == []
+    events = [json.loads(line) for line in open(streams[0])]
+    layouts = [e for e in events if e["event"] == "ring_layout"]
+    assert len(layouts) == 1, [e["event"] for e in events]
+    (ev,) = layouts
+    assert ev["rows"] == 64 and ev["n_envs"] == 2 and ev["device"].startswith("cpu:")
+    rgb = ev["keys"]["rgb"]
+    assert rgb == {"logical": [64, 64, 3], "stored": [96, 128], "dtype": "uint8", "bytes": 64 * 2 * 12288}
+    assert all(v["stored"] == v["logical"] for k, v in ev["keys"].items() if k != "rgb")
+    assert {"actions", "rewards", "terminated", "truncated", "is_first", "state"} <= set(ev["keys"])
+    assert ev["total_bytes"] == sum(v["bytes"] for v in ev["keys"].values())
+    assert ev["contiguous_bytes"] == rgb["bytes"]
+    assert 0.99 < ev["contiguous_bytes_share"] < 1.0
+    # the placement event it stands beside comes first: set-up, then the first sync
+    order = [e["event"] for e in events]
+    assert order.index("placement") < order.index("ring_layout")
+
+
+@pytest.mark.parametrize("g", [1, 3])
+def test_dreamer_v3_burst_keys_are_the_eager_splits(g):
+    """One dispatch in place of three on the learner's chain to the train
+    step: the key stream of a run must not move by it."""
+    import jax
+    import numpy as np
+
+    from sheeprl_tpu.algos.dreamer_v3.dreamer_v3 import burst_keys
+
+    root = jax.random.key(11)
+    for _ in range(2):  # the second call takes the first one's output
+        want_root, sub = jax.random.split(root)
+        want = jax.random.split(sub, g)
+        root, keys = burst_keys(root, g)
+        assert keys.shape == (g,)
+        np.testing.assert_array_equal(jax.random.key_data(root), jax.random.key_data(want_root))
+        np.testing.assert_array_equal(jax.random.key_data(keys), jax.random.key_data(want))
 
 
 def test_dreamer_v3_decoupled_rssm(standard_args):

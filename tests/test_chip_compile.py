@@ -165,38 +165,80 @@ def test_dv3_s_train_step_compiles_for_v5e_with_native_convs(one_chip, rssm):
     assert resident + RING_RGB_BYTES < HBM_BYTES, (mem, RING_RGB_BYTES)
 
 
-# -- the device replay ring at the recipe's buffer size -------------------------
-def _ring(one_chip, rows):
-    items = {"rgb": ((64, 64, 3), jnp.uint8), "actions": ((N_ACT,), jnp.float32)}
+# -- the device replay ring: the recipe's buffer and the benchmark's two cells ----
+# rows x envs x Discrete(n): the leaves are `rgb u8[64,64,3]`, the action's
+# one-hot and the four float scalars, in the shapes the prefetcher allocates
+RINGS = {"recipe": (RECIPE_ROWS, 1, N_ACT), "dv3_xl.crafter": (220_000, 1, 17), "dv3_l.navigate4": (75_000, 4, 10)}
+
+
+def _ring(one_chip, size):
+    """(ring leaves as stored, the gathers' `items`, the ring's bytes by
+    rows x row bytes)."""
+    from sheeprl_tpu.data.device_ring import stored_item_shape
+
+    rows, n_envs, n_act = RINGS[size]
+    items = {"rgb": ((64, 64, 3), jnp.uint8), "actions": ((n_act,), jnp.float32)}
     items.update({k: ((1,), jnp.float32) for k in ("rewards", "terminated", "truncated", "is_first")})
-    return {k: _sds(one_chip, (rows, 1) + shape, dtype) for k, (shape, dtype) in items.items()}, items
+    ring = {k: _sds(one_chip, (rows, n_envs) + stored_item_shape(item, dt), dt) for k, (item, dt) in items.items()}
+    assert ring["rgb"].shape == (rows, n_envs, 96, 128)
+    restore = tuple((k, item) for k, (item, dt) in items.items() if stored_item_shape(item, dt) != item)
+    nbytes = sum(rows * n_envs * int(np.prod(item)) * np.dtype(dt).itemsize for item, dt in items.values())
+    return ring, restore, nbytes
 
 
-def _fits_beside_train_step(mem):
-    """No padded layout blew the 1.2 GB of frames up, and the program's peak
-    leaves the DV3-S train program (1.6 GB) its room."""
-    assert mem.argument_size_in_bytes < 1.1 * RING_RGB_BYTES, mem
+def _touches_only_its_rows(mem, ring_bytes):
+    """No whole-buffer copy (the logical `u8[rows, envs, 64, 64, 3]` carried a
+    temp of twice the ring in every one of these programs: its default layout
+    puts the row axis minor-most), no padded layout, and the program's peak
+    leaves the train program its room."""
+    assert mem.temp_size_in_bytes < 0.10 * ring_bytes, mem
+    assert mem.argument_size_in_bytes < 1.01 * ring_bytes, mem
     peak = mem.argument_size_in_bytes + mem.temp_size_in_bytes + mem.output_size_in_bytes
     assert peak + 2 * 10**9 < HBM_BYTES, mem
 
 
-def test_device_ring_gather_compiles_for_v5e_at_recipe_size(one_chip):
+@pytest.mark.parametrize("size", list(RINGS))
+def test_device_ring_gather_compiles_for_v5e_without_a_whole_buffer_copy(one_chip, size):
     from sheeprl_tpu.data.device_ring import _gather_batch
 
-    ring, _ = _ring(one_chip, RECIPE_ROWS)
-    compiled = _gather_batch.lower(
-        ring, _sds(one_chip, (1, T, B), jnp.int32), _sds(one_chip, (B,), jnp.int32), ()
-    ).compile()
-    _fits_beside_train_step(compiled.memory_analysis())
+    ring, restore, nbytes = _ring(one_chip, size)
+    lowered = _gather_batch.lower(
+        ring, _sds(one_chip, (1, T, B), jnp.int32), _sds(one_chip, (B,), jnp.int32), (), items=restore
+    )
+    assert lowered.out_info["rgb"].shape == (1, T, B, 64, 64, 3)  # the batch train receives
+    _touches_only_its_rows(lowered.compile().memory_analysis(), nbytes)
 
 
-def test_device_ring_scatter_compiles_for_v5e_at_recipe_size(one_chip):
+@pytest.mark.parametrize("size", list(RINGS))
+def test_device_ring_scatter_compiles_for_v5e_in_place(one_chip, size):
     from sheeprl_tpu.data.device_ring import _scatter_rows
 
-    ring, items = _ring(one_chip, RECIPE_ROWS)
-    rows = {k: _sds(one_chip, (8,) + shape, dtype) for k, (shape, dtype) in items.items()}
-    idx = _sds(one_chip, (8,), jnp.int32)
-    compiled = _scatter_rows.lower(ring, rows, idx, idx).compile()
-    mem = compiled.memory_analysis()
-    _fits_beside_train_step(mem)
-    assert mem.alias_size_in_bytes >= RING_RGB_BYTES  # the donated ring is updated in place
+    ring, _, nbytes = _ring(one_chip, size)
+    n = 8 * (1 + 8 * (ring["rgb"].shape[1] > 1))  # 8 padded rows, 72 with four envs
+    rows = {k: _sds(one_chip, (n,) + ring[k].shape[2:], ring[k].dtype) for k in ring}
+    idx = _sds(one_chip, (n,), jnp.int32)
+    mem = _scatter_rows.lower(ring, rows, idx, idx).compile().memory_analysis()
+    _touches_only_its_rows(mem, nbytes)
+    assert mem.alias_size_in_bytes >= nbytes  # the donated ring is updated in place
+
+
+@pytest.mark.parametrize("size", list(RINGS))
+def test_uniform_ring_gather_compiles_for_v5e_without_a_whole_buffer_copy(one_chip, size):
+    from sheeprl_tpu.data.device_ring import _gather_uniform
+
+    ring, restore, nbytes = _ring(one_chip, size)
+    idx = _sds(one_chip, (256,), jnp.int32)
+    lowered = _gather_uniform.lower(ring, idx, idx, 1, 256, ("rgb",), (), items=restore)
+    assert lowered.out_info["next_rgb"].shape == (1, 256, 64, 64, 3)
+    _touches_only_its_rows(lowered.compile().memory_analysis(), nbytes)
+
+
+@pytest.mark.parametrize("size", list(RINGS))
+def test_uniform_ring_scatter_compiles_for_v5e_in_place(one_chip, size):
+    from sheeprl_tpu.data.device_ring import _scatter_steps
+
+    ring, _, nbytes = _ring(one_chip, size)
+    rows = {k: _sds(one_chip, (8,) + ring[k].shape[1:], ring[k].dtype) for k in ring}
+    mem = _scatter_steps.lower(ring, rows, _sds(one_chip, (8,), jnp.int32)).compile().memory_analysis()
+    _touches_only_its_rows(mem, nbytes)
+    assert mem.alias_size_in_bytes >= nbytes

@@ -10,7 +10,13 @@ import pytest
 jax = pytest.importorskip("jax")
 
 from sheeprl_tpu.data import EnvIndependentReplayBuffer, SequentialReplayBuffer
-from sheeprl_tpu.data.device_ring import DeviceRingPrefetcher, estimate_row_bytes
+from sheeprl_tpu.data.device_ring import (
+    DeviceRingPrefetcher,
+    as_logical,
+    as_stored,
+    estimate_row_bytes,
+    stored_item_shape,
+)
 
 KEYS = ("rgb", "state")
 
@@ -36,6 +42,12 @@ def _make(size=32, n_envs=2):
     )
     ring = DeviceRingPrefetcher(rb, batch_size=4, sequence_length=5, cnn_keys=("rgb",), bucket=8)
     return rb, ring
+
+def _ring_host(ring, host):
+    """The ring's leaves on the host in their items' own shapes, whatever
+    shape the ring stores them in; ``host[k]`` is ``[size, n_envs, *item]``."""
+    return {k: as_logical(np.asarray(v), host[k].shape[2:]) for k, v in ring.ring.items()}
+
 
 def _host_window(rb, env, start, L, key):
     size = rb.buffer_size
@@ -68,7 +80,7 @@ def test_wraparound_parity():
         if t % 7 == 0:
             ring.sync()
     ring.sync()
-    ring_host = {k: np.asarray(v) for k, v in ring.ring.items()}
+    ring_host = _ring_host(ring, rb.buffer[0])
     for e in range(2):
         np.testing.assert_array_equal(ring_host["rgb"][:, e], rb.buffer[e]["rgb"][:, 0])
         np.testing.assert_array_equal(ring_host["state"][:, e], rb.buffer[e]["state"][:, 0])
@@ -83,7 +95,7 @@ def test_backlog_exceeding_capacity_resyncs_fully():
     for t in range(1, 40):  # 39 new rows ≫ 16 slots, no intermediate sync
         rb.add(_row(t, 0, 2))
     ring.sync()
-    ring_host = {k: np.asarray(v) for k, v in ring.ring.items()}
+    ring_host = _ring_host(ring, rb.buffer[0])
     for e in range(2):
         np.testing.assert_array_equal(ring_host["state"][:, e], rb.buffer[e]["state"][:, 0])
 
@@ -99,7 +111,7 @@ def test_per_env_divergent_adds():
     rb.add(extra, indices=[1])
     rb.add(extra, indices=[1])
     ring.sync()
-    ring_host = {k: np.asarray(v) for k, v in ring.ring.items()}
+    ring_host = _ring_host(ring, rb.buffer[0])
     assert rb.buffer[0]._pos == 6 and rb.buffer[1]._pos == 8
     for e in range(2):
         pos = rb.buffer[e]._pos
@@ -117,7 +129,7 @@ def test_inplace_edit_reshipped():
     ring.sync()
     rb.mark_restart(1)  # edits env 1's newest row in place
     ring.sync()
-    ring_host = np.asarray(ring.ring["truncated"])
+    ring_host = _ring_host(ring, rb.buffer[0])["truncated"]
     assert ring_host[4, 1, 0] == 1.0
     assert ring_host[4, 0, 0] == 0.0
 
@@ -159,7 +171,7 @@ def test_resync_after_checkpoint_roundtrip():
     ring2.sync()
     for e in range(2):
         np.testing.assert_array_equal(
-            np.asarray(ring2.ring["state"])[:9, e], rb.buffer[e]["state"][:9, 0]
+            _ring_host(ring2, rb2.buffer[0])["state"][:9, e], rb.buffer[e]["state"][:9, 0]
         )
 
 
@@ -246,7 +258,7 @@ def test_uniform_wraparound_and_backlog():
     for t in range(1, 40):
         rb.add(_row(t, 0, 2))
     ring.sync()
-    ring_host = {k: np.asarray(v) for k, v in ring.ring.items()}
+    ring_host = _ring_host(ring, rb)
     np.testing.assert_array_equal(ring_host["state"], rb["state"])
     np.testing.assert_array_equal(ring_host["rgb"], rb["rgb"])
 
@@ -375,3 +387,193 @@ def test_sharded_uniform_requires_divisible_sizes():
     rb = ReplayBuffer(16, n_envs=3, obs_keys=KEYS)
     with pytest.raises(ValueError, match="divisible"):
         ShardedDeviceUniformRingPrefetcher(rb, 4, dist=dist)
+
+
+# -- how a row's item is stored (PR 33): whole native tiles go row-contiguous ---
+
+STORED = [
+    # (logical item, dtype, stored item)
+    ((64, 64, 3), np.uint8, (96, 128)),  # 12288 = 3 tiles of 4096 uint8
+    ((64, 64, 1), np.uint8, (32, 128)),  # exactly one tile
+    ((84, 84, 3), np.uint8, (84, 84, 3)),  # 21168: no whole number of tiles
+    ((17,), np.float32, (17,)),
+    ((1,), np.float32, (1,)),
+]
+
+
+@pytest.mark.parametrize("item,dtype,stored", STORED, ids=[f"{np.dtype(d).name}{list(i)}" for i, d, _ in STORED])
+def test_stored_item_shape_round_trip(item, dtype, stored):
+    """The rule reads shape and dtype alone, keeps every byte in place, and
+    its two views are each other's inverse on numpy and on jax arrays, under
+    any leading axes."""
+    assert stored_item_shape(item, dtype) == stored
+    assert int(np.prod(stored)) == int(np.prod(item))
+    rng = np.random.default_rng(0)
+    for lead in ((5, 2), (3,), (2, 4, 3)):
+        x = (rng.random(lead + item) * 200).astype(dtype)
+        kept = as_stored(x, item)
+        assert kept.shape == lead + stored
+        assert np.shares_memory(kept, x)  # a view: nothing is copied on the host
+        np.testing.assert_array_equal(kept.reshape(-1), x.reshape(-1))  # row-major bytes stay where they were
+        np.testing.assert_array_equal(as_logical(kept, item), x)
+        back = as_logical(as_stored(jax.numpy.asarray(x), item), item)
+        assert back.shape == x.shape and back.dtype == x.dtype
+        np.testing.assert_array_equal(np.asarray(back), x)
+
+
+def test_stored_item_shape_counts_tiles_by_dtype():
+    assert stored_item_shape((1024,), np.float32) == (8, 128)  # one tile of 32-bit values
+    assert stored_item_shape((1024,), np.uint8) == (1024,)  # a quarter of a uint8 tile
+    assert stored_item_shape((32, 64), np.float64) == (16, 128)
+    assert stored_item_shape((0, 128), np.uint8) == (0, 128)  # nothing to store stays as it is
+
+
+# "big" fills one uint8 tile and is stored [32, 128]; "rgb" (4x4x3) is stored as it is
+IMG_KEYS = ("big", "rgb")
+
+
+def _img_row(t, n_envs):
+    """A row whose image bytes are unique to (t, env), in both image keys."""
+    row = _row(t, 0, n_envs)
+    row["state"] = (1000.0 * t + np.arange(n_envs, dtype=np.float32))[None, :, None] * np.ones((1, 1, 3), np.float32)
+    for key, item in (("big", (64, 64, 1)), ("rgb", (4, 4, 3))):
+        row[key] = np.stack(
+            [np.random.default_rng(1000 * t + e).integers(0, 256, item, np.uint8) for e in range(n_envs)]
+        )[None]
+    return row
+
+
+def _img_make(size=16, n_envs=2):
+    rb = EnvIndependentReplayBuffer(
+        size, n_envs=n_envs, obs_keys=KEYS + ("big",), buffer_cls=SequentialReplayBuffer, seed=7
+    )
+    return rb, DeviceRingPrefetcher(rb, batch_size=4, sequence_length=5, cnn_keys=IMG_KEYS, bucket=8)
+
+
+def _assert_batch_is_host_rows(rb, ring, batch, g):
+    """Every column of the batch is the host buffer's rows, byte for byte, in
+    the item's own shape and dtype."""
+    t_idx, env_order = ring._last_idx
+    for key in IMG_KEYS:
+        item = rb.buffer[0][key].shape[2:]
+        got = np.asarray(batch[key])
+        assert got.shape == (g, 5, len(env_order)) + item and got.dtype == np.uint8
+        for i in range(g):
+            for b, e in enumerate(env_order):
+                want = rb.buffer[int(e)][key][t_idx[i, :, b], 0]
+                assert got[i, :, b].tobytes() == want.tobytes(), (key, i, b)
+
+
+def _assert_ring_is_host(rb, ring):
+    host = _ring_host(ring, rb.buffer[0])
+    for e, b in enumerate(rb.buffer):
+        for key in IMG_KEYS + ("state", "truncated"):
+            np.testing.assert_array_equal(host[key][:, e], b[key][:, 0])
+
+
+def test_ring_stores_whole_tiles_contiguous_and_the_rest_as_it_is():
+    rb, ring = _img_make()
+    rb.add(_img_row(0, 2))
+    ring.sync()
+    assert ring.ring["big"].shape == (16, 2, 32, 128) and ring.ring["big"].dtype == np.uint8
+    assert ring.ring["rgb"].shape == (16, 2, 4, 4, 3)
+    assert ring.ring["state"].shape == (16, 2, 3)
+
+
+@pytest.mark.parametrize("scenario", ["wrap", "mark_dirty", "resync"])
+def test_gathered_images_are_host_rows_byte_for_byte(scenario):
+    """A key stored row-contiguous and one stored as it is, across a wrap of
+    the ring, after a host edit re-shipped through `mark_dirty`, and after
+    `resync()` rebuilt the mirror."""
+    rb, ring = _img_make(size=16)
+    for t in range(40):  # wraps twice, synced in uneven steps
+        rb.add(_img_row(t, 2))
+        if t % 7 == 0:
+            ring.sync()
+    if scenario == "mark_dirty":
+        ring.sync()
+        for key in IMG_KEYS:
+            rb.buffer[1][key][3, 0] = 255 - rb.buffer[1][key][3, 0]
+        ring.mark_dirty(1, 3)
+    elif scenario == "resync":
+        ring.sync()
+        ring.resync()
+        assert ring.ring is None
+    batch = ring.take(3)
+    _assert_batch_is_host_rows(rb, ring, batch, 3)
+    _assert_ring_is_host(rb, ring)
+
+
+def test_sharded_ring_gathers_contiguous_images_byte_for_byte():
+    from sheeprl_tpu.data.device_ring import ShardedDeviceRingPrefetcher
+    from sheeprl_tpu.parallel import Distributed
+
+    dist = Distributed(devices=2)
+    rb = EnvIndependentReplayBuffer(
+        16, n_envs=4, obs_keys=KEYS + ("big",), buffer_cls=SequentialReplayBuffer, seed=3
+    )
+    ring = ShardedDeviceRingPrefetcher(rb, batch_size=4, sequence_length=5, cnn_keys=IMG_KEYS, dist=dist)
+    for t in range(23):  # wraps
+        rb.add(_img_row(t, 4))
+    batch = ring.take(2)
+    assert batch["big"].shape == (2, 5, 4, 64, 64, 1)
+    assert batch["big"].sharding.spec == jax.sharding.PartitionSpec(None, None, "dp")
+    assert all(r["big"].shape == (16, 2, 32, 128) for r in ring.ring)
+    host = np.asarray(batch["state"])  # state = 1000*t + env names the row
+    for g in range(2):
+        for c in range(4):
+            t0, env = int(host[g, 0, c, 0] // 1000), int(host[g, 0, c, 0] % 1000)
+            for key in IMG_KEYS:
+                want = _host_window(rb, env, t0 % 16, 5, key)
+                assert np.asarray(batch[key])[g, :, c].tobytes() == want.tobytes(), (key, g, c)
+
+
+@pytest.mark.parametrize("next_obs", [False, True], ids=["obs", "next_obs"])
+def test_uniform_ring_gathers_contiguous_images_byte_for_byte(next_obs):
+    from sheeprl_tpu.data import ReplayBuffer
+    from sheeprl_tpu.data.device_ring import DeviceUniformRingPrefetcher
+
+    rb = ReplayBuffer(16, n_envs=2, obs_keys=KEYS + ("big",), seed=11)
+    ring = DeviceUniformRingPrefetcher(rb, 4, cnn_keys=IMG_KEYS, sample_next_obs=next_obs, bucket=8)
+    for t in range(40):  # wraps twice
+        rb.add(_img_row(t, 2))
+        if t % 7 == 0:
+            ring.sync()
+    batch = ring.take(3)
+    idxs, env_idxs = ring._last_idx
+    assert ring.ring["big"].shape == (16, 2, 32, 128) and ring.ring["rgb"].shape == (16, 2, 4, 4, 3)
+    for key in IMG_KEYS:
+        item = rb[key].shape[2:]
+        got = np.asarray(batch[key])
+        assert got.shape == (3, 4) + item and got.dtype == np.uint8
+        assert got.tobytes() == rb[key][idxs, env_idxs].tobytes()
+        if next_obs:
+            nxt = np.asarray(batch[f"next_{key}"])
+            assert nxt.shape == (3, 4) + item and nxt.dtype == np.uint8
+            assert nxt.tobytes() == rb[key][(idxs + 1) % 16, env_idxs].tobytes()
+    host = _ring_host(ring, rb)
+    for key in IMG_KEYS + ("state",):
+        np.testing.assert_array_equal(host[key], rb[key])
+
+
+def test_ring_programs_take_rows_in_the_items_own_shape_too():
+    """The benchmark's rehearsal lowers both programs with leaves of the
+    item's own shape and no `items`: they still compile and behave as before
+    this layout, and a stored ring takes rows of either shape."""
+    import jax.numpy as jnp
+
+    from sheeprl_tpu.data.device_ring import _gather_batch, _scatter_rows
+
+    rows = np.random.default_rng(0).integers(0, 256, (8, 64, 64, 1), np.uint8)
+    idx = jnp.arange(8, dtype=jnp.int32)
+    e_idx = jnp.zeros((8,), jnp.int32)
+    logical = _scatter_rows({"big": jnp.zeros((16, 1, 64, 64, 1), jnp.uint8)}, {"big": rows}, idx, e_idx)
+    stored = _scatter_rows({"big": jnp.zeros((16, 1, 32, 128), jnp.uint8)}, {"big": rows}, idx, e_idx)
+    assert logical["big"].shape == (16, 1, 64, 64, 1) and stored["big"].shape == (16, 1, 32, 128)
+    t_idx = jnp.arange(6, dtype=jnp.int32).reshape(1, 3, 2)
+    e2 = jnp.zeros((2,), jnp.int32)
+    a = _gather_batch(logical, t_idx, e2, ())
+    b = _gather_batch(stored, t_idx, e2, (), items=(("big", (64, 64, 1)),))
+    assert a["big"].shape == b["big"].shape == (1, 3, 2, 64, 64, 1)
+    np.testing.assert_array_equal(np.asarray(a["big"]), np.asarray(b["big"]))
+    np.testing.assert_array_equal(np.asarray(a["big"])[0, :, 0], rows[[0, 2, 4]])
