@@ -32,7 +32,7 @@ import optax
 from ...config import Config, instantiate
 from ...data import EnvIndependentReplayBuffer, SequentialReplayBuffer
 from ...data.device_ring import estimate_row_bytes, make_sequential_prefetcher
-from ...engine import BufferOpSink, OverlapEngine, Packet, RecordingSink
+from ...engine import OverlapEngine, Packet, RecordingSink
 from ...fleet import FleetEngine
 from ...distributions import (
     BernoulliSafeMode,
@@ -701,13 +701,13 @@ def main(dist: Distributed, cfg: Config) -> None:
     _progress = int(os.environ.get("SHEEPRL_TPU_PROGRESS", "0") or 0)
     _t0 = time.perf_counter()
 
-    p_step = policy_step  # player-side env-step counter (== policy_step serially)
+    p_step = policy_step  # player-side env-step counter (== policy_step with the inline source)
 
     def interact(sink) -> None:
         """ONE vector env step (the reference train() env block): act from
-        the mirror snapshot and record the replay-row mutations into `sink`
-        — the real buffer serially (no copies), a `RecordingSink` packet
-        under the overlap engine (applied learner-side in order)."""
+        the mirror snapshot and record the replay-row mutations into `sink`,
+        a `RecordingSink` that rides a packet and is applied learner-side in
+        order."""
         nonlocal obs, player_state, player_key, p_step
         if p_step <= learning_starts:
             actions_env = np.stack([action_space.sample() for _ in range(num_envs)])
@@ -750,8 +750,8 @@ def main(dist: Distributed, cfg: Config) -> None:
         dones = np.logical_or(terminated, truncated)
 
         for ep_rew, ep_len in episode_stats(info):
-            # through the sink: the aggregator is not thread-safe, so under
-            # overlap these ride the packet and land on the learner thread
+            # through the sink: the aggregator is not thread-safe, so these
+            # ride the packet and land on the learner thread
             sink.stat("Rewards/rew_avg", ep_rew)
             sink.stat("Game/ep_len_avg", ep_len)
 
@@ -821,171 +821,92 @@ def main(dist: Distributed, cfg: Config) -> None:
             with telem.span("Time/checkpoint"):
                 ckpt.save(policy_step, _ckpt_state())
 
-    engine = OverlapEngine.setup(
-        cfg, telem, guard, total_steps=total_steps, initial_step=policy_step
-    )
-    fleet = FleetEngine.setup(
-        cfg, telem, guard, total_steps=total_steps, initial_step=policy_step
-    )
-    if fleet.enabled:
-        # ---- supervised actor-fleet loop (sheeprl_tpu/fleet/): worker
-        # processes run the recurrent player against published {wm, actor}
-        # snapshots; each worker's ops replay against its own global env
-        # columns of the per-env sequential buffer (apply_sliced), so a
-        # quarantined slice simply stops growing. One round per num_envs
-        # quantum keeps the Ratio ledger identical to the serial loop's.
-        fleet.start("sheeprl_tpu.fleet.programs:dreamer_v3_program", num_envs, cfg)
-        fleet.publish(mirror.current())
-        stopped = False
-        bursts = 0  # train calls so far: the `burst` of Time/train_time
-        while policy_step < total_steps:
-            telem.tick(policy_step)
-            if guard.stop_reached(policy_step, total_steps, None, save=False):
-                stopped = True
-                break
-            with telem.span("Time/env_interaction_time"):
-                rnd = fleet.take_round(policy_step)
-            if rnd is None:
-                break
-            with telem.span("Time/learner_apply", env_steps=rnd.env_steps, packets=1):
-                fleet.apply_sliced(rnd, rb, aggregator)
-            policy_step += rnd.env_steps
-            g = 0
-            if policy_step >= learning_starts:
-                g = ratio(policy_step / dist.world_size)
-                telem.record_grad_steps(g)
-            if g > 0:
-                bursts += 1
-                with telem.span("Time/train_time", grad_steps=g, burst=bursts):
-                    batches = prefetch.take(g)  # [G, T, B, ...]
-                    root_key, keys = burst_keys(root_key, g)
-                    params, opt_states, moments, metrics = train(params, opt_states, moments, batches, keys)
-                if not MetricAggregator.disabled:
-                    pending_metrics.append(metrics)
-                mirror.refresh(player_view(params))
-                fleet.publish(mirror.current())
-                run_info.mark_steady(policy_step, sync=lambda: jax.block_until_ready(metrics))
-            if learning_starts <= policy_step < total_steps:
-                # same guard as the serial loop: staging before training can
-                # start would pay a host sample that take() can never use
-                with telem.span("Time/replay_stage"):
-                    prefetch.stage(ratio.peek((policy_step + rnd.env_steps) / dist.world_size))
-            flush_logs()
-            maybe_checkpoint()
-        policy_step += fleet.shutdown(lambda r: fleet.apply_sliced(r, rb, aggregator))
-        if (stopped or policy_step < total_steps) and not guard.preempted and cfg.checkpoint.save_last:
-            ckpt.save(policy_step, _ckpt_state())
-    elif engine.enabled:
-        # ---- overlapped player/learner loop (engine/overlap.py) ----------
-        def play() -> Packet:  # the engine times it under Time/env_interaction_time
-            rec = RecordingSink()
-            interact(rec)
-            return Packet(rec, num_envs)
+    def play() -> Packet:  # the source times it under Time/env_interaction_time
+        rec = RecordingSink()
+        interact(rec)
+        return Packet(rec, num_envs)
 
-        engine.start(play)
-        stopped = False
-        while policy_step < total_steps:
-            telem.tick(policy_step)
-            if guard.stop_reached(policy_step, total_steps, None, save=False):
-                stopped = True
-                break
-            packets = engine.take()
-            if not packets:
-                break
-            # ack packets in FIFO order, feeding the Ratio ledger exactly as
-            # the serial loop would (one call per num_envs env steps)
-            gs = []
-            with telem.span(
-                "Time/learner_apply", env_steps=sum(pkt.env_steps for pkt in packets), packets=len(packets)
-            ):
-                for pkt in packets:
-                    pkt.apply(rb, aggregator)
-                    policy_step += pkt.env_steps
-                    if policy_step >= learning_starts:
-                        g = ratio(policy_step / dist.world_size)
-                        telem.record_grad_steps(g)
-                        gs.append(g)
-            if _progress and policy_step % _progress < num_envs * len(packets):
-                print(
-                    f"[progress] step={policy_step} t={time.perf_counter() - _t0:.1f}s",
-                    file=sys.stderr,
-                    flush=True,
-                )
-            # one train call per owed burst, same [G, ...] shapes as the
-            # serial loop (no new compiled shapes, no retraces); dispatch is
-            # async, so staging the next burst overlaps device execution
-            bursting = False
-            for i, g in enumerate(gs):
-                if g <= 0:
-                    continue
-                with telem.span("Time/train_time", grad_steps=g, burst=engine.burst):
-                    bursting = True
-                    batches = prefetch.take(g)  # [G, T, B, ...]
-                    root_key, keys = burst_keys(root_key, g)
-                    params, opt_states, moments, metrics = train(params, opt_states, moments, batches, keys)
-                if not MetricAggregator.disabled:
-                    pending_metrics.append(metrics)
-                nxt = next((x for x in gs[i + 1 :] if x > 0), 0)
-                if nxt > 0:
-                    with telem.span("Time/replay_stage"):
-                        prefetch.stage(nxt)
-            if bursting:
-                mirror.refresh(player_view(params))
-                run_info.mark_steady(policy_step, sync=lambda: jax.block_until_ready(metrics))
-            engine.published()  # release take()'s claim every iteration
-            if policy_step < total_steps:
-                with telem.span("Time/replay_stage"):
-                    prefetch.stage(ratio.peek((policy_step + num_envs) / dist.world_size))
-            flush_logs()
-            maybe_checkpoint()
-        # drain: player stops feeding, queued transitions land in the buffer
-        # so the final checkpoint is consistent (the ratio ledger catches up
-        # at resume time for drained-but-untrained steps)
-        policy_step += engine.shutdown(lambda pkt: pkt.apply(rb, aggregator))
-        if stopped and not guard.preempted and cfg.checkpoint.save_last:
-            ckpt.save(policy_step, _ckpt_state())
+    # Who produces the packets the loop below consumes is decided here, once
+    # (the protocol is in engine/overlap.py): supervised worker PROCESSES
+    # running the recurrent player against published {wm, actor} snapshots,
+    # each worker's ops replayed against its own global env columns of the
+    # per-env sequential buffer (apply_sliced: a quarantined slice simply
+    # stops growing); else `play` on a player thread beside this one, or
+    # inline on this thread (`algo.overlap.enabled`).
+    source = FleetEngine.setup(cfg, telem, guard, total_steps=total_steps, initial_step=policy_step)
+    if source.enabled:
+        source.start(
+            "sheeprl_tpu.fleet.programs:dreamer_v3_program", num_envs, cfg, apply=FleetEngine.apply_sliced
+        )
+        source.published(mirror.current())  # v1: the workers act with these
     else:
-        # ---- serial loop (reference semantics) ----------------------------
-        sink = BufferOpSink(rb, aggregator)
-        bursts = 0  # train calls so far: the `burst` of Time/train_time
-        while policy_step < total_steps:
-            telem.tick(policy_step)
-            if guard.stop_reached(policy_step, total_steps, _ckpt_state):
-                break
-            if _progress and policy_step % _progress < num_envs:
-                print(
-                    f"[progress] step={policy_step} t={time.perf_counter() - _t0:.1f}s",
-                    file=sys.stderr,
-                    flush=True,
-                )
-            with telem.span("Time/env_interaction_time", env_steps=num_envs, version=bursts):
-                interact(sink)
-            policy_step = p_step
-
-            if policy_step >= learning_starts:
-                per_rank_gradient_steps = ratio(policy_step / dist.world_size)
-                telem.record_grad_steps(per_rank_gradient_steps)
-                if per_rank_gradient_steps > 0:
-                    bursts += 1
-                    with telem.span("Time/train_time", grad_steps=per_rank_gradient_steps, burst=bursts):
-                        batches = prefetch.take(per_rank_gradient_steps)  # [G, T, B, ...]
-                        root_key, keys = burst_keys(root_key, per_rank_gradient_steps)
-                        params, opt_states, moments, metrics = train(params, opt_states, moments, batches, keys)
-                    # metrics stay on device until log time — no per-step host sync
-                    if not MetricAggregator.disabled:
-                        # device refs held until the log-cadence host sync;
-                        # skip entirely when metrics are off (bench legs)
-                        pending_metrics.append(metrics)
-                    mirror.refresh(player_view(params))
-                    run_info.mark_steady(policy_step, sync=lambda: jax.block_until_ready(metrics))
-                if policy_step < total_steps:
-                    # overlap the next sample + host→HBM transfer with the train
-                    # step the device is computing right now
-                    with telem.span("Time/replay_stage"):
-                        prefetch.stage(ratio.peek((policy_step + num_envs) / dist.world_size))
-
-            flush_logs()
-            maybe_checkpoint()
+        source = OverlapEngine.setup(
+            cfg, telem, guard, total_steps=total_steps, initial_step=policy_step
+        ).start(play)
+    stopped = False
+    while policy_step < total_steps:
+        telem.tick(policy_step)
+        if guard.stop_reached(policy_step, total_steps, None, save=False):
+            stopped = True
+            break
+        packets = source.take()
+        if not packets:
+            break
+        # ack packets in FIFO order, one Ratio call per packet at the true
+        # cumulative step: the ledger is the same whichever source fed it
+        gs = []
+        taken = sum(pkt.env_steps for pkt in packets)
+        with telem.span("Time/learner_apply", env_steps=taken, packets=len(packets)):
+            for pkt in packets:
+                pkt.apply(rb, aggregator)
+                policy_step += pkt.env_steps
+                if policy_step >= learning_starts:
+                    g = ratio(policy_step / dist.world_size)
+                    telem.record_grad_steps(g)
+                    gs.append(g)
+        if _progress and policy_step % _progress < taken:
+            print(
+                f"[progress] step={policy_step} t={time.perf_counter() - _t0:.1f}s",
+                file=sys.stderr,
+                flush=True,
+            )
+        # one train call per owed burst, always [G, ...] shapes (no new
+        # compiled shapes, no retraces); dispatch is async, so staging the
+        # next burst overlaps device execution
+        bursting = False
+        for i, g in enumerate(gs):
+            if g <= 0:
+                continue
+            with telem.span("Time/train_time", grad_steps=g, burst=source.burst):
+                bursting = True
+                batches = prefetch.take(g)  # [G, T, B, ...]
+                root_key, keys = burst_keys(root_key, g)
+                params, opt_states, moments, metrics = train(params, opt_states, moments, batches, keys)
+            # held on device until log time; not at all when metrics are off
+            if not MetricAggregator.disabled:
+                pending_metrics.append(metrics)
+            nxt = next((x for x in gs[i + 1 :] if x > 0), 0)
+            if nxt > 0:
+                with telem.span("Time/replay_stage"):
+                    prefetch.stage(nxt)
+        if bursting:
+            mirror.refresh(player_view(params))
+            run_info.mark_steady(policy_step, sync=lambda: jax.block_until_ready(metrics))
+        # every iteration: releases take()'s claim (a fleet is sent the params)
+        source.published(mirror.current() if bursting else None)
+        if policy_step < total_steps:
+            # the next packet is as large as this one (a degraded fleet round is smaller)
+            with telem.span("Time/replay_stage"):
+                prefetch.stage(ratio.peek((policy_step + packets[-1].env_steps) / dist.world_size))
+        flush_logs()
+        maybe_checkpoint()
+    # drain: what the source had queued lands in the buffer, so the final
+    # checkpoint is consistent (the ratio catches up at resume)
+    policy_step += source.shutdown(lambda pkt: pkt.apply(rb, aggregator))
+    # an early exit (wall cap, or a fleet whose every worker is quarantined)
+    # still leaves a resumable checkpoint; preemption saves through the guard
+    if (stopped or policy_step < total_steps) and not guard.preempted and cfg.checkpoint.save_last:
+        ckpt.save(policy_step, _ckpt_state())
 
     guard.close(policy_step, _ckpt_state)
     if envs is not None:

@@ -228,7 +228,7 @@ def main(dist: Distributed, cfg: Config) -> None:
 
     def _ckpt_state():
         # `completed_update` = the last update whose params this checkpoint
-        # carries (resume restarts at +1). The overlapped loop can break at
+        # carries (resume restarts at +1). Both loops can break at
         # the TOP of an iteration (preemption/wall-cap before the update
         # ran), so the loop counter itself would over-count by one there.
         return {
@@ -245,8 +245,8 @@ def main(dist: Distributed, cfg: Config) -> None:
         """One rollout_steps collection (reference ppo.py:232-312): acts
         with the mirror snapshot, fills `buf`, and returns
         ``(local [T, N, ...] dict, bootstrap next_value, episode stats)``.
-        Runs on the calling thread serially, on the player thread under the
-        overlap engine (everything it touches — envs, mirror, rollout
+        Runs on the learner's thread with the inline source, on the player
+        thread otherwise (everything it touches — envs, mirror, rollout
         buffer, player_key — is player-owned; episode stats are RETURNED,
         not aggregated, because the aggregator is not thread-safe and its
         writes must stay on the learner thread)."""
@@ -380,14 +380,6 @@ def main(dist: Distributed, cfg: Config) -> None:
             last_checkpoint = policy_step
             ckpt.save(policy_step, _ckpt_state())
 
-    engine = OverlapEngine.setup(
-        cfg,
-        telem,
-        guard,
-        total_steps=num_updates * policy_steps_per_iter,
-        initial_step=policy_step,
-        default_queue_depth=1,  # at most one rollout ahead of the learner
-    )
     fleet = FleetEngine.setup(
         cfg,
         telem,
@@ -400,7 +392,7 @@ def main(dist: Distributed, cfg: Config) -> None:
     if fleet.enabled:
         # ---- supervised actor-fleet loop (sheeprl_tpu/fleet/): each worker
         # collects ONE rollout slice per param publication (strict on-policy
-        # round protocol — the fleet twin of the overlap engine's
+        # round protocol — the fleet twin of the player thread's
         # staleness_bound=0 mode), merged full-width learner-side. A
         # quarantined worker's columns are backfilled by duplicating
         # surviving slices so the jitted update's shapes never change.
@@ -437,20 +429,31 @@ def main(dist: Distributed, cfg: Config) -> None:
             maybe_checkpoint(update_iter)
             update_iter += 1
         # queued rollouts (collected for params that will never act again)
-        # are dropped — PPO keeps no cross-update buffer, same as overlap
+        # are dropped — PPO keeps no cross-update buffer, same as in-process
         fleet.shutdown()
         if (stopped or update_iter <= num_updates) and not guard.preempted and cfg.checkpoint.save_last:
             ckpt.save(policy_step, _ckpt_state())
-    elif engine.enabled:
-        # ---- overlapped rollout/update loop (engine/overlap.py): the
-        # player collects rollout k+1 against the pre-update mirror snapshot
-        # (staleness = one update; the clipped surrogate absorbs it) while
-        # the learner updates on rollout k ------------------------------
-        # ping-pong rollout buffers instead of a per-update deep copy: with
-        # the engine's pre-collection backpressure, a buffer is only refilled
-        # after the learner has consumed the packet queue_depth packets back,
-        # so queue_depth+1 buffers cycled round-robin are race-free and the
-        # multi-MB snapshot copy disappears from the player's critical path.
+    else:
+        # ---- in-process loop: `play` is one rollout, which the engine runs
+        # on a player thread beside this one or inline on this thread
+        # (`algo.overlap.enabled`, engine/overlap.py). Threaded with
+        # staleness_bound 0 (the default) the player waits for each update to
+        # publish and acts with the params the inline source acts with; with
+        # bound 1 it collects rollout k+1 against the pre-update snapshot
+        # while the learner updates on rollout k.
+        # Ping-pong rollout buffers instead of a per-update deep copy: the
+        # engine's pre-collection backpressure refills a buffer only after
+        # the learner has consumed the packet `run_ahead` packets back, so
+        # run_ahead+1 buffers cycled round-robin are race-free (inline nothing
+        # runs ahead: `rb` alone).
+        engine = OverlapEngine.setup(
+            cfg,
+            telem,
+            guard,
+            total_steps=num_updates * policy_steps_per_iter,
+            initial_step=policy_step,
+            default_queue_depth=1,  # at most one rollout ahead of the learner
+        )
         bufs = [rb] + [
             ReplayBuffer(
                 rollout_steps,
@@ -462,7 +465,7 @@ def main(dist: Distributed, cfg: Config) -> None:
                 else None,
                 seed=cfg.seed + 1024 * rank + 7 * (i + 1),
             )
-            for i in range(engine.queue_depth)
+            for i in range(engine.run_ahead)
         ]
         buf_idx = [0]
 
@@ -502,30 +505,6 @@ def main(dist: Distributed, cfg: Config) -> None:
         engine.shutdown()
         if stopped and not guard.preempted and cfg.checkpoint.save_last:
             ckpt.save(policy_step, _ckpt_state())
-    else:
-        # ---- serial loop (reference semantics) ---------------------------
-        for update_iter in range(start_iter, num_updates + 1):
-            telem.tick(policy_step)
-            with telem.span("Time/env_interaction_time"):
-                local, next_value, ep_stats = rollout(rb)
-            policy_step += policy_steps_per_iter
-            record_ep_stats(ep_stats)
-
-            with telem.span("Time/train_time"):
-                metrics = update_from(local, next_value, update_iter)
-                mirror.refresh(params)  # blocking: next rollout acts with fresh params
-                run_info.mark_steady(policy_step)
-            completed_update = update_iter
-
-            if aggregator is not None:
-                for k, v in metrics.items():
-                    aggregator.update(k, np.asarray(v))  # host-sync: ok (update cadence)
-
-            flush_logs()
-            maybe_checkpoint(update_iter)
-
-            if guard.stop_reached(policy_step, int(cfg.algo.total_steps), _ckpt_state):
-                break
 
     guard.close(policy_step, _ckpt_state)
     if envs is not None:

@@ -28,7 +28,7 @@ import optax
 from ...config import Config, instantiate
 from ...data import ReplayBuffer
 from ...data.device_ring import estimate_row_bytes, make_uniform_prefetcher
-from ...engine import BufferOpSink, OverlapEngine, Packet, RecordingSink
+from ...engine import OverlapEngine, Packet, RecordingSink
 from ...fleet import FleetEngine
 from ...parallel import Distributed
 from ...parallel.placement import make_param_mirror
@@ -246,12 +246,12 @@ def main(dist: Distributed, cfg: Config) -> None:
             s["rb"] = rb.checkpoint_state_dict()
         return s
 
-    p_step = policy_step  # player-side env-step counter (== policy_step serially)
+    p_step = policy_step  # player-side env-step counter (== policy_step with the inline source)
 
     def interact(sink) -> None:
         """ONE vector env step (reference sac.py env block): act from the
-        mirror snapshot, record the replay row into `sink` — the real buffer
-        serially (no copies), a `RecordingSink` packet under overlap."""
+        mirror snapshot, record the replay row into `sink`, a `RecordingSink`
+        that rides a packet and is applied learner-side."""
         nonlocal obs_vec, player_key, p_step
         if p_step <= learning_starts:
             env_actions = np.stack([action_space.sample() for _ in range(num_envs)])
@@ -284,24 +284,25 @@ def main(dist: Distributed, cfg: Config) -> None:
         obs_vec = flatten_obs(next_obs, mlp_keys, num_envs)
 
         for ep_rew, ep_len in episode_stats(info):
-            # through the sink: the aggregator is not thread-safe, so under
-            # overlap these ride the packet and land on the learner thread
+            # through the sink: the aggregator is not thread-safe, so these
+            # ride the packet and land on the learner thread
             sink.stat("Rewards/rew_avg", ep_rew)
             sink.stat("Game/ep_len_avg", ep_len)
 
     def flush_logs() -> None:
         nonlocal last_log
         if policy_step - last_log >= cfg.metric.log_every or cfg.dry_run:
-            for m in pending_metrics:  # host-sync deferred to log cadence
-                for k, v in m.items():
-                    aggregator.update(k, np.asarray(v))
-            pending_metrics.clear()
-            telem.log(
-                policy_step,
-                extra_metrics={"Params/replay_ratio": cumulative_grad_steps * dist.world_size / policy_step}
-                if policy_step > 0
-                else None,
-            )
+            with telem.span("Time/log_flush"):
+                for m in pending_metrics:  # host-sync deferred to log cadence
+                    for k, v in m.items():
+                        aggregator.update(k, np.asarray(v))
+                pending_metrics.clear()
+                telem.log(
+                    policy_step,
+                    extra_metrics={"Params/replay_ratio": cumulative_grad_steps * dist.world_size / policy_step}
+                    if policy_step > 0
+                    else None,
+                )
             last_log = policy_step
 
     def maybe_checkpoint() -> None:
@@ -310,155 +311,84 @@ def main(dist: Distributed, cfg: Config) -> None:
             cfg.checkpoint.every > 0 and policy_step - last_checkpoint >= cfg.checkpoint.every
         ) or cfg.dry_run or policy_step >= total_steps:
             last_checkpoint = policy_step
-            ckpt.save(policy_step, _ckpt_state())
+            with telem.span("Time/checkpoint"):
+                ckpt.save(policy_step, _ckpt_state())
 
-    engine = OverlapEngine.setup(
-        cfg, telem, guard, total_steps=total_steps, initial_step=policy_step
-    )
-    fleet = FleetEngine.setup(
-        cfg, telem, guard, total_steps=total_steps, initial_step=policy_step
-    )
-    if fleet.enabled:
-        # ---- supervised actor-fleet loop (sheeprl_tpu/fleet/) ------------
-        # N worker processes step the env slices and stream RecordingSink
-        # packets; one ROUND (one packet per active worker, FIFO-merged in
-        # worker order) is the serial loop's num_envs quantum, so the Ratio
-        # ledger below is fed with exactly the serial call sequence.
-        fleet.start("sheeprl_tpu.fleet.programs:sac_program", num_envs, cfg)
-        fleet.publish(mirror.current())  # v1: workers act with these params
-        stopped = False
-        while policy_step < total_steps:
-            telem.tick(policy_step)
-            if guard.stop_reached(policy_step, total_steps, None, save=False):
-                stopped = True
-                break
-            with telem.span("Time/env_interaction_time"):
-                rnd = fleet.take_round(policy_step)
-            if rnd is None:
-                break
-            fleet.apply_concat(rnd, rb, aggregator, validate=cfg.buffer.validate_args)
-            policy_step += rnd.env_steps
-            g = 0
-            if policy_step >= learning_starts:
-                g = ratio(policy_step / dist.world_size)
-                telem.record_grad_steps(g)
-            if g > 0:
-                with telem.span("Time/train_time"):
-                    batches = prefetch.take(g)  # [G, B, ...]
-                    root_key, sub = jax.random.split(root_key)
-                    params, opt_states, metrics = train(
-                        params, opt_states, batches, jax.random.split(sub, g)
-                    )
-                    cumulative_grad_steps += g
-                if not MetricAggregator.disabled:
-                    pending_metrics.append(metrics)
-                # ParamMirror → fleet publication: the same snapshot path
-                # the overlap engine and serve/reload share
-                mirror.refresh({"actor": params["actor"]})
-                fleet.publish(mirror.current())
-                run_info.mark_steady(policy_step, sync=lambda: jax.block_until_ready(metrics))
-            if learning_starts <= policy_step < total_steps:
-                # same guard as the serial loop: staging before training can
-                # start would pay a host sample that take() can never use
-                prefetch.stage(ratio.peek((policy_step + rnd.env_steps) / dist.world_size))
-            flush_logs()
-            maybe_checkpoint()
-        # drain: every COMPLETE queued round lands in the buffer so the
-        # final checkpoint is consistent (ratio catches up at resume)
-        policy_step += fleet.shutdown(
-            lambda r: fleet.apply_concat(r, rb, aggregator, validate=cfg.buffer.validate_args)
-        )
-        # an early exit (wall cap / whole-fleet quarantine halt) still
-        # leaves a resumable checkpoint; preemption saves through the guard
-        if (stopped or policy_step < total_steps) and not guard.preempted and cfg.checkpoint.save_last:
-            ckpt.save(policy_step, _ckpt_state())
-    elif engine.enabled:
-        # ---- overlapped player/learner loop (engine/overlap.py) ----------
-        def play() -> Packet:  # the engine times it under Time/env_interaction_time
-            rec = RecordingSink()
-            interact(rec)
-            return Packet(rec, num_envs)
+    def play() -> Packet:  # the source times it under Time/env_interaction_time
+        rec = RecordingSink()
+        interact(rec)
+        return Packet(rec, num_envs)
 
-        engine.start(play)
-        stopped = False
-        while policy_step < total_steps:
-            telem.tick(policy_step)
-            if guard.stop_reached(policy_step, total_steps, None, save=False):
-                stopped = True
-                break
-            packets = engine.take()
-            if not packets:
-                break
-            gs = []
-            for pkt in packets:  # FIFO ack: the Ratio ledger matches serial
+    # Who produces the packets the loop below consumes is decided here, once
+    # (the protocol is in engine/overlap.py): supervised worker PROCESSES that
+    # step the env slices, one ROUND (one packet per active worker, merged
+    # full-width in worker order: apply_concat) per num_envs quantum; else
+    # `play` on a player thread beside this one, or inline on this thread
+    # (`algo.overlap.enabled`).
+    source = FleetEngine.setup(cfg, telem, guard, total_steps=total_steps, initial_step=policy_step)
+    if source.enabled:
+        source.start("sheeprl_tpu.fleet.programs:sac_program", num_envs, cfg, apply=FleetEngine.apply_concat)
+        source.published(mirror.current())  # v1: the workers act with these
+    else:
+        source = OverlapEngine.setup(
+            cfg, telem, guard, total_steps=total_steps, initial_step=policy_step
+        ).start(play)
+    stopped = False
+    while policy_step < total_steps:
+        telem.tick(policy_step)
+        if guard.stop_reached(policy_step, total_steps, None, save=False):
+            stopped = True
+            break
+        packets = source.take()
+        if not packets:
+            break
+        # ack packets in FIFO order, one Ratio call per packet at the true
+        # cumulative step: the ledger is the same whichever source fed it
+        gs = []
+        taken = sum(pkt.env_steps for pkt in packets)
+        with telem.span("Time/learner_apply", env_steps=taken, packets=len(packets)):
+            for pkt in packets:
                 pkt.apply(rb, aggregator)
                 policy_step += pkt.env_steps
                 if policy_step >= learning_starts:
                     g = ratio(policy_step / dist.world_size)
                     telem.record_grad_steps(g)
                     gs.append(g)
-            bursting = False
-            for i, g in enumerate(gs):
-                if g <= 0:
-                    continue
-                with telem.span("Time/train_time"):
-                    bursting = True
-                    batches = prefetch.take(g)  # [G, B, ...]
-                    root_key, sub = jax.random.split(root_key)
-                    params, opt_states, metrics = train(
-                        params, opt_states, batches, jax.random.split(sub, g)
-                    )
-                    cumulative_grad_steps += g
-                if not MetricAggregator.disabled:
-                    pending_metrics.append(metrics)
-                nxt = next((x for x in gs[i + 1 :] if x > 0), 0)
-                if nxt > 0:
+        bursting = False
+        for i, g in enumerate(gs):
+            if g <= 0:
+                continue
+            with telem.span("Time/train_time", grad_steps=g, burst=source.burst):
+                bursting = True
+                batches = prefetch.take(g)  # [G, B, ...]
+                root_key, sub = jax.random.split(root_key)
+                params, opt_states, metrics = train(params, opt_states, batches, jax.random.split(sub, g))
+                cumulative_grad_steps += g
+            # held on device until log time; not at all when metrics are off
+            if not MetricAggregator.disabled:
+                pending_metrics.append(metrics)
+            nxt = next((x for x in gs[i + 1 :] if x > 0), 0)
+            if nxt > 0:
+                with telem.span("Time/replay_stage"):
                     prefetch.stage(nxt)
-            if bursting:
-                mirror.refresh({"actor": params["actor"]})
-                run_info.mark_steady(policy_step, sync=lambda: jax.block_until_ready(metrics))
-            engine.published()  # release take()'s claim every iteration
-            if policy_step < total_steps:
-                prefetch.stage(ratio.peek((policy_step + num_envs) / dist.world_size))
-            flush_logs()
-            maybe_checkpoint()
-        # drain: queued transitions land in the buffer so the final
-        # checkpoint is consistent (ratio catches up at resume)
-        policy_step += engine.shutdown(lambda pkt: pkt.apply(rb, aggregator))
-        if stopped and not guard.preempted and cfg.checkpoint.save_last:
-            ckpt.save(policy_step, _ckpt_state())
-    else:
-        # ---- serial loop (reference semantics) ---------------------------
-        sink = BufferOpSink(rb, aggregator)
-        while policy_step < total_steps:
-            telem.tick(policy_step)
-            if guard.stop_reached(policy_step, total_steps, _ckpt_state):
-                break
-            with telem.span("Time/env_interaction_time"):
-                interact(sink)
-            policy_step = p_step
-
-            if policy_step >= learning_starts:
-                per_rank_gradient_steps = ratio(policy_step / dist.world_size)
-                telem.record_grad_steps(per_rank_gradient_steps)
-                if per_rank_gradient_steps > 0:
-                    with telem.span("Time/train_time"):
-                        batches = prefetch.take(per_rank_gradient_steps)  # [G, B, ...]
-                        root_key, sub = jax.random.split(root_key)
-                        keys = jax.random.split(sub, per_rank_gradient_steps)
-                        params, opt_states, metrics = train(params, opt_states, batches, keys)
-                        cumulative_grad_steps += per_rank_gradient_steps
-                    if not MetricAggregator.disabled:
-                        # device refs held until the log-cadence host sync;
-                        # skip entirely when metrics are off (bench legs)
-                        pending_metrics.append(metrics)
-                    mirror.refresh({"actor": params["actor"]})
-                    run_info.mark_steady(policy_step, sync=lambda: jax.block_until_ready(metrics))
-                if policy_step < total_steps:
-                    prefetch.stage(ratio.peek((policy_step + num_envs) / dist.world_size))
-
-            flush_logs()
-            maybe_checkpoint()
+        if bursting:
+            mirror.refresh({"actor": params["actor"]})
+            run_info.mark_steady(policy_step, sync=lambda: jax.block_until_ready(metrics))
+        # every iteration: releases take()'s claim (a fleet is sent the params)
+        source.published(mirror.current() if bursting else None)
+        if policy_step < total_steps:
+            # the next packet is as large as this one (a degraded fleet round is smaller)
+            with telem.span("Time/replay_stage"):
+                prefetch.stage(ratio.peek((policy_step + packets[-1].env_steps) / dist.world_size))
+        flush_logs()
+        maybe_checkpoint()
+    # drain: what the source had queued lands in the buffer, so the final
+    # checkpoint is consistent (the ratio catches up at resume)
+    policy_step += source.shutdown(lambda pkt: pkt.apply(rb, aggregator))
+    # an early exit (wall cap, or a fleet whose every worker is quarantined)
+    # still leaves a resumable checkpoint; preemption saves through the guard
+    if (stopped or policy_step < total_steps) and not guard.preempted and cfg.checkpoint.save_last:
+        ckpt.save(policy_step, _ckpt_state())
 
     guard.close(policy_step, _ckpt_state)
     if envs is not None:

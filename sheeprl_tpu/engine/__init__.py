@@ -2,6 +2,6 @@
 (concurrency, overlap, cadence), while the algorithms keep deciding *what*
 runs (losses, agents, buffers)."""
 
-from .overlap import BufferOpSink, OverlapEngine, Packet, RecordingSink, SpscRing
+from .overlap import OverlapEngine, Packet, RecordingSink, SpscRing, telem_span
 
-__all__ = ["BufferOpSink", "OverlapEngine", "Packet", "RecordingSink", "SpscRing"]
+__all__ = ["OverlapEngine", "Packet", "RecordingSink", "SpscRing", "telem_span"]

@@ -1,10 +1,23 @@
-"""Overlapped player/learner engine: concurrent acting + training with
-bounded staleness.
+"""The in-process sources of packets: a player thread beside the learner
+(concurrent acting + training with bounded staleness), or the same player
+inline on the learner's thread.
 
-The serial loops interleave env interaction and gradient bursts in one
-thread, so the device idles while Python steps environments, and the
-player's jitted ``act`` dispatches queue behind the scanned train burst on
-the same device stream. The fix is the Podracer/Sebulba split (arXiv:
+An algorithm's ``main`` has ONE learner loop, written against the source
+protocol: ``start(play)``, ``take(max_packets=0) -> list`` (empty: ended), a
+packet with ``env_steps`` and ``apply(rb, aggregator)``, ``published(...)``
+once per iteration that consumed packets, ``burst``, and ``shutdown(absorb)
+-> drained env steps``. :class:`OverlapEngine` answers it twice, selected by
+``algo.overlap.enabled``, and :class:`sheeprl_tpu.fleet.FleetEngine` a third
+time with worker processes (``algo.fleet.workers > 0``).
+
+**Inline** (``enabled`` false): ``take()`` runs ``play`` once on the caller's
+thread and returns that one packet. Env interaction and gradient bursts then
+interleave in one thread: the device idles while Python steps environments,
+and the player acts with the params the last iteration refreshed. No thread,
+no ring, no gate, no ``overlap`` event; tests use it as the reference of the
+other two sources.
+
+**Threaded** (``enabled`` true) is the Podracer/Sebulba split (arXiv:
 2104.06272), re-derived for a single-controller JAX process. (The
 multi-PROCESS twin of this split lives in the actor fleet: under
 ``fleet.act_mode=inference`` the workers ship obs batches to the
@@ -27,8 +40,8 @@ under one jitted scan, the Anakin corner of the same paper.)
   configured bound if a future learner ever pipelines bursts);
 * **replay-ratio accounting is exact**: the learner feeds the `Ratio`
   controller one call per acknowledged packet, in FIFO order, with the
-  same ``policy_step`` arguments the serial loop would have used — the
-  env-step:grad-step ledger is bit-identical to the serial loop's.
+  same ``policy_step`` arguments the inline source leads to — the
+  env-step:grad-step ledger is bit-identical to the inline run's.
 
 Integration contract (what each adopted algorithm provides):
 
@@ -36,8 +49,8 @@ Integration contract (what each adopted algorithm provides):
   Dreamer/SAC, one full rollout for PPO) that records its replay-buffer
   mutations into a :class:`RecordingSink` and returns a :class:`Packet`;
 * an ``absorb(packet)`` learner-side apply (usually ``packet.apply(rb)``);
-* ``engine.burst_started()`` / ``engine.published()`` around the train
-  burst + mirror refresh, so the engine can account staleness and stalls.
+* ``engine.published()`` after the train burst + mirror refresh, so the
+  engine can account staleness and stalls.
 
 `RunGuard` integration: the player stops feeding as soon as preemption is
 requested (its queue waits poll ``guard.preempted``); the learner breaks at
@@ -67,7 +80,7 @@ import numpy as np
 
 from ..telemetry.spans import Span
 
-__all__ = ["BufferOpSink", "OverlapEngine", "Packet", "RecordingSink", "SpscRing"]
+__all__ = ["OverlapEngine", "Packet", "RecordingSink", "SpscRing", "telem_span"]
 
 
 class SpscRing:
@@ -154,32 +167,6 @@ class Packet:
             self.payload.apply(rb, aggregator)
 
 
-class BufferOpSink:
-    """Pass-through sink: the serial path — ops hit the buffer (and metric
-    aggregator) directly, with no copies. Shares the recorder's interface
-    so the interaction closure is written once for both modes."""
-
-    __slots__ = ("rb", "aggregator")
-
-    def __init__(self, rb: Any, aggregator: Any = None):
-        self.rb = rb
-        self.aggregator = aggregator
-
-    def add(self, data: Dict[str, np.ndarray], idxes: Any = None, validate_args: bool = False) -> None:
-        if idxes is None:
-            self.rb.add(data, validate_args=validate_args)
-        else:
-            self.rb.add(data, idxes, validate_args=validate_args)
-
-    def mark_restart(self, env_idx: int) -> None:
-        if hasattr(self.rb, "mark_restart"):
-            self.rb.mark_restart(int(env_idx))
-
-    def stat(self, key: str, value: Any) -> None:
-        if self.aggregator is not None:
-            self.aggregator.update(key, value)
-
-
 class RecordingSink:
     """Records replay-buffer mutations player-side, to be applied
     learner-side in the same order.
@@ -187,8 +174,8 @@ class RecordingSink:
     ``add`` **copies** its arrays: the interaction closures reuse/mutate
     their ``step_data`` dicts across iterations (and gymnasium vector envs
     reuse their obs buffers in place), and the learner may apply the op well
-    after the player has moved on. The copy is the price of the handoff —
-    the serial pass-through sink pays none.
+    after the player has moved on. The copy is the price of the handoff,
+    and the inline source pays it too: one closure, one kind of packet.
 
     ``stat`` records metric updates (episode reward/length) for the same
     deferred apply: the aggregator has no locking, so all of its writes
@@ -231,11 +218,17 @@ class RecordingSink:
 _SLEEP_S = 0.0005  # park granularity for a blocked side (≪ one env step)
 
 
-class OverlapEngine:
-    """Concurrent player/learner driver with bounded staleness.
+def telem_span(telem: Any, name: str, **counts: float) -> Span:
+    """The run's span where ``telem`` is its facade (it honours
+    `metric.disable_timer`), else a plain one on the global tracker."""
+    make = getattr(telem, "span", None)
+    return make(name, **counts) if make is not None else Span(name, **counts)
 
-    Construct via :meth:`setup`; when ``enabled`` is False every method is a
-    cheap no-op and the caller runs its serial loop unchanged.
+
+class OverlapEngine:
+    """The in-process source of packets: a player thread with bounded
+    staleness when ``enabled``, else the same ``play`` inline on the caller's
+    thread (module docstring). Construct via :meth:`setup`.
     """
 
     def __init__(
@@ -258,7 +251,7 @@ class OverlapEngine:
         # async dispatch (not its device completion), so the player unblocks
         # in microseconds and env stepping still overlaps device execution —
         # this is the on-policy (PPO) mode: trajectories are bitwise-identical
-        # to the serial loop's, because the acting params are exactly the
+        # to the inline source's, because the acting params are exactly the
         # latest update's.
         self.staleness_bound = max(0, int(staleness_bound))
         self.stats_every_s = float(stats_every_s)
@@ -273,6 +266,7 @@ class OverlapEngine:
         self._player_done = threading.Event()
         self._player_exc: Optional[BaseException] = None
         self._thread: Optional[threading.Thread] = None
+        self._play: Optional[Callable[[], Optional[Packet]]] = None
 
         # learner-owned counters (GIL-atomic int stores; the player only reads)
         self._burst_seq = 0  # bursts started
@@ -323,17 +317,27 @@ class OverlapEngine:
 
     # -- lifecycle ---------------------------------------------------------
     def start(self, play_fn: Callable[[], Optional[Packet]]) -> "OverlapEngine":
-        """Spawn the player thread. ``play_fn()`` performs one env slice and
-        returns a Packet (or None to stop early)."""
-        if not self.enabled or self._thread is not None:
+        """Keep ``play_fn`` (one env slice per call: a Packet, or None to stop
+        early) and, when ``enabled``, spawn the player thread that calls it;
+        inline, :meth:`take` calls it."""
+        if self._play is not None:
             return self
+        self._play = play_fn
         self.produced_steps = self.initial_step
         self.acked_steps = self.initial_step
-        self._thread = threading.Thread(
-            target=self._player_main, args=(play_fn,), name="overlap-player", daemon=True
-        )
-        self._thread.start()
+        if self.enabled:
+            self._thread = threading.Thread(
+                target=self._player_main, args=(play_fn,), name="overlap-player", daemon=True
+            )
+            self._thread.start()
         return self
+
+    @property
+    def run_ahead(self) -> int:
+        """Packets the player may have produced beyond the one the learner
+        holds: a payload that is handed over without a copy (PPO's rollout
+        buffer) needs that many spares."""
+        return self.queue_depth if self.enabled else 0
 
     def _should_stop(self) -> bool:
         if self._stop.is_set():
@@ -342,10 +346,7 @@ class OverlapEngine:
         return g is not None and getattr(g, "preempted", False)
 
     def _span(self, name: str, **counts: float) -> Span:
-        """The run's span where the engine has its facade (it honours
-        `metric.disable_timer`), else a plain one on the global tracker."""
-        make = getattr(self.telem, "span", None)
-        return make(name, **counts) if make is not None else Span(name, **counts)
+        return telem_span(self.telem, name, **counts)
 
     def _book(self, player_busy_s: float = 0.0, player_stall_s: float = 0.0, learner_stall_s: float = 0.0) -> None:
         """A span's elapsed seconds into the interval's `overlap` event."""
@@ -457,7 +458,14 @@ class OverlapEngine:
         a packet landing and its update publishing, a strict
         (``staleness_bound=0``) player is always held by either the queue
         bound or the claim — there is no instant where it could start
-        acting with pre-update params."""
+        acting with pre-update params.
+
+        Inline, there is nothing to drain: one ``play`` on this thread, under
+        the same span with the same counts, is the one packet (``[]`` once a
+        stop is requested or ``play`` returns None; what ``play`` raises,
+        raises here)."""
+        if not self.enabled:
+            return self._take_inline()
         out: List[Packet] = []
         claimed = False
 
@@ -510,16 +518,32 @@ class OverlapEngine:
         self.maybe_emit()
         return out
 
+    def _take_inline(self) -> List[Packet]:
+        if self._should_stop():
+            return []
+        with self._span("Time/env_interaction_time", version=self._pub_seq) as busy:
+            pkt = self._play()
+            if pkt is not None:
+                busy.count(env_steps=pkt.env_steps)
+        if pkt is None:
+            return []
+        self._burst_seq += 1  # the same claim counter: `burst` means one thing
+        pkt.version = self._pub_seq
+        self.acked_steps += pkt.env_steps  # the player-owned counters stay the thread's
+        return [pkt]
+
     def burst_started(self) -> None:
         """Claim an EXTRA burst slot (a pipelined learner dispatching more
         than one unpublished burst); ``take()`` already claims one per
         non-empty drain, so synchronous learners never call this."""
         self._burst_seq += 1
 
-    def published(self) -> None:
+    def published(self, snapshot: Any = None) -> None:
         """Release the claim(s): the iteration's params are published (call
-        after ``mirror.refresh`` when training ran, or bare otherwise —
-        once per learner iteration that consumed packets)."""
+        after ``mirror.refresh`` when training ran — once per learner
+        iteration that consumed packets). ``snapshot``, the refreshed params
+        when training ran, is for a source whose players live elsewhere (the
+        fleet broadcasts it); this one's player reads the mirror itself."""
         self._pub_seq = self._burst_seq
 
     @property
@@ -577,7 +601,7 @@ class OverlapEngine:
         """Stop the player, join it, and drain queued packets through
         ``absorb`` (learner-side buffer apply) so the final checkpoint sees
         every transition that crossed the queue. Returns the env steps
-        drained. Safe to call twice / when disabled."""
+        drained. Safe to call twice; inline, nothing is queued: 0."""
         if not self.enabled:
             return 0
         self._stop.set()

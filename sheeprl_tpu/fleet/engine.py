@@ -6,9 +6,9 @@ write and `Ratio` ledger call happens here, in deterministic order.
 
 The ordering contract is the **round**: one packet from every active worker,
 FIFO per worker, workers in id order. A full-strength round carries exactly
-``num_envs`` env steps — the same quantum the serial loop (and the overlap
-engine) advances per iteration — so feeding the `Ratio` controller once per
-round with the true cumulative ``policy_step`` reproduces the serial
+``num_envs`` env steps — the same quantum one packet of the in-process
+sources (engine/overlap.py) carries — so feeding the `Ratio` controller once
+per round with the true cumulative ``policy_step`` reproduces the in-process
 env-step:grad-step ledger *bit-identically*. A worker mid-respawn delays
 its round (the queue merge waits, monitored, never parked on a dead pipe);
 a **quarantined** worker shrinks the round instead: the fleet keeps
@@ -28,16 +28,30 @@ Two apply modes cover the repo's replay layouts:
   Dreamer family). Each worker's ops are replayed against its own global
   env columns (indices offset by the worker's slice), so quarantined
   columns simply stop growing.
+
+For the ratio-driven algorithms (SAC, DreamerV3) the engine is the third
+**source of packets** behind the protocol of :mod:`sheeprl_tpu.engine.overlap`:
+``take()`` returns one round as one packet whose ``apply(rb, aggregator)`` is
+the apply mode the algorithm named at ``start``; ``published(snapshot)``
+broadcasts the refreshed params; ``burst`` counts the rounds taken;
+``shutdown(absorb)`` drains whole rounds. What only a fleet has lives here and
+not in the learner's loop: a round may carry fewer than ``num_envs`` steps (the
+loop asks the packet), the workers must be SENT the params, and the fleet can
+end by itself (every worker quarantined: ``take()`` returns ``[]``). PPO keeps
+its strict on-policy round protocol (``take_round(min_version=...)``,
+``merge_ppo_round``, ``mark_applied``) and its own loop.
 """
 from __future__ import annotations
 
 import sys
 import time
 from collections import deque
+from functools import partial
 from typing import Any, Callable, Dict, List, NamedTuple, Optional
 
 import numpy as np
 
+from ..engine import telem_span
 from .protocol import FleetPacket, TornPacketError, decode_packet
 from .supervisor import FleetSupervisor
 
@@ -60,11 +74,17 @@ class FleetRound(NamedTuple):
     packets: List[FleetPacket]  # one per contributing worker, id order
     worker_ids: List[int]
     env_steps: int
+    merge: Optional[Callable[..., int]] = None  # the engine's apply mode, named at `start`
+
+    def apply(self, rb: Any, aggregator: Any = None) -> int:
+        """The packet protocol's apply: the round into ``rb`` by the apply
+        mode its engine was started with."""
+        return self.merge(self, rb, aggregator)
 
 
 class FleetEngine:
-    """Construct via :meth:`setup`; when ``enabled`` is False every method is
-    a cheap no-op and the caller runs its serial/overlap path unchanged."""
+    """Construct via :meth:`setup` where :meth:`configured` says the run uses
+    the fleet; when ``enabled`` is False every method is a cheap no-op."""
 
     def __init__(
         self,
@@ -126,6 +146,7 @@ class FleetEngine:
         self.rounds = 0
         self.dropped_steps = 0
         self._pending: Dict[int, deque] = {}
+        self._merge: Optional[Callable[..., int]] = None
         self._stats_round_wait_s = 0.0
         self._last_emit_t = time.perf_counter()
         self._stopped = False
@@ -210,9 +231,18 @@ class FleetEngine:
         )
 
     # -- lifecycle ---------------------------------------------------------
-    def start(self, program: str, num_envs: int, cfg: Any) -> "FleetEngine":
+    def start(
+        self, program: str, num_envs: int, cfg: Any, apply: Optional[Callable[..., int]] = None
+    ) -> "FleetEngine":
+        """Spawn the supervised workers running ``program``. ``apply`` names
+        the mode a round's :meth:`FleetRound.apply` takes
+        (``FleetEngine.apply_sliced`` or ``FleetEngine.apply_concat``, with
+        the run's `buffer.validate_args`); PPO merges its rounds itself and
+        names none."""
         if not self.enabled or self.sup is not None:
             return self
+        if apply is not None:
+            self._merge = partial(apply, self, validate=bool(cfg.select("buffer.validate_args", False)))
         num_envs = int(num_envs)
         if num_envs % self.workers != 0:
             raise ValueError(
@@ -430,11 +460,34 @@ class FleetEngine:
                     self.acked_steps += env_steps
                     self.sup.progress_step = self.acked_steps
                     self.rounds += 1
-                    return FleetRound(packets, list(active), env_steps)
+                    return FleetRound(packets, list(active), env_steps, self._merge)
                 time.sleep(_SLEEP_S)
         finally:
             self._stats_round_wait_s += time.perf_counter() - t0
             self.maybe_emit(step)
+
+    # -- the source protocol (engine/overlap.py) ----------------------------
+    def take(self, max_packets: int = 0) -> List[FleetRound]:
+        """One round as one packet, ``[]`` when the fleet has ended
+        (preempted, stopped, or every worker gone). The span is the learner's
+        wait for the round; the slices themselves are in the workers' own
+        streams."""
+        with telem_span(self.telem, "Time/env_interaction_time") as wait:
+            rnd = self.take_round(self.acked_steps)
+            if rnd is not None:
+                wait.count(env_steps=rnd.env_steps)
+        return [] if rnd is None else [rnd]
+
+    def published(self, snapshot: Any = None) -> None:
+        """Once per learner iteration; ``snapshot`` is the mirror's params
+        when a burst refreshed them (None otherwise: nothing new to send)."""
+        if snapshot is not None:
+            self.publish(snapshot)
+
+    @property
+    def burst(self) -> int:
+        """Rounds taken so far: the `burst` of the learner's `Time/train_time`."""
+        return self.rounds
 
     def mark_applied(self, rnd: FleetRound, t_start: Optional[float] = None) -> None:
         """Emit the learner-side apply spans for a round merged OUTSIDE the
@@ -594,7 +647,7 @@ class FleetEngine:
         return rec
 
     # -- shutdown ----------------------------------------------------------
-    def shutdown(self, absorb: Optional[Callable[[FleetRound], int]] = None) -> int:
+    def shutdown(self, absorb: Optional[Callable[[FleetRound], Any]] = None) -> int:
         """Stop the fleet and drain every COMPLETE remaining round through
         ``absorb`` so the final checkpoint sees a consistent buffer (the
         step counter matches the content exactly; an incomplete trailing
@@ -618,8 +671,8 @@ class FleetEngine:
             while all(self._pending[w] for w in active):
                 packets = [self._pending[w].popleft() for w in active]
                 env_steps = sum(p.env_steps for p in packets)
-                rnd = FleetRound(packets, list(active), env_steps)
-                drained += int(absorb(rnd) or 0)
+                absorb(FleetRound(packets, list(active), env_steps, self._merge))
+                drained += env_steps
                 self.acked_steps += env_steps
                 self.rounds += 1
         # trailing PARTIAL rounds can't be applied (the round contract needs

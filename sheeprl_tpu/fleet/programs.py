@@ -2,7 +2,7 @@
 
 A *program* is what a fleet worker process runs between packets: it owns
 the worker's env slice and its host-CPU policy, and replays the exact
-env-interaction logic of the algorithm's serial ``interact()`` closure —
+env-interaction logic of the algorithm's in-process ``interact()`` closure —
 restricted to ``envs_per_worker`` columns — into the packet's
 ``RecordingSink``. All heavy imports happen lazily inside the builder
 functions: this module is imported BY PATH inside the worker process (the
@@ -10,7 +10,7 @@ spawn args stay picklable strings), and must stay light for the learner
 process which imports it only for the numpy-only merge helpers.
 
 Seeding contract: worker ``w`` builds env columns ``[w·epw, (w+1)·epw)``
-with the *same per-env seeds* the serial loop's ``vectorize`` would give
+with the *same per-env seeds* the in-process ``vectorize`` would give
 those columns, so the env streams are identical modulo action divergence.
 
 Programs expose:
@@ -106,7 +106,7 @@ def _slice_cfg(cfg: Any, epw: int) -> Any:
 
 
 def _slice_seed(cfg: Any, worker_id: int, epw: int) -> int:
-    # serial vectorize seeds env i with `seed + rank*num_envs + i`; the fleet
+    # the in-process vectorize seeds env i with `seed + rank*num_envs + i`; the fleet
     # is rank-0/single-controller, so column w*epw+j gets seed + w*epw + j
     return int(cfg.seed) + worker_id * epw
 

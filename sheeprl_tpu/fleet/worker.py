@@ -10,7 +10,7 @@ accelerator — the Podracer parameter-server actor layout.
 A worker owns:
 
 * its **env slice**: ``num_envs / num_workers`` envs, seeded exactly like
-  the same columns of the serial loop's vector env;
+  the same columns of the in-process vector env;
 * its **program**: the per-algorithm acting logic
   (:mod:`sheeprl_tpu.fleet.programs`), resolved by import path in the
   child so the spawn args stay picklable;

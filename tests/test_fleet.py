@@ -13,6 +13,8 @@ The invariants, each proved with a deterministic injected fault:
   incidents as ranked findings;
 * a repeated crasher exhausts the fail budget and is QUARANTINED; the fleet
   degrades gracefully (training completes on the survivors);
+* a degraded round (one worker quarantined) is a smaller PACKET: the learner's
+  one loop stages the next burst by the round's own `env_steps`;
 * a torn packet is detected learner-side and routed through the worker
   fault path;
 * SIGTERM mid-run drains live workers into a consistent, resumable final
@@ -324,6 +326,58 @@ def test_repeated_crasher_is_quarantined_and_fleet_degrades():
     findings = run_detectors(tl)
     assert findings and findings[0].code == "quarantine"
     assert findings[0].severity == "critical"
+
+
+def test_degraded_round_stages_the_next_burst_by_its_own_env_steps(monkeypatch):
+    """After worker 0 is quarantined a round carries 1 env step, not
+    `num_envs` = 2. The learner's loop has no fleet branch: it asks the packet
+    how many steps came, so the burst it stages for the next iteration is the
+    one that iteration takes (peeking `policy_step + num_envs` would stage
+    bursts of 2 that every `take(1)` throws away for a synchronous sample)."""
+    from sheeprl_tpu.algos.sac import sac
+    from sheeprl_tpu.cli import run
+
+    calls = []
+
+    def recording(make):
+        def make_uniform_prefetcher(*args, **kwargs):
+            prefetch = make(*args, **kwargs)
+            stage, take = prefetch.stage, prefetch.take
+            prefetch.stage = lambda g: (calls.append(("stage", g)), stage(g))[1]
+            prefetch.take = lambda g: (calls.append(("take", g)), take(g))[1]
+            return prefetch
+
+        return make_uniform_prefetcher
+
+    monkeypatch.setattr(sac, "make_uniform_prefetcher", recording(sac.make_uniform_prefetcher))
+    run(
+        _sac_args(
+            "fleet_degraded_stage",
+            total=64,
+            extra=[
+                "algo.fleet.workers=2",
+                "fleet.max_fails=1",
+                "resilience.chaos.enabled=True",
+                "resilience.chaos.crash_at_step=10",
+                "resilience.chaos.crash_workers=[0]",
+                "resilience.chaos.crash_repeat=True",
+            ],
+        )
+    )
+    st, base = _final_ckpt("fleet_degraded_stage")
+    assert st["policy_step"] == 64
+    _, fleet_evs = _fleet_events(base)
+    assert "quarantine" in [e["action"] for e in fleet_evs]
+
+    takes = [i for i, (kind, _) in enumerate(calls) if kind == "take"]
+    degraded = [i for i in takes if calls[i][1] == 1]
+    assert len(degraded) >= 8, calls  # rounds of one env step did train
+    # every burst after the first degraded one was staged as taken: the stage
+    # call right before it (the previous iteration's last) names the same g
+    for i in degraded[1:]:
+        assert calls[i - 1] == ("stage", 1), calls[max(0, i - 3) : i + 1]
+    # and full-strength rounds before the quarantine were staged as 2
+    assert ("stage", 2) in calls and ("take", 2) in calls
 
 
 # ---------------------------------------------------------------------------

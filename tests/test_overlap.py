@@ -3,9 +3,11 @@
 * the SPSC ring is FIFO, bounded, and safe across a producer/consumer pair;
 * the staleness gate really blocks the player once more than
   `staleness_bound` bursts are unpublished;
-* replay-ratio accounting is EXACT: a 512-step SAC run drives the same
-  env-step:grad-step ledger overlapped as serial (same cumulative grad
-  steps, same Ratio state);
+* the inline source (`enabled=False`) runs `play` on the caller's thread,
+  one packet a `take()`, with no thread and no `overlap` event;
+* replay-ratio accounting is EXACT: SAC, DreamerV3 and PPO runs end with the
+  same step counters, gradient steps, Ratio state and buffer position with
+  the player thread as with the inline source;
 * a 512-step DreamerV3 run emits `overlap` telemetry (player-stall fraction
   reported), player env-interaction spans land in the same log intervals as
   learner train spans, observed staleness stays within the bound, and the
@@ -143,7 +145,44 @@ def test_engine_take_drains_fifo_and_shutdown_drains_rest():
 
 
 # ---------------------------------------------------------------------------
-# e2e: exact replay-ratio ledger (overlap vs serial), 512 SAC steps
+# unit: the inline source
+# ---------------------------------------------------------------------------
+def test_inline_source_plays_on_the_callers_thread_one_packet_a_take():
+    events, played_on = [], []
+
+    class Telem:
+        def emit(self, rec):
+            events.append(rec)
+
+    def play():
+        played_on.append(threading.current_thread())
+        assert not [t for t in threading.enumerate() if t.name == "overlap-player"]
+        return Packet(None, 3)
+
+    eng = OverlapEngine(enabled=False, queue_depth=4, total_steps=100, initial_step=6, telem=Telem(), stats_every_s=0.0)
+    assert eng.run_ahead == 0  # nothing is produced beyond the packet the learner holds
+    assert eng.start(play) is eng and eng._thread is None and played_on == []  # start only keeps `play`
+    for n in (1, 2):
+        pkts = eng.take(max_packets=1)
+        assert [p.env_steps for p in pkts] == [3] and len(played_on) == n  # ONE play a take
+        assert eng.burst == n and pkts[0].version == n - 1  # the claim counter of the threaded source
+        assert eng.published() is None
+    assert played_on == [threading.current_thread()] * 2
+    assert eng.acked_steps == 12 and eng.queue_len == 0
+    drained = []
+    assert eng.shutdown(drained.append) == 0 and drained == []  # nothing is ever queued
+    assert events == []  # no `overlap` event, no trace record
+
+    def broken():
+        raise ValueError("env died")
+
+    with pytest.raises(ValueError, match="env died"):  # on the caller's own stack, unwrapped
+        OverlapEngine(enabled=False).start(broken).take()
+    assert OverlapEngine(enabled=False).start(lambda: None).take() == []  # `play` may end the run
+
+
+# ---------------------------------------------------------------------------
+# e2e: exact replay-ratio ledger (player thread vs inline), three algorithms
 # ---------------------------------------------------------------------------
 def _sac_args(run_name, overlap, total=512):
     return [
@@ -172,10 +211,72 @@ def _sac_args(run_name, overlap, total=512):
     ]
 
 
-def _final_ckpt(run_name):
+def _dv3_args(run_name, total=512, extra=()):
+    return [
+        "exp=dreamer_v3",
+        "env=dummy",
+        "env.id=discrete_dummy",
+        "env.num_envs=2",
+        "env.sync_env=True",
+        "env.capture_video=False",
+        "algo=dreamer_v3_XS",
+        f"algo.total_steps={total}",
+        "algo.learning_starts=64",
+        "algo.replay_ratio=0.25",
+        "algo.per_rank_batch_size=2",
+        "algo.per_rank_sequence_length=2",
+        "algo.horizon=4",
+        "algo.dense_units=16",
+        "algo.world_model.encoder.cnn_channels_multiplier=2",
+        "algo.world_model.recurrent_model.recurrent_state_size=16",
+        "algo.world_model.transition_model.hidden_size=16",
+        "algo.world_model.representation_model.hidden_size=16",
+        "algo.world_model.discrete_size=4",
+        "algo.world_model.stochastic_size=4",
+        "algo.cnn_keys.encoder=[rgb]",
+        "algo.mlp_keys.encoder=[]",
+        "algo.run_test=False",
+        "buffer.size=512",
+        "buffer.memmap=False",
+        "metric.log_level=1",
+        "model_manager.disabled=True",
+        f"run_name={run_name}",
+    ] + list(extra)
+
+
+def _ppo_args(run_name, total=256):
+    return [
+        "exp=ppo",
+        "env=dummy",
+        "env.id=discrete_dummy",
+        "env.num_envs=2",
+        "env.sync_env=True",
+        "env.capture_video=False",
+        "buffer.memmap=False",
+        "metric.log_level=1",
+        f"algo.total_steps={total}",
+        "algo.rollout_steps=16",
+        "algo.update_epochs=1",
+        "algo.per_rank_batch_size=8",
+        "algo.encoder.cnn_features_dim=16",
+        "algo.encoder.mlp_features_dim=16",
+        "algo.encoder.dense_units=8",
+        "algo.dense_units=8",
+        "algo.mlp_layers=1",
+        "algo.cnn_keys.encoder=[rgb]",
+        "algo.mlp_keys.encoder=[state]",
+        "algo.run_test=False",
+        "checkpoint.every=0",
+        "checkpoint.save_last=True",
+        "model_manager.disabled=True",
+        f"run_name={run_name}",
+    ]
+
+
+def _final_ckpt(run_name, algo="sac", env_id="continuous_dummy"):
     from sheeprl_tpu.utils.checkpoint import CheckpointManager
 
-    base = Path("logs/runs/sac/continuous_dummy") / run_name
+    base = Path("logs/runs") / algo / env_id / run_name
     cks = sorted(
         (base / "version_0" / "checkpoint").glob("ckpt_*.ckpt"),
         key=lambda p: int(p.stem.split("_")[1]),
@@ -184,28 +285,78 @@ def _final_ckpt(run_name):
     return CheckpointManager.load(cks[-1]), base
 
 
-def test_sac_overlap_replay_ratio_ledger_matches_serial():
-    """The env-step:grad-step budget must be IDENTICAL to the serial loop
-    over 512 steps: same cumulative grad steps, same Ratio controller state,
-    same buffer fill — the overlap engine only changes *when* work runs."""
+def _optimizer_steps(state):
+    """The gradient steps a checkpoint's optimizers have taken: every optax
+    `count` leaf of the state, so a run that trained more or less shows."""
+    import jax
+
+    opt = state.get("opt_states", state.get("opt_state"))
+    counts = [
+        int(np.asarray(leaf))
+        for path, leaf in jax.tree_util.tree_leaves_with_path(opt)
+        if "count" in jax.tree_util.keystr(path)
+    ]
+    assert counts, "no optax count in the checkpoint's optimizer state"
+    return counts
+
+
+def _buffer_positions(rb_state):
+    buffers = rb_state["buffers"] if "buffers" in rb_state else [rb_state]
+    return [(b["pos"], b["full"]) for b in buffers]
+
+
+_LEDGER_RUNS = {
+    # algo: (args of one run, env id, final policy_step)
+    "sac": (lambda name, overlap: _sac_args(name, overlap), "continuous_dummy", 512),
+    "dreamer_v3": (
+        lambda name, overlap: _dv3_args(
+            name,
+            total=256,
+            extra=[f"algo.overlap.enabled={overlap}", "buffer.checkpoint=True", "checkpoint.every=0", "checkpoint.save_last=True"],
+        ),
+        "discrete_dummy",
+        256,
+    ),
+    "ppo": (lambda name, overlap: _ppo_args(name) + [f"algo.overlap.enabled={overlap}"], "discrete_dummy", 256),
+}
+
+
+@pytest.mark.parametrize("algo", sorted(_LEDGER_RUNS))
+def test_overlap_replay_ratio_ledger_matches_inline(algo):
+    """The env-step:grad-step budget must be IDENTICAL between the two
+    in-process sources: same final step, same gradient steps, same Ratio
+    controller state, same buffer fill — the player thread only changes
+    *when* work runs. The inline run (`algo.overlap.enabled=False`: `play` on
+    the learner's thread) starts no player thread and emits no `overlap`
+    event."""
     from sheeprl_tpu.cli import run
 
-    run(_sac_args("overlap_ledger_on", True))
-    on, base_on = _final_ckpt("overlap_ledger_on")
-    run(_sac_args("overlap_ledger_off", False))
-    off, _ = _final_ckpt("overlap_ledger_off")
+    args, env_id, final_step = _LEDGER_RUNS[algo]
+    run(args(f"ledger_{algo}_on", True))
+    on, base_on = _final_ckpt(f"ledger_{algo}_on", algo, env_id)
+    run(args(f"ledger_{algo}_off", False))
+    off, base_off = _final_ckpt(f"ledger_{algo}_off", algo, env_id)
 
-    assert on["policy_step"] == off["policy_step"] == 512
-    assert on["cumulative_grad_steps"] == off["cumulative_grad_steps"] > 0
-    assert on["ratio"] == off["ratio"]
-    assert on["rb"]["pos"] == off["rb"]["pos"] and on["rb"]["full"] == off["rb"]["full"]
+    assert on["policy_step"] == off["policy_step"] == final_step
+    assert _optimizer_steps(on) == _optimizer_steps(off) and min(_optimizer_steps(on)) > 0
+    for key in ("cumulative_grad_steps", "ratio", "update"):  # what each algorithm keeps of its ledger
+        assert (key in on) == (key in off)
+        if key in on:
+            assert on[key] == off[key], key
+    assert ("rb" in on) == ("rb" in off) == (algo != "ppo")  # PPO keeps no buffer across updates
+    if "rb" in on:
+        assert _buffer_positions(on["rb"]) == _buffer_positions(off["rb"])
 
-    # the overlapped run's telemetry carries the engine's interval events
-    events = [json.loads(ln) for ln in open(base_on / "version_0" / "telemetry.jsonl")]
-    overlap_events = [e for e in events if e["event"] == "overlap"]
-    assert overlap_events, "no overlap events in the JSONL stream"
-    assert all(e["staleness_max"] <= 1 for e in overlap_events)  # bounded staleness
-    assert all("player_stall_frac" in e for e in overlap_events)
+    # the threaded run's telemetry carries the engine's interval events, the
+    # inline run's none: no thread, no ring, no gate to report on
+    def overlap_events(base):
+        return [e for e in map(json.loads, open(base / "version_0" / "telemetry.jsonl")) if e["event"] == "overlap"]
+
+    threaded = overlap_events(base_on)
+    assert threaded, "no overlap events in the JSONL stream"
+    assert all(e["staleness_max"] <= 1 for e in threaded)  # bounded staleness
+    assert all("player_stall_frac" in e for e in threaded)
+    assert overlap_events(base_off) == []
 
 
 # ---------------------------------------------------------------------------
@@ -216,39 +367,10 @@ def test_dreamer_v3_overlap_512_steps_telemetry_and_no_retraces():
     from sheeprl_tpu.telemetry.schema import validate_jsonl
 
     run(
-        [
-            "exp=dreamer_v3",
-            "env=dummy",
-            "env.id=discrete_dummy",
-            "env.num_envs=2",
-            "env.sync_env=True",
-            "env.capture_video=False",
-            "algo=dreamer_v3_XS",
-            "algo.total_steps=512",
-            "algo.learning_starts=64",
-            "algo.replay_ratio=0.25",
-            "algo.per_rank_batch_size=2",
-            "algo.per_rank_sequence_length=2",
-            "algo.horizon=4",
-            "algo.dense_units=16",
-            "algo.world_model.encoder.cnn_channels_multiplier=2",
-            "algo.world_model.recurrent_model.recurrent_state_size=16",
-            "algo.world_model.transition_model.hidden_size=16",
-            "algo.world_model.representation_model.hidden_size=16",
-            "algo.world_model.discrete_size=4",
-            "algo.world_model.stochastic_size=4",
-            "algo.cnn_keys.encoder=[rgb]",
-            "algo.mlp_keys.encoder=[]",
-            "algo.run_test=False",
-            "algo.overlap.stats_every_s=0.5",
-            "buffer.size=512",
-            "buffer.memmap=False",
-            "metric.log_level=1",
-            "metric.log_every=128",
-            "checkpoint.save_last=False",
-            "model_manager.disabled=True",
-            "run_name=overlap_dv3",
-        ]
+        _dv3_args(
+            "overlap_dv3",
+            extra=["algo.overlap.stats_every_s=0.5", "metric.log_every=128", "checkpoint.save_last=False"],
+        )
     )
     stream = Path("logs/runs/dreamer_v3/discrete_dummy/overlap_dv3/version_0/telemetry.jsonl")
     assert validate_jsonl(stream) == []
