@@ -37,6 +37,7 @@ from ...distributions import (
 from ...config.instantiate import locate
 from ...models import MLP, LayerNorm, LayerNormGRUCell
 from ...ops import symlog
+from ...ops.wgrad_hoist import HoistableDense
 from ...ops.conv_einsum import (
     EinsumConvTranspose4x4S2,
     conv4x4s2,
@@ -295,7 +296,7 @@ class RecurrentModel(nn.Module):
     dense_units: int
 
     def setup(self) -> None:
-        self.mlp = nn.Dense(self.dense_units, use_bias=False, kernel_init=xavier_normal)
+        self.mlp = HoistableDense(self.dense_units, use_bias=False, kernel_init=xavier_normal)
         self.LayerNorm_0 = LayerNorm(eps=1e-3)
         self.gru = LayerNormGRUCell(self.recurrent_state_size, use_bias=False)
 
@@ -316,11 +317,14 @@ class _StochHead(nn.Module):
 
     @nn.compact
     def __call__(self, x: jax.Array) -> jax.Array:
-        x = nn.Dense(self.hidden_size, use_bias=not self.layer_norm, kernel_init=xavier_normal)(x)
+        # named as `nn.Dense` names itself, so that the tree keeps its paths
+        x = HoistableDense(
+            self.hidden_size, use_bias=not self.layer_norm, kernel_init=xavier_normal, name="Dense_0"
+        )(x)
         if self.layer_norm:
             x = LayerNorm(eps=1e-3)(x)
         x = nn.silu(x)
-        return nn.Dense(self.stoch_logits, kernel_init=uniform_init(1.0), name="logits")(x)
+        return HoistableDense(self.stoch_logits, kernel_init=uniform_init(1.0), name="logits")(x)
 
 
 class RSSM(nn.Module):

@@ -42,6 +42,7 @@ from ...distributions import (
 )
 from ...ops import lambda_values as lambda_values_op
 from ...ops import pallas_gru as pg
+from ...ops import wgrad_hoist
 from ...ops.transforms import unrolled_cumprod
 from ...optim import clipped
 from ...parallel import Distributed
@@ -101,8 +102,11 @@ def make_train_fn(
     is_continuous: bool,
     actions_dim: Sequence[int],
     state_shardings: Any = None,
+    emit: Any = None,
 ):
-    """The jitted train function. ``state_shardings`` is the sharding tree
+    """The jitted train function. ``emit`` (``telem.emit``) takes the run's
+    ``wgrad_hoist`` event when the function is traced. ``state_shardings``
+    is the sharding tree
     of ``(params, opt_states, moments)`` as the loop placed them: the step
     then hands the state back placed the same way. Left to itself the
     compiler picks output shardings of its own on a multi-axis mesh (at S
@@ -155,6 +159,32 @@ def make_train_fn(
     target_freq = int(cfg.algo.critic.per_rank_target_network_update_freq)
     moments_cfg = cfg.algo.actor.moments
 
+    # The two sequence scans of the world model, as steps of
+    # `ops.wgrad_hoist.scan`: the gradient of every Dense kernel the step
+    # applies is one [T*B]-row matmul after the backward scan, which then
+    # carries (h, z) and vectors but no array of a kernel's shape.
+    def dyn_step(wm_params, perturbations, carry, e, held):
+        h, z = carry
+        a, first, k = held
+        (h, z, post_logits, prior_logits), taped = wm_apply(
+            wm_params, WorldModel.dynamic, z, h, a, e, first, k, tape=True, perturbations=perturbations
+        )
+        return (h, z), (h, z, post_logits, prior_logits), taped
+
+    def dyn_step_dec(wm_params, perturbations, h, z_in, held):
+        a, first = held
+        (h, prior_logits), taped = wm_apply(
+            wm_params, WorldModel.dynamic_decoupled, z_in, h, a, first, tape=True, perturbations=perturbations
+        )
+        return h, (h, prior_logits), taped
+
+    reported = []
+
+    def report_hoist(scan, hoisted):
+        # while tracing: once for this train function, however often it traces
+        if emit is not None and not reported:
+            reported.append(scan)
+            emit({"event": "wgrad_hoist", "scan": scan, **hoisted})
 
     def one_step(params, opt_states, moments: MomentsState, batch, key):
         T, B = batch["rewards"].shape[:2]
@@ -211,33 +241,22 @@ def make_train_fn(
                         )
                         prior_logits = wm_apply(wm_params, WorldModel.transition_logits, hs)
                     else:
-
-                        def dyn_step_dec(h, xs):
-                            z_in, a, first = xs
-                            h, prior_logits = wm_apply(
-                                wm_params, WorldModel.dynamic_decoupled, z_in, h, a, first
-                            )
-                            return h, (h, prior_logits)
-
-                        h0 = jnp.zeros((B, R))
-                        _, (hs, prior_logits) = jax.lax.scan(
-                            dyn_step_dec, h0, (z_prev, batch_actions, is_first)
+                        _, (hs, prior_logits) = wgrad_hoist.scan(
+                            dyn_step_dec,
+                            {"rssm": wm_params["rssm"]},
+                            jnp.zeros((B, R)),
+                            z_prev,
+                            (batch_actions, is_first),
+                            report=partial(report_hoist, "decoupled"),
                         )
                 else:
-
-                    def dyn_step(carry, xs):
-                        h, z = carry
-                        a, e, first, k = xs
-                        h, z, post_logits, prior_logits = wm_apply(
-                            wm_params, WorldModel.dynamic, z, h, a, e, first, k
-                        )
-                        return (h, z), (h, z, post_logits, prior_logits)
-
-                    keys = jax.random.split(k_dyn, T)
-                    h0 = jnp.zeros((B, R))
-                    z0 = jnp.zeros((B, stoch_flat))
-                    _, (hs, zs, post_logits, prior_logits) = jax.lax.scan(
-                        dyn_step, (h0, z0), (batch_actions, embedded, is_first, keys)
+                    _, (hs, zs, post_logits, prior_logits) = wgrad_hoist.scan(
+                        dyn_step,
+                        {"rssm": wm_params["rssm"]},
+                        (jnp.zeros((B, R)), jnp.zeros((B, stoch_flat))),
+                        embedded,
+                        (batch_actions, is_first, jax.random.split(k_dyn, T)),
+                        report=partial(report_hoist, "coupled"),
                     )
             latents = jnp.concatenate([zs, hs], axis=-1)
             with jax.named_scope("wm_decoder"):
@@ -613,10 +632,6 @@ def main(dist: Distributed, cfg: Config) -> None:
     if state and cfg.buffer.checkpoint and "rb" in state:
         rb.load_state_dict(state["rb"])
 
-    train = make_train_fn(
-        wm, actor, critic, txs, cfg, is_continuous, actions_dim,
-        state_shardings=jax.tree.map(lambda x: x.sharding, (params, opt_states, moments)),
-    )
     player_init, player_step_fn = make_player(wm, actor, cfg, actions_dim, is_continuous, num_envs)
     # Actor/learner split (parallel/placement.py): per-step inference runs on
     # the player device, which `auto` picks by the bytes the player reads; the
@@ -629,6 +644,11 @@ def main(dist: Distributed, cfg: Config) -> None:
     telem = Telemetry.setup(cfg, log_dir, rank, logger=logger, aggregator_keys=AGGREGATOR_KEYS)
     aggregator = telem.aggregator
     telem.emit(mirror.placement)
+    train = make_train_fn(
+        wm, actor, critic, txs, cfg, is_continuous, actions_dim,
+        state_shardings=jax.tree.map(lambda x: x.sharding, (params, opt_states, moments)),
+        emit=telem.emit,
+    )
     # the mesh layout is a telemetry artifact: every inferred spec (and the
     # per-chip bytes accounting) lands in the JSONL stream as `sharding`
     # events — doctor's replicated_giant reads them

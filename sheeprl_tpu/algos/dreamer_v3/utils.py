@@ -118,6 +118,7 @@ def make_precision_applies(cfg: Any, wm, actor, critic):
     (wm_apply, actor_apply, critic_apply, cast, compute_dtype, mixed)."""
     import jax.numpy as jnp
 
+    from ...ops import wgrad_hoist
     from ...parallel.mesh import cast_floating, get_precision
 
     compute_dtype = get_precision(str(cfg.select("fabric.precision", "32-true"))).compute_dtype
@@ -126,9 +127,18 @@ def make_precision_applies(cfg: Any, wm, actor, critic):
     def cast(tree, dtype):
         return cast_floating(tree, dtype) if mixed else tree
 
-    def wm_apply(p, method, *args):
-        out = wm.apply({"params": cast(p, compute_dtype)}, *cast(args, compute_dtype), method=method)
-        return cast(out, jnp.float32)
+    def wm_apply(p, method, *args, tape=False, perturbations=None):
+        """``tape=True`` is a step inside `ops.wgrad_hoist.scan`: it hands that
+        step's ``perturbations`` (None when the scan probes) to the method's
+        `HoistableDense` calls and also returns what they taped."""
+        variables = {"params": cast(p, compute_dtype)}
+        args = cast(args, compute_dtype)
+        if not tape:
+            return cast(wm.apply(variables, *args, method=method), jnp.float32)
+        if perturbations is not None:
+            variables[wgrad_hoist.PERTURB] = perturbations
+        out, taped = wm.apply(variables, *args, method=method, mutable=[wgrad_hoist.TAPE])
+        return cast(out, jnp.float32), taped[wgrad_hoist.TAPE]
 
     def actor_apply(p, x):
         return cast(actor.apply({"params": cast(p, compute_dtype)}, cast(x, compute_dtype)), jnp.float32)

@@ -22,6 +22,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..ops.wgrad_hoist import HoistableDense
+
 Dtype = Any
 
 _ACTIVATIONS: Dict[str, Callable] = {
@@ -226,7 +228,7 @@ class LayerNormGRUCell(nn.Module):
     @nn.compact
     def __call__(self, h: jax.Array, x: jax.Array) -> Tuple[jax.Array, jax.Array]:
         inp = jnp.concatenate([x, h], axis=-1)
-        y = nn.Dense(3 * self.hidden_size, use_bias=self.use_bias, dtype=self.dtype, name="fused")(inp)
+        y = HoistableDense(3 * self.hidden_size, use_bias=self.use_bias, dtype=self.dtype, name="fused")(inp)
         if self.layer_norm:
             y = LayerNorm(eps=1e-3)(y)
         reset, cand, update = jnp.split(y, 3, axis=-1)

@@ -452,6 +452,18 @@ EVENT_SCHEMAS: Dict[str, Dict[str, Tuple[bool, type]]] = {
         "contiguous_bytes": (True, _NUM),
         "contiguous_bytes_share": (True, _NUM),
     },
+    # what the world model's sequence scan takes out of its backward loop,
+    # once when the train function is traced (ops/wgrad_hoist.py `scan`,
+    # dreamer_v3.py `make_train_fn`): which scan, how many Dense kernels'
+    # gradients are one matmul after the backward scan, their float32 bytes
+    # (what that scan no longer carries and rewrites at every step) and the
+    # rows (T * B) of each contraction
+    "wgrad_hoist": {
+        "scan": (True, _STR),  # coupled | decoupled
+        "kernels": (True, _NUM),
+        "kernel_bytes": (True, _NUM),
+        "rows": (True, _NUM),
+    },
     # deterministic fault injection (resilience/chaos.py): faults the
     # SUPERVISOR injects (worker-side faults surface as `fleet` incidents —
     # a chaos crash is indistinguishable from a real one by design)

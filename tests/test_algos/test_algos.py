@@ -162,6 +162,31 @@ def test_dreamer_v3_device_ring_emits_its_layout_once(standard_args):
     assert order.index("placement") < order.index("ring_layout")
 
 
+@pytest.mark.parametrize("rssm, kernels", [("coupled", 6), ("decoupled", 4)])
+def test_dreamer_v3_emits_what_its_sequence_scan_hoists_once(standard_args, rssm, kernels):
+    """The run's `wgrad_hoist` event: one, when the train function is traced,
+    valid against the schema, with the kernels the world model's scan applies
+    (GRU, pre-GRU MLP, both layers of the transition head and, coupled, of the
+    representation head) and the T*B rows of their contractions."""
+    import glob
+    import json
+
+    from sheeprl_tpu.telemetry import validate_jsonl
+
+    args = [a for a in DV3_RING_ARGS if not a.startswith("buffer.device_cache")]
+    run(args + standard_args + [f"algo.world_model.decoupled_rssm={rssm == 'decoupled'}", "metric.log_level=1", "metric.log_every=1000"])
+    streams = glob.glob("logs/runs/**/telemetry.jsonl", recursive=True)
+    assert len(streams) == 1, streams
+    assert validate_jsonl(streams[0]) == []
+    events = [json.loads(line) for line in open(streams[0])]
+    hoists = [e for e in events if e["event"] == "wgrad_hoist"]
+    assert len(hoists) == 1, [e["event"] for e in events]
+    (ev,) = hoists
+    assert ev["scan"] == rssm and ev["kernels"] == kernels and ev["rows"] == 2 * 2
+    gru = 4 * (16 + 16) * 3 * 16  # [dense_units + recurrent_state_size, 3 x recurrent_state_size] float32
+    assert ev["kernel_bytes"] > gru
+
+
 @pytest.mark.parametrize("g", [1, 3])
 def test_dreamer_v3_burst_keys_are_the_eager_splits(g):
     """One dispatch in place of three on the learner's chain to the train

@@ -54,6 +54,16 @@ def test_the_scan_of_a_part_stays_under_it_and_imagination_is_innermost_inside_a
     assert imagined and all(re.search(r"actor\)*/imagination/", n) for n in imagined)
 
 
+@pytest.mark.parametrize("rssm", sorted(RSSM))
+def test_the_kernels_gradients_are_contracted_after_the_backward_scan_and_under_wm_rssm(rssm):
+    """`ops.wgrad_hoist.scan`'s matmuls over all T*B rows: outside the backward
+    loop and outside every flax module, so their `op_name` is the transposed
+    scope and the matmul, and the reader books them to the part."""
+    after_the_scan = [n for n in compiled_op_names(rssm) if re.search(r"/transpose\(jvp\(wm_rssm\)\)/dot_general$", n)]
+    assert after_the_scan, "no contraction follows the world model's backward scan"
+    assert all(part_of(n) == "wm_rssm" and "/while/body/" not in n.split("wm_rssm", 1)[1] for n in after_the_scan)
+
+
 def test_part_of_takes_whole_components_and_the_innermost_one():
     assert part_of("jit(train)/while/body/closed_call/transpose(jvp(wm_rssm))/while/body/mul") == "wm_rssm"
     assert part_of("jit(train)/while/body/jvp(actor)/imagination/while/body/WorldModel.imagination/dot_general") == "imagination"
