@@ -5,7 +5,7 @@ from __future__ import annotations
 import importlib
 from types import ModuleType
 
-CONTRACT = ("installed", "program_shapes", "seed_weights", "decide", "step_flops", "kept_bytes", "step_programs",
+CONTRACT = ("installed", "program_shapes", "seed_weights", "decide", "step_flops", "kept_bytes", "step_programs", "step_parts",
             "rehearsal_overrides", "rehearse", "faults", "widths_of", "compared_numbers", "fault_kinds")
 
 

@@ -62,6 +62,8 @@ fault_kinds = {
     "altered_batch": ("replay_wrong_rows",),   # a gathered row altered where it is produced
 }
 step_programs = ("jit_train",)  # the device programs that are the train step
+# the `jax.named_scope` names of `make_train_fn`'s `one_step`, outermost level: the parts `span_reduce` books the step's ops to
+step_parts = ("wm_encoder", "wm_rssm", "wm_decoder", "wm_heads", "imagination", "actor", "critic", "optimizer")
 # the CPU rehearsal only: the same program at widths a CPU compiles in seconds
 rehearsal_overrides = [
     "algo.per_rank_batch_size=4",

@@ -36,6 +36,7 @@ from ..taps import Run, flat_names
 
 CHECK_CALLS = 1  # the reference follows the first update: the only rollout acted with the seeded weights
 step_programs = ("jit_update",)  # the device program that is the train step
+step_parts = ()  # `make_update_fn` has no `jax.named_scope`: nothing to book by part, so the per-part readers read nothing
 # the CPU rehearsal only: widths a CPU compiles in seconds
 rehearsal_overrides = ["algo.dense_units=16", "algo.mlp_layers=2", "algo.encoder.mlp_features_dim=16"]
 # every name `decide` may return: a limits file names none but these

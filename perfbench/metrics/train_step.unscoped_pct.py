@@ -1,5 +1,6 @@
-"""Share of jit(train)'s summed op time in the traced window under none of
-the eight scope names of `make_train_fn` (span_reduce.PARTS)."""
+"""Share of the summed op time of the step's programs in the traced window
+under none of the step's `jax.named_scope` names (the cell's adapter's
+`step_parts`, which the capture carries)."""
 from perfbench import span_reduce
 
 

@@ -13,11 +13,15 @@ COMMON_OVERRIDES = [
 ]
 
 
-def __getattr__(name: str):
-    # `tests/test_train_scopes.py` (outside the benchmark's own directories, so no benchmark PR may edit it) still
-    # imports DreamerV3's rehearsal overrides from here; they live with their adapter
-    if name == "REHEARSAL_OVERRIDES":
-        from .adapters.dreamer_v3 import rehearsal_overrides
+# `tests/test_train_scopes.py` (outside the benchmark's own directories, so no benchmark PR may edit it) still imports
+# DreamerV3's rehearsal overrides from here, and its step's parts through `span_reduce.PARTS` and
+# `span_reduce.part_of(op_name)`; both live with their adapter
+_LAZY = {"REHEARSAL_OVERRIDES": "rehearsal_overrides", "STEP_PARTS": "step_parts"}
 
-        return rehearsal_overrides
+
+def __getattr__(name: str):
+    if name in _LAZY:
+        from .adapters import dreamer_v3
+
+        return getattr(dreamer_v3, _LAZY[name])
     raise AttributeError(f"module {__name__!r} has no attribute {name!r}")

@@ -86,6 +86,12 @@ def metric_reader(name: str):
     return mod.read
 
 
+def reported_by(entries: List[Dict[str, Any]], workload: str) -> List[Dict[str, Any]]:
+    """The metrics of one list of the benchmark file that a cell reports: those that list it under `workloads`, and
+    those that have no such key (reported by every cell). Holds for `end_to_end` as for `per_layer`."""
+    return [m for m in entries if not m.get("workloads") or workload in m["workloads"]]
+
+
 def window_numbers(run, envs: Dict[int, Any]) -> Dict[str, Any]:
     import numpy as np
 
@@ -209,9 +215,7 @@ def main(argv: Optional[List[str]] = None) -> int:
                 "step_gap_p95_ms": float(np.percentile(win["gaps_ms"], 95)) if len(win["gaps_ms"]) else None,
                 "setup_s": run.t_open - T_START,
             }
-            for m in bench["end_to_end"]:
-                if m.get("workloads") and args.workload not in m["workloads"]:
-                    continue
+            for m in reported_by(bench["end_to_end"], args.workload):
                 if e2e.get(m["name"]) is not None:
                     metrics[m["name"]] = {"value": e2e[m["name"]], "unit": m["unit"]}
         else:
@@ -220,7 +224,7 @@ def main(argv: Optional[List[str]] = None) -> int:
             t0 = time.perf_counter()
             planes = trace_reduce.read_dir(trace_dir)  # the one parse: both reducers read it
             reduced = trace_reduce.reduce_events(planes)
-            capture = span_reduce.Capture(planes, adapter.step_programs)
+            capture = span_reduce.Capture(planes, adapter.step_programs, adapter.step_parts)
             log(f"trace reduced in {time.perf_counter() - t0:.1f}s: {reduced['n_device_events']} device events")
             if args.keep and args.keep_trace:
                 os.makedirs(args.keep, exist_ok=True)
@@ -232,9 +236,7 @@ def main(argv: Optional[List[str]] = None) -> int:
                 "shapes": run.shapes, "rehearse": args.rehearse_cpu, "adapter": adapter,
                 "trace_dir": trace_dir, "capture": capture,
             }
-            for m in bench["per_layer"]:
-                if m.get("workloads") and args.workload not in m["workloads"]:
-                    continue
+            for m in reported_by(bench["per_layer"], args.workload):
                 value = metric_reader(m["name"])(ctx)
                 if value is not None:
                     metrics[m["name"]] = {"value": float(value), "unit": units[m["name"]]}

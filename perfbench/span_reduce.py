@@ -6,7 +6,9 @@ or ``Player/`` the line (thread) it lies on and its stats (the counts a
 program span carries: ``grad_steps``, ``burst``, ``version``, ``bytes``);
 and for every `XLA Ops` event that starts inside an execution of the step's
 programs (the adapter's `step_programs`; `jit_train` for DreamerV3) the part
-of the step it belongs to. PERF.md (section 3) says where a v5e
+of the step it belongs to: one of the adapter's `step_parts`, the
+`jax.named_scope` names of that step, which `run.py` hands to `Capture`.
+No algorithm's scopes are listed here. PERF.md (section 3) says where a v5e
 capture keeps the HLO `op_name` (the stat `tf_op` of the event's metadata,
 which `ProfileData` does not hand out: `read_tf_ops` below) and how a fusion
 over two parts is named.
@@ -21,22 +23,36 @@ fewer than the executions in some windows (PERF.md, Findings of PR 31).
 from __future__ import annotations
 
 import re
-from typing import Any, Dict, List, Optional, Sequence, Tuple
+from typing import Any, Collection, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
 from perfbench import trace_reduce as tr
 
-# the `jax.named_scope` names of DreamerV3's `make_train_fn`'s `one_step`; a step without them reads as unscoped
-PARTS = ("wm_encoder", "wm_rssm", "wm_decoder", "wm_heads", "imagination", "actor", "critic", "optimizer")
-_PARTS = frozenset(PARTS)
 _WRAPPED = re.compile(r"[A-Za-z_]+\((.*)\)")  # jvp(..), transpose(..), jit(..)
 
 
-def part_of(op_name: str) -> Optional[str]:
-    """The innermost of PARTS that is a whole `/`-separated component of an
-    HLO `op_name`; autodiff wraps a component (`transpose(jvp(wm_rssm))`), so
-    the wrappers are peeled first. None where no component is a part."""
+def _legacy_parts() -> Tuple[str, ...]:
+    # `tests/test_train_scopes.py` (outside the benchmark's own directories, so no benchmark PR may edit it) still
+    # imports `PARTS` from here and calls `part_of(op_name)` with no parts; `overrides.py` keeps that test's lazy names
+    from perfbench import overrides
+
+    return overrides.STEP_PARTS
+
+
+def __getattr__(name: str):
+    if name == "PARTS":
+        return _legacy_parts()
+    raise AttributeError(f"module {__name__!r} has no attribute {name!r}")
+
+
+def part_of(op_name: str, parts: Optional[Collection[str]] = None) -> Optional[str]:
+    """The innermost of `parts` (an adapter's `step_parts`) that is a whole
+    `/`-separated component of an HLO `op_name`; autodiff wraps a component
+    (`transpose(jvp(<part>))`), so the wrappers are peeled first. None where
+    no component is a part."""
+    if parts is None:
+        parts = _legacy_parts()
     found = None
     for comp in op_name.split("/"):
         while True:
@@ -44,7 +60,7 @@ def part_of(op_name: str) -> Optional[str]:
             if m is None:
                 break
             comp = m.group(1)
-        if comp in _PARTS:
+        if comp in parts:
             found = comp
     return found
 
@@ -135,8 +151,10 @@ def read_tf_ops(path: str, prefer: Tuple[str, ...] = ("jit(train)",)) -> Dict[st
 class Capture:
     """The window, the program's host spans by thread, and the ops of the step's programs by part."""
 
-    def __init__(self, planes: Dict[str, Any], step_programs: Sequence[str] = ("jit_train",)):
+    def __init__(self, planes: Dict[str, Any], step_programs: Sequence[str], step_parts: Sequence[str]):
         self.step_programs = tuple(step_programs)
+        self.step_parts = tuple(step_parts)  # the step's `jax.named_scope` names, as the cell's adapter has them
+        parts = frozenset(self.step_parts)
         self.host = [ev for ev in planes["host"] if ev[0] not in (tr.OPEN_MARK, tr.CLOSE_MARK)]  # name, thread, start, end, stats
         self.w0, self.w1, _ = tr.window_of(planes)
         self.window_s = (self.w1 - self.w0) * 1e-9
@@ -169,7 +187,7 @@ class Capture:
             if short.split(".", 1)[0] in tr.WRAPPERS:
                 continue
             if name not in part_by_name:
-                part_by_name[name] = part_of(tf_ops.get(name, ""))
+                part_by_name[name] = part_of(tf_ops.get(name, ""), parts)
             self.train_ops.append((part_by_name[name], short, (e0 - s0) * 1e-9))
         self.scoped = any(p is not None for p, _, _ in self.train_ops)
         # a program that has the layer-boundary spans shows some in any capture; one of
@@ -273,14 +291,17 @@ def step_ms(ctx: Dict[str, Any]) -> Optional[float]:
 
 def part_ms(ctx: Dict[str, Any], part: str) -> Optional[float]:
     """Device time of the step's ops under that scope, per gradient step;
-    None where the program has no scopes (the parent) or no capture."""
+    None where the step has no such part (another adapter's), where the
+    program has no scopes (the parent), or where there is no capture."""
     cap = ctx.get("capture")
-    if cap is None or not cap.scoped or cap.step_executions <= 0:
+    if cap is None or part not in cap.step_parts or not cap.scoped or cap.step_executions <= 0:
         return None
     return 1e3 * cap.part_seconds().get(part, 0.0) / (cap.step_executions * steps_per_call(ctx))
 
 
 def unscoped_pct(ctx: Dict[str, Any]) -> Optional[float]:
+    """Share of the step's summed op time under none of the capture's own
+    `step_parts`; None for a step that has none, or whose program emits none."""
     cap = ctx.get("capture")
     if cap is None or not cap.scoped:
         return None

@@ -1,6 +1,6 @@
 """Cut a capture down to a fixture for `span_reduce`, keeping what `trim_trace.py` drops:
 
-    python3 perfbench/trim_scopes.py <in.xplane.pb> <out.xplane.pb> <out.json> [seconds] [max_ops]
+    python3 perfbench/trim_scopes.py <adapter> <in.xplane.pb> <out.xplane.pb> <out.json> [seconds] [max_ops]
 
 Keeps, from the first window mark on and for `seconds`: the device planes'
 `XLA Modules` events, every k-th event of their `XLA Ops` line (k so that about
@@ -9,8 +9,9 @@ it is there), each kept op's `tf_op` (the stat of its metadata that holds the
 HLO `op_name`), and the host plane's `Time/`, `Wait/` and `Player/` spans with
 their stats (the counts) and the window marks. Then reads the cut file with
 every per-layer reader that `span_reduce` serves and writes the numbers beside
-it, for the test that reads it again. Needs TensorFlow's xplane protobuf
-bindings, as `trim_trace.py` does.
+it, for the test that reads it again. `<adapter>` names the file under
+`perfbench/adapters/` whose `step_programs` and `step_parts` the capture is
+read by. Needs TensorFlow's xplane protobuf bindings, as `trim_trace.py` does.
 """
 from __future__ import annotations
 
@@ -23,14 +24,15 @@ sys.path.insert(0, ROOT)
 NAME_CHARS, TF_OP_CHARS = 100, 110  # the instruction's own name and the scope components come first in both
 
 
-def readings(path: str, step_programs=("jit_train",)) -> dict:
-    """Every metric of BENCHMARK.json whose file reads through `span_reduce`, on one capture file."""
+def readings(path: str, adapter) -> dict:
+    """Every metric of BENCHMARK.json whose file reads through `span_reduce`, on one capture file of a cell of that adapter."""
     from perfbench import span_reduce, trace_reduce
     from perfbench.run import load_json, metric_reader
 
     planes = trace_reduce.read_planes(path)
     reduced = trace_reduce.reduce_events(planes)
-    cap = span_reduce.Capture(planes, step_programs)
+    step_programs = adapter.step_programs
+    cap = span_reduce.Capture(planes, step_programs, adapter.step_parts)
     executions = sum(reduced["programs"].get(p, {}).get("executions", 0) for p in step_programs)
     # one gradient step per execution in both cells
     ctx = {"window": {"grad_steps": executions, "train_calls": executions}, "capture": cap, "trace": reduced}
@@ -38,7 +40,9 @@ def readings(path: str, step_programs=("jit_train",)) -> dict:
     for m in load_json(ROOT, "BENCHMARK.json")["per_layer"]:
         with open(os.path.join(ROOT, "perfbench", "metrics", m["name"] + ".py")) as f:
             if "span_reduce" in f.read():
-                out[m["name"]] = metric_reader(m["name"])(ctx)
+                value = metric_reader(m["name"])(ctx)
+                if value is not None:  # as `run.py` has it: a reader that finds nothing to read is left out
+                    out[m["name"]] = value
     by_part = cap.part_seconds()
     return {"grad_steps": executions, "whole_executions": cap.step_executions, "window_s": cap.window_s, "metrics": out,
             "part_seconds": {str(k): v for k, v in by_part.items()}}
@@ -47,12 +51,13 @@ def readings(path: str, step_programs=("jit_train",)) -> dict:
 def main(argv) -> int:
     from tensorflow.tsl.profiler.protobuf import xplane_pb2
 
-    from perfbench import span_reduce as sr
+    from perfbench import adapters
     from perfbench import trace_reduce as tr
 
-    src, dst, dst_json = argv[:3]
-    seconds = float(argv[3]) if len(argv) > 3 else 0.45
-    max_ops = int(argv[4]) if len(argv) > 4 else 1500
+    adapter = adapters.load(argv[0])
+    src, dst, dst_json = argv[1:4]
+    seconds = float(argv[4]) if len(argv) > 4 else 0.45
+    max_ops = int(argv[5]) if len(argv) > 5 else 1500
     space = xplane_pb2.XSpace()
     with open(src, "rb") as f:
         space.ParseFromString(f.read())
@@ -120,7 +125,7 @@ def main(argv) -> int:
                             keep_stat(st, md.stats, TF_OP_CHARS)
     with open(dst, "wb") as f:
         f.write(out.SerializeToString())
-    read = readings(dst)
+    read = readings(dst, adapter)
     with open(dst_json, "w") as f:
         json.dump(read, f, indent=1)
     print(f"[trim] {os.path.getsize(src)} -> {os.path.getsize(dst)} bytes, {read['grad_steps']} train executions, "

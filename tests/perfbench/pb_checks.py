@@ -4,6 +4,8 @@ tests over the accepted cells and the fixture's, and by the proof that a cell
 of another adapter is files alone (`test_pb_addition.py`). Nothing here knows
 an algorithm: what only one adapter's cells keep is in that adapter's own
 `test_pb_<adapter>.py`."""
+import os
+
 from pb_helpers import BENCH_FILE, ROOT
 
 GIB = 2**30
@@ -85,3 +87,31 @@ def cells_of(adapter_name, benchmark=BENCH_FILE):
 
     names = [w["name"] for w in load_json(ROOT, benchmark)["workloads"]]
     return [n for n in names if load_cell(n, benchmark)["config"]["adapter"] == adapter_name]
+
+
+def adapter_names():
+    """Every adapter there is: `perfbench/adapters/<name>.py`."""
+    return sorted(n[:-3] for n in os.listdir(os.path.join(ROOT, "perfbench", "adapters")) if n.endswith(".py") and n != "__init__.py")
+
+
+def adapter_of_test_file(name):
+    """The adapter whose own tests a file of `tests/perfbench/` holds: `test_pb_<adapter>.py` or
+    `test_pb_<adapter>_<what>.py`, the longest adapter's name where two fit; None for a test of any cell."""
+    fits = [a for a in adapter_names() if name == f"test_pb_{a}.py" or name.startswith(f"test_pb_{a}_")]
+    return max(fits, key=len) if fits else None
+
+
+def capture_for(cell, planes, benchmark=BENCH_FILE):
+    """The second reduction of a parsed capture as `run.py` makes it for a run of that cell: the step's programs and
+    the step's parts are its adapter's."""
+    from perfbench import span_reduce
+
+    _, adapter = spec_and_adapter(cell, benchmark)
+    return span_reduce.Capture(planes, adapter.step_programs, adapter.step_parts)
+
+
+def spans_are_registered_and_the_owed_occur(seen, owed, schemas):
+    """What a capture of one loop is held to: every span in it is in the program's table, and every span THAT loop
+    owes is in it. Not equality with the table: a span registered for another loop occurs in no run of this one."""
+    assert set(seen) <= set(schemas), sorted(set(seen) - set(schemas))
+    assert set(owed) <= set(seen), sorted(set(owed) - set(seen))

@@ -11,9 +11,9 @@ if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
 
-# the benchmark file every test here reads: the root's, or the one `PB_BENCHMARK` names (a path from the root or an
-# absolute one). `test_pb_addition.py` sets it to run these very tests over a file that has grown by a cell
-BENCH_FILE = os.environ.get("PB_BENCHMARK", "BENCHMARK.json")
+# the benchmark file every test here reads: the root's. (`test_pb_addition.py` runs these very tests over a tree whose
+# root file has grown by a cell: there `ROOT` is that tree)
+BENCH_FILE = "BENCHMARK.json"
 
 
 def bench():

@@ -7,14 +7,10 @@ import re
 import pytest
 
 import pb_checks
+from pb_checks import adapter_names
 from pb_helpers import ANY_CELLS, CELLS, PPO_BENCH, PPO_CELL, ROOT, bench, config_files, mix_files
 
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
-
-
-def adapter_names():
-    """Every adapter there is: `perfbench/adapters/<name>.py`."""
-    return sorted(n[:-3] for n in os.listdir(os.path.join(ROOT, "perfbench", "adapters")) if n.endswith(".py") and n != "__init__.py")
 
 
 def test_benchmark_json_has_exactly_the_contract_keys(benchmark_json):
@@ -37,6 +33,36 @@ def test_every_name_keeps_to_the_contracts_alphabet(benchmark_json):
     assert all(NAME.match(n) for n in names), [n for n in names if not NAME.match(n)]
     assert len({m["name"] for m in benchmark_json["end_to_end"] + benchmark_json["per_layer"]}) == len(
         benchmark_json["end_to_end"] + benchmark_json["per_layer"])
+
+
+def test_every_workloads_list_names_cells_and_names_none_twice(benchmark_json):
+    for m in benchmark_json["end_to_end"] + benchmark_json["per_layer"]:
+        listed = m.get("workloads")
+        assert listed is None or (listed and len(set(listed)) == len(listed) and set(listed) <= set(CELLS)), m["name"]
+    assert "workloads" not in next(m for m in benchmark_json["end_to_end"] if m["name"] == "setup_s")  # every cell reports it
+
+
+@pytest.mark.parametrize("cell", CELLS)
+def test_cell_reports_setup_another_end_to_end_metric_and_per_layer_metrics_that_move_what_it_reports(cell, benchmark_json):
+    """The contract's least for a cell, read as the harness reads it: an end-to-end metric may list its cells too
+    (`step_gap_p95_ms` since PR 37), so a cell whose long gap is of another kind reports the others."""
+    from perfbench.run import reported_by
+
+    e2e = {m["name"] for m in reported_by(benchmark_json["end_to_end"], cell)}
+    assert "setup_s" in e2e and len(e2e) >= 2, e2e
+    mine = reported_by(benchmark_json["per_layer"], cell)
+    assert mine and all(m["moves"] in e2e for m in mine), [m["name"] for m in mine if m["moves"] not in e2e]
+    # the whole step's share of the peak stands beside whatever else the cell reads, moving a metric the cell reports
+    assert any("mfu" in re.split(r"[._]", m["name"]) for m in mine)
+
+
+def test_a_metric_that_lists_its_cells_is_reported_by_those_alone_and_one_that_lists_none_by_all():
+    from perfbench.run import reported_by
+
+    entries = [{"name": "a"}, {"name": "b", "workloads": ["x.1"]}, {"name": "c", "workloads": ["x.1", "y.2"]}]
+    assert [m["name"] for m in reported_by(entries, "x.1")] == ["a", "b", "c"]
+    assert [m["name"] for m in reported_by(entries, "y.2")] == ["a", "c"]
+    assert [m["name"] for m in reported_by(entries, "z.3")] == ["a"]
 
 
 def test_four_chip_cells_stay_within_their_share(benchmark_json):
@@ -91,9 +117,12 @@ def test_every_configuration_names_an_adapter_that_exports_the_whole_contract(pa
     adapter = adapters.load(conf["adapter"])
     assert os.path.isfile(os.path.join(ROOT, "perfbench", "adapters", conf["adapter"] + ".py"))
     assert all(hasattr(adapter, name) for name in adapters.CONTRACT)
-    data = ("step_programs", "rehearsal_overrides", "compared_numbers", "fault_kinds")
+    data = ("step_programs", "step_parts", "rehearsal_overrides", "compared_numbers", "fault_kinds")
     assert all(callable(getattr(adapter, name)) for name in adapters.CONTRACT if name not in data)
     assert adapter.step_programs and all(p.startswith("jit_") for p in adapter.step_programs)
+    # the step's `jax.named_scope` names: a tuple of names, empty for a step that has none
+    assert isinstance(adapter.step_parts, tuple) and len(set(adapter.step_parts)) == len(adapter.step_parts)
+    assert all(NAME.match(p) and "/" not in p for p in adapter.step_parts)
     assert all("=" in o for o in adapter.rehearsal_overrides)
 
 
@@ -147,11 +176,69 @@ def perfbench_sources():
 
 
 # where an adapter's algorithm is named besides its adapter and its reference under `references/`: DreamerV3's reference
-# stays `perfbench/reference.py` (accepted before the seam), and overrides.py keeps one lazy name for
+# stays `perfbench/reference.py` (accepted before the seam), and overrides.py keeps the lazy names for
 # `tests/test_train_scopes.py`, which lies outside the benchmark's directories. A new adapter gets no entry here.
 ELSEWHERE = {"dreamer_v3": {"perfbench/reference.py", "perfbench/overrides.py"}}
 # the names of an algorithm's own modules beside the adapter's name
 ALSO = {"dreamer_v3": "|world_model"}
+
+
+def algorithm_names():
+    """Every name an identifier can belong to: the program's algorithms (a directory listing of `sheeprl_tpu/algos`,
+    no import) and the adapters there are."""
+    algos = os.path.join(ROOT, "sheeprl_tpu", "algos")
+    return sorted({n for n in os.listdir(algos) if os.path.isdir(os.path.join(algos, n)) and not n.startswith("_")} | set(adapter_names()))
+
+
+def names_the_algorithm(text, name, names=None):
+    """Whether a text names that algorithm. An identifier belongs to ONE algorithm: around every match of the name
+    (or of one of its modules' names) the maximal run of letters, digits and `_` is taken, and it is given to the
+    longest of `names` (every algorithm and adapter there is) that begins it: `ppo_recurrent_benchmarks` is `ppo_recurrent`'s and not `ppo`'s,
+    `dreamer_v3_XL_crafter` is still `dreamer_v3`'s. A run that no name begins (`world_model`, `make_ppo_agent`) counts
+    for the name that matched inside it, as every match did before PR 37."""
+    names = algorithm_names() if names is None else names
+    pattern = re.compile(name + ALSO.get(name, ""), re.I)
+    for run in re.findall(r"[A-Za-z0-9_]+", text):  # a name is letters, digits and `_`: a match lies inside one run
+        if pattern.search(run):
+            begun = [n for n in names if run.lower().startswith(n)]
+            if not begun or max(begun, key=len) == name:
+                return True
+    return False
+
+
+@pytest.mark.parametrize("text,name,named", [
+    ("ppo_recurrent", "ppo", False),
+    ('"exp=ppo_recurrent_benchmarks"', "ppo", False),
+    ("perfbench/adapters/ppo_recurrent.py", "ppo", False),
+    ("from .adapters import ppo_recurrent as adapter", "ppo", False),
+    ('"exp=ppo"', "ppo", True),
+    ("algos/ppo/ppo.py", "ppo", True),
+    ("exp=ppo_benchmarks", "ppo", True),
+    ("exp=ppo_recurrent_benchmarks is not exp=ppo", "ppo", True),   # side by side: the second is `ppo`'s
+    ("def make_ppo_agent():", "ppo", True),                        # no algorithm begins the run: the match counts
+    ("exp=ppo_recurrent_benchmarks", "ppo_recurrent", True),
+    ('"exp=dreamer_v3_XL_crafter"', "dreamer_v3", True),
+    ("perfbench/adapters/dreamer_v3.py", "dreamer_v3", True),
+    ("DREAMER_V3_OWES", "dreamer_v3", True),
+    ("the world_model's scan", "dreamer_v3", True),
+    ("exp=p2e_dv3_exploration", "dreamer_v3", False),
+    ("p2e_dv3", "ppo", False),
+    ("exp=dreamer_v2", "dreamer_v3", False),
+    ("sac_ae", "sac", False),
+])
+def test_an_identifier_belongs_to_one_algorithm(text, name, named):
+    """Obstacle 3 of ISSUE 37, as cases. Until then the pattern was the adapter's bare letters, so every file
+    under `perfbench/` that named `ppo_recurrent` failed the `ppo` case below."""
+    names = ["a2c", "dreamer_v1", "dreamer_v2", "dreamer_v3", "droq", "p2e_dv1", "p2e_dv2", "p2e_dv3", "ppo", "ppo_recurrent",
+             "sac", "sac_ae"]
+    assert names_the_algorithm(text, name, names) is named
+    assert bool(re.search(name + ALSO.get(name, ""), text, re.I)) or not named  # the old pattern never saw less
+
+
+def test_the_algorithms_an_identifier_can_belong_to_are_the_programs_and_the_adapters():
+    names = algorithm_names()
+    assert {"ppo", "ppo_recurrent", "dreamer_v3", "p2e_dv3", "sac", "sac_ae"} <= set(names) and set(adapter_names()) <= set(names)
+    assert "__pycache__" not in names and "__init__" not in names and "__init__.py" not in names
 
 
 @pytest.mark.parametrize("name", adapter_names())
@@ -160,12 +247,11 @@ def test_only_its_adapter_and_its_reference_name_an_algorithm(name):
     by its adapter and its reference alone. The data files name their recipe
     (`exp=dreamer_v3_...`) and their adapter; no other code of `perfbench/` does."""
     allowed = {f"perfbench/adapters/{name}.py", f"perfbench/references/{name}.py"} | ELSEWHERE.get(name, set())
-    pattern = re.compile(name + ALSO.get(name, ""), re.I)
-    sources = list(perfbench_sources())
-    code = sorted(p for p, text in sources if p.endswith(".py") and pattern.search(text))
+    sources, names = list(perfbench_sources()), algorithm_names()
+    code = sorted(p for p, text in sources if p.endswith(".py") and names_the_algorithm(text, name, names))
     assert f"perfbench/adapters/{name}.py" in code and set(code) <= allowed, code
     # a data file names the algorithm only where some configuration of the benchmark runs it
-    data = sorted(p for p, text in sources if p.endswith(".json") and pattern.search(text))
+    data = sorted(p for p, text in sources if p.endswith(".json") and names_the_algorithm(text, name, names))
     runs_it = any(json.loads(text).get("adapter") == name for p, text in sources if p.startswith("perfbench/configs/"))
     assert runs_it or not data, data
     for shared in ("run.py", "check.py", "taps.py", "rehearse.py", "calibrate.py", "span_reduce.py", "trace_reduce.py", "envs.py", "overrides.py"):

@@ -11,8 +11,8 @@ CONTRACT_KEYS = ["correct", "attempted", "failed", "metrics", "device"]
 
 
 @pytest.fixture(scope="module")
-def rehearsal():
-    rc, out, err = run_harness("--workload", XL_CELL, "--seed", "3000000019", "--seconds", "1", "--trace", "1", "--rehearse-cpu")
+def rehearsal(xl_rehearsal):
+    rc, out, err, _ = xl_rehearsal
     return rc, out, err
 
 

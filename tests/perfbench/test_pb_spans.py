@@ -1,7 +1,7 @@
 """The program's spans in a capture, end to end on the CPU: one rehearsal of
 the harness whose learner thread is tiled by `Time/` and `Wait/` spans and
 whose counts add up, and one short run of the program itself in which every
-span of the table occurs and the overlap engine's stall seconds are the
+span the DreamerV3 loop owes occurs (and none that the table lacks) and the overlap engine's stall seconds are the
 tracker's."""
 import glob
 import json
@@ -9,25 +9,29 @@ import os
 
 import pytest
 
-from pb_helpers import XL_CELL, run_harness
+from pb_checks import capture_for, spans_are_registered_and_the_owed_occur
+from pb_helpers import XL_CELL
 from perfbench import span_reduce, trace_reduce
 from sheeprl_tpu.telemetry.schema import SPAN_SCHEMAS
 
+# the spans the DreamerV3 loop owes, written out as that loop's own list: the fourteen the table had while it was the
+# only loop instrumented (PR 28). A name registered since for another loop is in the table and in no run of this one
+DREAMER_V3_OWES = ["Time/env_interaction_time", "Time/train_time", "Time/learner_apply", "Time/replay_sync", "Time/replay_sample",
+                   "Time/replay_stage", "Time/param_refresh", "Time/log_flush", "Time/checkpoint", "Wait/learner_queue",
+                   "Wait/player_queue", "Player/act", "Player/env_step", "Player/record"]
 STEADY = ["Time/train_time", "Time/env_interaction_time", "Time/learner_apply", "Time/replay_sync", "Time/replay_sample",
           "Time/replay_stage", "Time/param_refresh", "Player/act", "Player/env_step", "Player/record"]
 
 
 @pytest.fixture(scope="module")
-def rehearsal(tmp_path_factory):
-    keep = str(tmp_path_factory.mktemp("keep"))
-    rc, out, err = run_harness("--workload", XL_CELL, "--seed", "3000000021", "--seconds", "2", "--trace", "1",
-                               "--rehearse-cpu", "--keep", keep, "--keep-trace", "1")
+def rehearsal(xl_rehearsal):
+    rc, _, err, keep = xl_rehearsal
     assert rc == 0, err[-3000:]
     path = glob.glob(os.path.join(keep, "*.xplane.pb"))[0]
     with open(glob.glob(os.path.join(keep, "*_t1.json"))[0]) as f:
         window = json.load(f)["window"]
     planes = trace_reduce.read_planes(path)
-    return span_reduce.Capture(planes), trace_reduce.reduce_events(planes), window
+    return capture_for(XL_CELL, planes), trace_reduce.reduce_events(planes), window
 
 
 def test_the_steady_loops_spans_are_all_in_the_window_under_their_bare_names(rehearsal):
@@ -83,7 +87,7 @@ def test_new_readers_read_the_rehearsals_capture(rehearsal):
     assert span_reduce.part_ms(ctx, "wm_encoder") is None and span_reduce.step_ms(ctx) is None  # the CPU's plane has no `XLA Ops` line
 
 
-def test_every_span_of_the_table_occurs_and_the_engines_stalls_are_the_trackers():
+def test_every_span_the_loop_owes_occurs_and_the_engines_stalls_are_the_trackers():
     import jax
 
     from sheeprl_tpu.cli import run
@@ -106,8 +110,8 @@ def test_every_span_of_the_table_occurs_and_the_engines_stalls_are_the_trackers(
         ])
     finally:
         jax.profiler.stop_trace()
-    cap = span_reduce.Capture(trace_reduce.read_dir("trace"))
-    assert sorted(set(n for n, *_ in cap.host)) == sorted(SPAN_SCHEMAS)
+    cap = capture_for(XL_CELL, trace_reduce.read_dir("trace"))
+    spans_are_registered_and_the_owed_occur({n for n, *_ in cap.host}, DREAMER_V3_OWES, SPAN_SCHEMAS)
     learner = cap.learner_thread()
     player = {th for n, th, *_ in cap.host if n.startswith("Player/")}
     assert len(player) == 1 and learner not in player
@@ -123,3 +127,21 @@ def test_every_span_of_the_table_occurs_and_the_engines_stalls_are_the_trackers(
                         ("player_busy_s", "Time/env_interaction_time")):
         booked, timed = sum(e[field] for e in overlap), sum(s.get(name, 0.0) for s in spans)
         assert booked > 0 and booked == pytest.approx(timed, abs=1e-5 * (len(overlap) + len(spans))), (field, booked, timed)
+
+
+def test_the_loops_own_list_is_the_tables_fourteen_and_every_name_is_still_registered():
+    assert len(set(DREAMER_V3_OWES)) == 14 and set(DREAMER_V3_OWES) <= set(SPAN_SCHEMAS)
+
+
+def test_a_span_registered_for_another_loop_fails_neither_half_and_a_missing_or_unregistered_one_fails():
+    """Obstacle 2 of ISSUE 37, as cases: until then the run's names had to EQUAL the table, so a fifteenth name
+    registered for another loop (PR 36: `Player/prefill`, `Time/cache_stage`) failed every DreamerV3 run."""
+    seen = set(DREAMER_V3_OWES)
+    longer = dict(SPAN_SCHEMAS, **{"Player/prefill": ("tokens",), "Time/cache_stage": ()})
+    assert sorted(seen) != sorted(longer)  # what the parent's line compared
+    spans_are_registered_and_the_owed_occur(seen, DREAMER_V3_OWES, longer)
+    for missing in ("Time/checkpoint", "Wait/player_queue", "Player/record"):
+        with pytest.raises(AssertionError, match=missing):
+            spans_are_registered_and_the_owed_occur(seen - {missing}, DREAMER_V3_OWES, longer)
+    with pytest.raises(AssertionError, match="Player/unheard_of"):
+        spans_are_registered_and_the_owed_occur(seen | {"Player/unheard_of"}, DREAMER_V3_OWES, longer)

@@ -89,12 +89,13 @@ def test_busy_and_idle_are_taken_per_device_plane_and_the_fullest_device_is_the_
 
 
 def test_the_capture_readers_take_the_fullest_plane_of_the_one_parse():
-    from perfbench import span_reduce
+    from pb_checks import capture_for
+    from pb_helpers import XL_CELL
     from perfbench.run import metric_reader
 
     planes, _ = two_planes()
-    cap, reduced = span_reduce.Capture(planes), tr.reduce_events(planes)
-    one = span_reduce.Capture(tr.read_planes(os.path.join(HERE, "fixtures", "chip_v5e.xplane.pb")))
+    cap, reduced = capture_for(XL_CELL, planes), tr.reduce_events(planes)
+    one = capture_for(XL_CELL, tr.read_planes(os.path.join(HERE, "fixtures", "chip_v5e.xplane.pb")))
     assert cap.step_executions == one.step_executions > 0 and cap.step_seconds == one.step_seconds
     gs, ge = cap.idle_intervals()
     assert float((ge - gs).sum()) * 1e-9 == pytest.approx(reduced["window_s"] - reduced["busy_fullest_s"], rel=1e-9)
