@@ -201,7 +201,9 @@ def main(dist: Distributed, cfg: Config) -> None:
     update = make_update_fn(module, tx, cfg, num_minibatches, mb_size)
     # per-step inference runs on the player device (host CPU when the mesh is
     # an accelerator — parallel/placement.py); blocking refresh after
-    # every update keeps PPO strictly on-policy
+    # every update keeps PPO strictly on-policy. NOT `in_order`: with the
+    # threaded source (`algo.overlap.enabled`) the player thread may act beside
+    # a donating update, so on the learner's device the mirror keeps a copy
     mirror, pdev, player_key, root_key = make_param_mirror(
         cfg, dist.local_device, params, root_key, allow_async=False
     )

@@ -15,7 +15,7 @@ scan, so there is no player/trainer duality.
 """
 from __future__ import annotations
 
-from typing import Any, Dict, List, Optional, Sequence, Tuple
+from typing import Any, Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 import flax.linen as nn
 import gymnasium as gym
@@ -24,6 +24,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ...models import MLP
+from ...models import sequence as seq
 from ..ppo.agent import PPOEncoder, actions_and_log_probs  # noqa: F401 — shared sampling
 
 
@@ -149,6 +150,35 @@ class RecurrentPPOAgent(nn.Module):
         )
 
 
+class SequencePolicy(NamedTuple):
+    """The sequence backbone (`models/sequence.py`) as the loop sees it: one token id an env step in, one id of the
+    held vocabulary slice out; the recurrent carry is the per-env latent cache."""
+
+    cfg: seq.SequenceConfig
+    token_key: str
+
+
+def backbone_name(cfg: Any) -> str:
+    """`algo.backbone.name`: `lstm` (every recipe that says nothing) or the sequence model's."""
+    return str(cfg.select("algo.backbone.name", "lstm") or "lstm")
+
+
+def build_sequence_agent(dist: Any, cfg: Any, observation_space: gym.spaces.Dict, action_space: gym.Space, key: jax.Array,
+                         params: Optional[Any] = None) -> Tuple[SequencePolicy, Any]:
+    scfg = seq.SequenceConfig.from_node(cfg.algo.backbone)
+    keys = list(cfg.algo.mlp_keys.encoder)
+    if len(keys) != 1 or list(cfg.algo.cnn_keys.encoder):
+        raise ValueError(f"a sequence policy reads one token key (`algo.mlp_keys.encoder`), got {keys}")
+    space = observation_space[keys[0]]
+    if not np.issubdtype(space.dtype, np.integer) or int(np.prod(space.shape)) != 1:
+        raise ValueError(f"observation {keys[0]!r} must be one integer id a step, got {space}")
+    if not isinstance(action_space, gym.spaces.Discrete) or int(action_space.n) != scfg.vocab_held:
+        raise ValueError(f"the actions are the ids of the held slice, Discrete({scfg.vocab_held}); the env has {action_space}")
+    if params is None:
+        params = seq.init_params(scfg, key)
+    return SequencePolicy(scfg, keys[0]), dist.replicate(params)
+
+
 def build_agent(
     dist: Any,
     cfg: Any,
@@ -156,8 +186,10 @@ def build_agent(
     action_space: gym.Space,
     key: jax.Array,
     params: Optional[Any] = None,
-) -> Tuple[RecurrentPPOAgent, Any]:
-    """Construct module + params (reference agent.py:402-470 build_agent)."""
+) -> Tuple[Any, Any]:
+    """Construct module + params (reference agent.py:402-470 build_agent). The backbone is the recipe's choice."""
+    if backbone_name(cfg) != "lstm":
+        return build_sequence_agent(dist, cfg, observation_space, action_space, key, params)
     is_continuous = isinstance(action_space, gym.spaces.Box)
     if is_continuous:
         actions_dim = [int(np.prod(action_space.shape))]

@@ -35,16 +35,13 @@ from ...ops import gae as gae_op
 from ...optim import clipped
 from ...parallel import Distributed
 from ...parallel.placement import make_param_mirror
-from ...telemetry import Telemetry
-from ...utils.checkpoint import CheckpointManager
 from ...utils.env import episode_stats, vectorize
 from ...utils.logger import get_log_dir, get_logger
 from ...utils.registry import register_algorithm, register_evaluation
-from ...resilience import RunGuard
-from ...utils.utils import linear_annealing, save_configs
 from ..ppo.loss import entropy_loss, policy_loss, value_loss
-from .agent import RecurrentPPOAgent, actions_and_log_probs, build_agent
-from .utils import AGGREGATOR_KEYS, prepare_obs, test
+from .agent import RecurrentPPOAgent, actions_and_log_probs, backbone_name, build_agent
+from .loop import LoopRun
+from .utils import prepare_obs, test, update_coefs
 
 
 def make_act_fn(module: RecurrentPPOAgent):
@@ -144,14 +141,12 @@ def make_update_fn(module: RecurrentPPOAgent, tx, cfg: Config, num_minibatches: 
 
 @register_algorithm(name="ppo_recurrent")
 def main(dist: Distributed, cfg: Config) -> None:
-    root_key = dist.seed_everything(cfg.seed)
-    rank = dist.process_index
-    log_dir = get_log_dir(cfg, cfg.root_dir, cfg.run_name)
-    logger = get_logger(cfg, log_dir, rank)
-    if rank == 0:
-        save_configs(cfg, log_dir)
+    if backbone_name(cfg) != "lstm":  # the backbone is the recipe's choice: a sequence model has a loop of its own
+        from . import sequence_policy
 
-    envs = vectorize(cfg, cfg.seed, rank, log_dir)
+        return sequence_policy.main(dist, cfg)
+    run = LoopRun(dist, cfg)  # the run around the loop, shared with the sequence backbone's
+    rank, log_dir, logger, envs, state = run.rank, run.log_dir, run.logger, run.envs, run.state
     obs_space = envs.single_observation_space
     action_space = envs.single_action_space
     num_envs = int(cfg.env.num_envs)
@@ -161,11 +156,7 @@ def main(dist: Distributed, cfg: Config) -> None:
     if not isinstance(obs_space, gym.spaces.Dict):
         raise RuntimeError(f"Unexpected observation type, should be of type Dict, got: {obs_space}")
 
-    state = None
-    if cfg.checkpoint.resume_from:
-        state = CheckpointManager.load(cfg.checkpoint.resume_from)
-
-    root_key, init_key = jax.random.split(state["rng"] if state else root_key)
+    root_key, init_key = jax.random.split(run.root_key)
     module, params = build_agent(
         dist, cfg, obs_space, action_space, init_key, state["params"] if state else None
     )
@@ -206,18 +197,9 @@ def main(dist: Distributed, cfg: Config) -> None:
         partial(gae_op, num_steps=rollout_steps, gamma=cfg.algo.gamma, gae_lambda=cfg.algo.gae_lambda)
     )
 
-    telem = Telemetry.setup(cfg, log_dir, rank, logger=logger, aggregator_keys=AGGREGATOR_KEYS)
+    run.begin(num_envs * rollout_steps)
+    telem = run.telem
     aggregator = telem.aggregator
-    ckpt = CheckpointManager(log_dir, keep_last=cfg.checkpoint.keep_last, enabled=rank == 0)
-    guard = RunGuard.setup(cfg, ckpt, telem, log_dir)
-    ckpt = guard.ckpt
-
-    policy_steps_per_iter = num_envs * rollout_steps
-    num_updates = int(cfg.algo.total_steps) // policy_steps_per_iter if not cfg.dry_run else 1
-    start_iter = (state["update"] + 1) if state else 1
-    policy_step = state["policy_step"] if state else 0
-    last_log = state["last_log"] if state else 0
-    last_checkpoint = state["last_checkpoint"] if state else 0
 
     def to_onehot(np_actions: np.ndarray) -> np.ndarray:
         """int actions [N, n_dims] → concatenated one-hot [N, act_width]."""
@@ -239,19 +221,11 @@ def main(dist: Distributed, cfg: Config) -> None:
     carry = jax.device_put(module.initial_states(num_envs), pdev)
     prev_actions = np.zeros((num_envs, act_width), np.float32)
 
-    def _ckpt_state():
-        return {
-            "params": params,
-            "opt_state": opt_state,
-            "update": update_iter,
-            "policy_step": policy_step,
-            "last_log": last_log,
-            "last_checkpoint": last_checkpoint,
-            "rng": root_key,
-        }
+    def learner():
+        return params, opt_state, root_key
 
-    for update_iter in range(start_iter, num_updates + 1):
-        telem.tick(policy_step)
+    for update_iter in range(run.start_iter, run.num_updates + 1):
+        telem.tick(run.policy_step)
         chunk_cx: list = []
         chunk_hx: list = []
         with telem.span("Time/env_interaction_time"):
@@ -274,7 +248,7 @@ def main(dist: Distributed, cfg: Config) -> None:
                 else:
                     env_actions = np_actions.reshape(num_envs)
                 next_obs, rewards, terminated, truncated, info = envs.step(env_actions)
-                policy_step += num_envs
+                run.policy_step += num_envs
 
                 rewards = np.asarray(rewards, dtype=np.float32).reshape(num_envs, 1)
                 dones = np.logical_or(terminated, truncated).astype(np.float32).reshape(num_envs, 1)
@@ -369,25 +343,7 @@ def main(dist: Distributed, cfg: Config) -> None:
             data["hx0"] = jnp.asarray(np.stack(chunk_hx).reshape(num_sequences, H))
             data = {k: jax.device_put(v, dist.batch_sharding) for k, v in data.items()}
 
-            frac = 1.0
-            if cfg.algo.anneal_lr:
-                frac = 1.0 - (update_iter - 1) / max(num_updates, 1)
-            coefs = {
-                "clip_coef": jnp.asarray(
-                    linear_annealing(cfg.algo.clip_coef, update_iter - 1, num_updates)
-                    if cfg.algo.anneal_clip_coef
-                    else cfg.algo.clip_coef,
-                    jnp.float32,
-                ),
-                "ent_coef": jnp.asarray(
-                    linear_annealing(cfg.algo.ent_coef, update_iter - 1, num_updates)
-                    if cfg.algo.anneal_ent_coef
-                    else cfg.algo.ent_coef,
-                    jnp.float32,
-                ),
-                "vf_coef": jnp.asarray(cfg.algo.vf_coef, jnp.float32),
-                "lr_frac": jnp.asarray(frac, jnp.float32),
-            }
+            coefs = update_coefs(cfg, update_iter, run.num_updates)
             root_key, up_key = jax.random.split(root_key)
             params, opt_state, metrics = update(params, opt_state, data, coefs, up_key)
             telem.record_grad_steps(num_minibatches * int(cfg.algo.update_epochs))
@@ -396,23 +352,10 @@ def main(dist: Distributed, cfg: Config) -> None:
         for k, v in metrics.items():
             aggregator.update(k, np.asarray(v))  # host-sync: ok (update cadence)
 
-        if policy_step - last_log >= cfg.metric.log_every or cfg.dry_run:
-            telem.log(policy_step)
-            last_log = policy_step
-
-        if (
-            cfg.checkpoint.every > 0 and policy_step - last_checkpoint >= cfg.checkpoint.every
-        ) or cfg.dry_run or update_iter == num_updates:
-            last_checkpoint = policy_step
-            ckpt.save(policy_step, _ckpt_state())
-
-        if guard.stop_reached(policy_step, int(cfg.algo.total_steps), _ckpt_state):
+        if run.end_iteration(update_iter, learner):
             break
 
-    guard.close(policy_step, _ckpt_state)
-    envs.close()
-    telem.close(policy_step)
-    if rank == 0 and cfg.algo.run_test:
+    def run_test(params):
         test_env = vectorize(
             Config({**cfg.to_dict(), "env": {**cfg.env.to_dict(), "num_envs": 1}}),
             cfg.seed,
@@ -420,12 +363,8 @@ def main(dist: Distributed, cfg: Config) -> None:
             log_dir,
         ).envs[0]
         test(module, params, test_env, cfg, log_dir, logger)
-    if rank == 0 and not cfg.model_manager.disabled:
-        from ...utils.model_manager import register_model
 
-        register_model(cfg, {"agent": params}, log_dir)
-    if logger is not None:
-        logger.close()
+    run.close(learner, run_test if cfg.algo.run_test else None)
 
 
 @register_evaluation(algorithms="ppo_recurrent")

@@ -17,6 +17,20 @@ AGGREGATOR_KEYS = {
 MODELS_TO_REGISTER = {"agent"}
 
 
+def update_coefs(cfg: Any, update_iter: int, num_updates: int) -> Dict[str, jax.Array]:
+    """The coefficients one update is given (both backbones' loops): clip and entropy annealed where the recipe says
+    so, the value coefficient, and the learning rate's fraction."""
+    from ...utils.utils import linear_annealing
+
+    a = cfg.algo
+    return {
+        "clip_coef": jnp.asarray(linear_annealing(a.clip_coef, update_iter - 1, num_updates) if a.anneal_clip_coef else a.clip_coef, jnp.float32),
+        "ent_coef": jnp.asarray(linear_annealing(a.ent_coef, update_iter - 1, num_updates) if a.anneal_ent_coef else a.ent_coef, jnp.float32),
+        "vf_coef": jnp.asarray(a.vf_coef, jnp.float32),
+        "lr_frac": jnp.asarray(1.0 - (update_iter - 1) / max(num_updates, 1) if a.anneal_lr else 1.0, jnp.float32),
+    }
+
+
 def prepare_obs(
     obs: Dict[str, np.ndarray], cnn_keys=(), mlp_keys=(), num_envs: int = 1
 ) -> Dict[str, jax.Array]:

@@ -99,3 +99,33 @@ class CrashingDummyEnv(DiscreteDummyEnv):
         if self._lifetime_steps % self._crash_every == 0:
             raise RuntimeError(f"scripted crash at lifetime step {self._lifetime_steps}")
         return super().step(action)
+
+
+class TokenDummyEnv(gym.Env):
+    """One token id a step: the observation is the agent's last action echoed (id 0 after a reset), the action any id
+    of `Discrete(vocab)`, episodes of `n_steps` steps, reward 1 at the last step if the episode's ids were not all
+    alike. What a sequence policy (`exp=ppo_recurrent_xing4`) acts on where no text environment is installed."""
+
+    def __init__(self, vocab: int = 16, n_steps: int = 8):
+        self.observation_space = gym.spaces.Dict({"token": gym.spaces.Box(0, vocab - 1, (1,), np.int32)})
+        self.action_space = gym.spaces.Discrete(int(vocab))
+        self._n_steps = int(n_steps)
+        self._seen: List[int] = []
+        self.render_mode = "rgb_array"
+
+    def reset(self, seed: Optional[int] = None, options: Optional[dict] = None):
+        super().reset(seed=seed)
+        self._seen = []
+        return {"token": np.zeros((1,), np.int32)}, {}
+
+    def step(self, action: Any):
+        self._seen.append(int(action))
+        done = len(self._seen) >= self._n_steps
+        reward = float(done and len(set(self._seen)) > 1)
+        return {"token": np.array([int(action)], np.int32)}, reward, done, False, {}
+
+    def render(self):
+        return np.zeros((64, 64, 3), dtype=np.uint8)
+
+    def close(self):
+        pass

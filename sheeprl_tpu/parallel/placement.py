@@ -155,10 +155,15 @@ class ParamMirror:
     player never sees a half-updated tree.
     """
 
-    def __init__(self, params: Any, device: Any, async_refresh: bool = False):
+    def __init__(self, params: Any, device: Any, async_refresh: bool = False, in_order: bool = False):
         self.device = device
         self.async_refresh = bool(async_refresh)
+        # the caller's word that no act is dispatched between an update's dispatch and the refresh that follows it:
+        # a blocking mirror on the learner's device then holds the learner's own buffers (`_put`)
+        self.alias = bool(in_order) and not self.async_refresh
         self.same_device = False  # whether the newest copy never left the device
+        self.aliased = False  # whether the newest "copy" is the learner's own buffers
+        self.copied_bytes = 0  # what the newest refresh copied or moved
         self.params = self._put(params)
         self._pending: Optional[Any] = None
         self._swap_lock = threading.Lock()
@@ -176,21 +181,34 @@ class ParamMirror:
         device, and the learner's train step donates its param buffers,
         which would delete the mirror's copy out from under the player.
         Those leaves get a real on-device copy: new buffers, all from one
-        dispatch."""
+        dispatch.
+
+        The one exception is a mirror told that acting and the update are in
+        order on one stream (``in_order``, never with async refresh): with
+        every leaf on its device it keeps each leaf's single-device view of
+        the learner's own buffer (`_local_copy`: committed to the one device
+        like a copy would be, so the player step traces the same), allocates
+        nothing, and is re-pointed by the refresh that follows the donating
+        update. A model whose parameters fill most of the chip has no room
+        for a second copy, let alone a third in flight."""
         leaves, treedef = jax.tree.flatten(params)
         local = [_local_copy(x, self.device) for x in leaves]
         here = [x for x in local if x is not None]
-        copied = iter(_copy_leaves(here) if here else ())
         self.same_device = len(here) == len(leaves)
+        self.aliased = self.alias and self.same_device
+        self.copied_bytes = 0 if self.aliased else tree_bytes(leaves)
+        if self.aliased:
+            return treedef.unflatten(local)
+        copied = iter(_copy_leaves(here) if here else ())
         return treedef.unflatten(
             [jax.device_put(x, self.device) if here is None else next(copied) for x, here in zip(leaves, local)]
         )
 
     def refresh(self, params: Any) -> None:
         leaves = jax.tree.leaves(params)
-        with Span("Time/param_refresh", bytes=tree_bytes(leaves), leaves=len(leaves)) as span:
+        with Span("Time/param_refresh", leaves=len(leaves)) as span:
             new = self._put(params)
-            span.count(same_device=int(self.same_device))
+            span.count(bytes=self.copied_bytes, same_device=int(self.same_device))
         if self.async_refresh:
             with self._swap_lock:
                 self._pending = new
@@ -250,7 +268,7 @@ def read_subtree(tree: Any, probe: Callable[..., Any], *args: Any) -> Callable[[
     return select
 
 
-def make_param_mirror(cfg: Any, accelerator: Any, params: Any, root_key: Any, allow_async: bool = True):
+def make_param_mirror(cfg: Any, accelerator: Any, params: Any, root_key: Any, allow_async: bool = True, in_order: bool = False):
     """The per-algorithm player setup, in one place: resolve the player
     device from the bytes of ``params`` (the tree the player reads), mirror
     it there, and derive a player PRNG key committed next to it (so the env
@@ -259,6 +277,13 @@ def make_param_mirror(cfg: Any, accelerator: Any, params: Any, root_key: Any, al
     ``allow_async=False`` pins the mirror to blocking refresh regardless of
     ``algo.player.async_refresh`` — on-policy algorithms (PPO/A2C) must act
     with the params the coming update will be credited to.
+
+    ``in_order=True`` is the caller's word that acting and the update are
+    serial on one in-order stream: no act is dispatched between an update's
+    dispatch and the ``refresh`` that follows it (a loop with no player
+    thread; never the threaded source of ``engine/overlap.py``, whose player
+    acts beside the update). A blocking mirror on the learner's device then
+    aliases the learner's buffers instead of copying them.
 
     ``mirror.placement`` is the choice as the run's ``placement`` event; the
     caller emits it once it has its ``telem``.
@@ -272,6 +297,7 @@ def make_param_mirror(cfg: Any, accelerator: Any, params: Any, root_key: Any, al
         params,
         pdev,
         async_refresh=allow_async and bool(cfg.select("algo.player.async_refresh", False)),
+        in_order=in_order and not allow_async,
     )
     mirror.placement = {
         "event": "placement",
@@ -281,6 +307,9 @@ def make_param_mirror(cfg: Any, accelerator: Any, params: Any, root_key: Any, al
         "tree_bytes": nbytes,
         "threshold_bytes": AUTO_ACCELERATOR_MIN_BYTES,
         "same_device": int(mirror.same_device),
+        # how a refresh brings the new parameters: the learner's own buffers re-pointed (acting and the update in
+        # order on one device), one on-device copy, or a transfer to the player's device
+        "refresh": "alias" if mirror.aliased else "copy" if mirror.same_device else "transfer",
     }
     root_key, pk = jax.random.split(root_key)
     return mirror, pdev, jax.device_put(pk, pdev), root_key

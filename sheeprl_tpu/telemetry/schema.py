@@ -438,6 +438,35 @@ EVENT_SCHEMAS: Dict[str, Dict[str, Tuple[bool, type]]] = {
         "tree_bytes": (True, _NUM),
         "threshold_bytes": (True, _NUM),
         "same_device": (True, _NUM),
+        "refresh": (False, _STR),  # alias | copy | transfer
+    },
+    # a sequence policy on the recurrent on-policy loop, once at set-up
+    # (algos/ppo_recurrent/sequence_policy.py): the share of each layer this
+    # chip holds, the bytes of the per-env latent cache and of the parameters
+    "sequence_policy": {
+        "backbone": (True, _STR),
+        "layers": (True, _NUM),
+        "experts_held": (True, _NUM),
+        "first_expert": (True, _NUM),
+        "n_routed_experts": (True, _NUM),
+        "heads_held": (True, _NUM),
+        "num_attention_heads": (True, _NUM),
+        "vocab_held": (True, _NUM),
+        "vocab_size": (True, _NUM),
+        "cache_bytes": (True, _NUM),
+        "param_bytes": (True, _NUM),
+    },
+    # the expert layers' load over one train call, from numbers the update
+    # returns beside its losses: (token, expert) pairs computed here, the
+    # rows the grouped products multiplied (a slot for every pair that can
+    # come), their ratio, the fullest held expert's load over the mean (the
+    # worst layer and step) and the pairs left out (none: dropless)
+    "moe_load": {
+        "routed_here": (True, _NUM),
+        "rows": (True, _NUM),
+        "slot_occupancy": (True, _NUM),
+        "max_over_mean": (True, _NUM),
+        "dropped": (True, _NUM),
     },
     # how a device replay ring keeps its rows, once per ring when it is
     # allocated (data/device_ring.py `_allocate`): per key the item's own
@@ -709,7 +738,7 @@ EVENT_SCHEMAS: Dict[str, Dict[str, Tuple[bool, type]]] = {
 # inside `Time/env_interaction_time`. howto/telemetry.md has where each lies.
 SPAN_SCHEMAS: Dict[str, Tuple[str, ...]] = {
     "Time/env_interaction_time": ("env_steps", "version"),
-    "Time/train_time": ("grad_steps", "burst"),
+    "Time/train_time": ("grad_steps", "burst", "tokens"),
     "Time/learner_apply": ("env_steps", "packets"),
     "Time/replay_sync": ("rows", "bytes"),
     "Time/replay_sample": ("grad_steps",),
@@ -717,9 +746,10 @@ SPAN_SCHEMAS: Dict[str, Tuple[str, ...]] = {
     "Time/param_refresh": ("bytes", "leaves", "same_device"),
     "Time/log_flush": (),
     "Time/checkpoint": (),
+    "Time/cache_reset": ("rows",),
     "Wait/learner_queue": ("packets",),
     "Wait/player_queue": (),
-    "Player/act": (),
+    "Player/act": ("tokens", "cache_rows"),
     "Player/env_step": (),
     "Player/record": (),
 }

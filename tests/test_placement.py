@@ -372,3 +372,61 @@ def test_wall_clock_stopper_and_cap_helper():
     # disabled (default -1) → never stops
     wall = WallClockStopper(_WallCfg(-1, True))
     assert not wall.expired(0, 100)
+
+
+# -- the in-order mirror: a blocking mirror on the learner's own device aliases -----------------
+def _pointers(tree):
+    return [x.unsafe_buffer_pointer() for x in jax.tree.leaves(tree)]
+
+
+def test_in_order_mirror_on_the_learners_device_is_the_learners_own_buffers_and_follows_a_donated_update(monkeypatch):
+    """Acting and the update serial on one stream (`in_order`): no copy program is dispatched, nothing is
+    allocated, `Time/param_refresh` counts 0 bytes, and after an update that donates the parameters the refresh
+    re-points the mirror at the new ones."""
+    from sheeprl_tpu.telemetry.spans import GLOBAL_TRACKER
+
+    dev = host_device()
+    params = jax.device_put({"w": jnp.ones((4, 4)), "b": jnp.zeros((4,)), "deep": {"k": jnp.ones((2,))}}, dev)
+    monkeypatch.setattr(placement, "_copy_leaves", lambda leaves: pytest.fail("an in-order mirror copies nothing"))
+    mirror = ParamMirror(params, dev, in_order=True)
+    assert mirror.same_device and mirror.aliased and mirror.copied_bytes == 0
+    assert _pointers(mirror.current()) == _pointers(params)
+    assert all(x.sharding == jax.sharding.SingleDeviceSharding(dev) for x in jax.tree.leaves(mirror.current()))
+    step = _donating_consumer()
+    GLOBAL_TRACKER.compute(reset=True)
+    for i in range(3):
+        stale = mirror.current()
+        params = step(params)  # donates what the mirror points at
+        assert all(x.is_deleted() for x in jax.tree.leaves(stale))
+        mirror.refresh(params)
+        assert _pointers(mirror.current()) == _pointers(params)
+        np.testing.assert_allclose(np.asarray(mirror.current()["w"]), (2.0 + i) * np.ones((4, 4)))
+    assert GLOBAL_TRACKER.sums()["Time/param_refresh"] == {"leaves": 9, "bytes": 0, "same_device": 3}
+    GLOBAL_TRACKER.compute(reset=True)
+
+
+@pytest.mark.parametrize("kind", ["async", "another_device", "not_said"])
+def test_only_a_blocking_in_order_mirror_on_the_learners_device_aliases(kind):
+    """The async mirror, the mirror on another device and the mirror of a caller that says nothing copy as before:
+    new buffers, the bytes counted, and the parameters they were made from may be donated at once."""
+    dev = host_device()
+    params = jax.device_put({"w": jnp.ones((4, 4)), "b": jnp.zeros((4,))}, dev)
+    target = jax.devices()[1] if kind == "another_device" else dev
+    mirror = ParamMirror(params, target, async_refresh=kind == "async", in_order=kind != "not_said")
+    assert not mirror.aliased and mirror.copied_bytes == (16 + 4) * 4
+    assert mirror.same_device == (kind != "another_device")
+    if kind != "another_device":
+        assert not set(_pointers(mirror.current())) & set(_pointers(params))
+    params = _donating_consumer()(params)
+    np.testing.assert_allclose(np.asarray(mirror.current()["w"]), np.ones((4, 4)))  # its own copy outlives the donation
+
+
+def test_make_param_mirror_aliases_only_for_a_caller_that_is_in_order_and_blocking():
+    from sheeprl_tpu.config import Config
+
+    params = jax.device_put({"w": jnp.ones((4, 8))}, jax.devices()[0])
+    said = {(False, True): "alias", (False, False): "copy", (True, True): "copy", (True, False): "copy"}
+    for (allow_async, in_order), refresh in said.items():
+        cfg = Config({"algo": {"player": {"async_refresh": True}}})
+        mirror, _, _, _ = placement.make_param_mirror(cfg, jax.devices()[0], params, jax.random.key(0), allow_async=allow_async, in_order=in_order)
+        assert mirror.placement["refresh"] == refresh and mirror.placement["same_device"] == 1, (allow_async, in_order)
