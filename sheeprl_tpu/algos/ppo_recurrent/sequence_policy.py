@@ -68,7 +68,7 @@ def make_value_fn(module: SequencePolicy):
     return value_fn
 
 
-@jax.jit
+@partial(jax.jit, donate_argnums=(0,))
 def restart(state):
     """The rollout-boundary restart: the next token is written at row 0 and read alone; the rows stay, unread."""
     cache = state["cache"]
@@ -174,6 +174,7 @@ def main(dist: Distributed, cfg: Config) -> None:
         "experts_held": scfg.experts_held, "first_expert": scfg.first_expert, "n_routed_experts": scfg.n_routed_experts,
         "heads_held": scfg.heads_held, "num_attention_heads": scfg.num_attention_heads, "vocab_held": scfg.vocab_held,
         "vocab_size": scfg.vocab_size, "cache_bytes": tree_bytes(carry["cache"]), "param_bytes": tree_bytes(params),
+        "cache_layout": list(carry["cache"]["latents"].format.layout.major_to_minor),
     })
 
     obs, _ = envs.reset(seed=cfg.seed)

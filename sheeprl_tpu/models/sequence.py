@@ -245,8 +245,9 @@ def mla_train(p: Params, u: jax.Array, positions: jax.Array, mask: jax.Array, cf
 
 def mla_decode(p: Params, u: jax.Array, latents: jax.Array, layer: int, pos: jax.Array, start: jax.Array, cfg: SequenceConfig,
                inv_freq: np.ndarray, write: bool = True) -> Tuple[jax.Array, jax.Array]:
-    """The acting form for one token an env, `u` [N, C]: the cache (`latents[layer]`, [N, S, kv_lora_rank + rope])
-    holds `(c_kv, k_rope)` only; `q_nope` is absorbed through W_uk and the weighted sum of `c_kv` goes through W_uv.
+    """The acting form for one token an env, `u` [N, C]: the cache (`latents[layer]`, [N, S, `cache_width`]) holds
+    `(c_kv, k_rope)` only, in its first `kv_lora_rank + rope` columns; `q_nope` is absorbed through W_uk and the
+    weighted sum of `c_kv` goes through W_uv.
     Rows `start[e]..pos - 1` are read and the token attends to itself beside them; with `write` its own latent goes
     to row `pos`, in place. Returns (output [N, C], all layers' latents)."""
     r = cfg.kv_lora_rank
@@ -256,7 +257,7 @@ def mla_decode(p: Params, u: jax.Array, latents: jax.Array, layer: int, pos: jax
     w = _w_ukv(p, cfg)
     q_lat = jnp.einsum("nhd,chd->nhc", q_nope, w[..., :cfg.qk_nope_head_dim])
     cache = latents[layer]
-    s = jnp.einsum("nhc,nsc->nhs", q_lat, cache[..., :r]) + jnp.einsum("nhd,nsd->nhs", q_rope, cache[..., r:])
+    s = jnp.einsum("nhc,nsc->nhs", q_lat, cache[..., :r]) + jnp.einsum("nhd,nsd->nhs", q_rope, cache[..., r:r + cfg.qk_rope_head_dim])
     own = jnp.einsum("nhc,nc->nh", q_lat, c_kv) + jnp.einsum("nhd,nd->nh", q_rope, k_rope)
     at = jnp.arange(cache.shape[1])
     live = (at >= start[:, None]) & (at < pos)
@@ -399,11 +400,22 @@ def forward_train(params: Params, tokens: jax.Array, is_first: jax.Array, cfg: S
     return logits, values, _sum_loads(loads)
 
 
+def cache_width(cfg: SequenceConfig) -> int:
+    """The cache's last axis: `kv_lora_rank + qk_rope_head_dim` rounded up to whole 128-wide lanes; the pad is never
+    read. The decode step writes one position for every env and layer, so the cache must be held row-major: the TPU's
+    default layout for `[.., capacity, 576]` puts the capacity axis minor-most (576 is no multiple of 128) and each
+    step relaid the whole cache out and back, while for `[.., capacity, 640]` it is row-major, in the bytes the
+    row-major tiles of 576 take anyway. Not a layout pinned at the jit boundary: an executable read back from the
+    persistent compilation cache returns its output in the default layout (jax 0.9)."""
+    width = cfg.kv_lora_rank + cfg.qk_rope_head_dim
+    return -(-width // 128) * 128
+
+
 def new_cache(cfg: SequenceConfig, num_envs: int, capacity: int) -> Dict[str, jax.Array]:
-    """The per-env latent cache: per layer `[num_envs, capacity, kv_lora_rank + rope]`, the write position, and per env
-    the position its episode started at."""
+    """The per-env latent cache: per layer `[num_envs, capacity, cache_width]`, the write position, and per env the
+    position its episode started at."""
     return {
-        "latents": jnp.zeros((cfg.num_hidden_layers, num_envs, capacity, cfg.kv_lora_rank + cfg.qk_rope_head_dim), jnp.float32),
+        "latents": jnp.zeros((cfg.num_hidden_layers, num_envs, capacity, cache_width(cfg)), jnp.float32),
         "pos": jnp.zeros((), jnp.int32),
         "start": jnp.zeros((num_envs,), jnp.int32),
     }

@@ -442,7 +442,8 @@ EVENT_SCHEMAS: Dict[str, Dict[str, Tuple[bool, type]]] = {
     },
     # a sequence policy on the recurrent on-policy loop, once at set-up
     # (algos/ppo_recurrent/sequence_policy.py): the share of each layer this
-    # chip holds, the bytes of the per-env latent cache and of the parameters
+    # chip holds, the bytes of the per-env latent cache and of the parameters,
+    # and the major-to-minor order the cache is held in on the device
     "sequence_policy": {
         "backbone": (True, _STR),
         "layers": (True, _NUM),
@@ -455,6 +456,7 @@ EVENT_SCHEMAS: Dict[str, Dict[str, Tuple[bool, type]]] = {
         "vocab_size": (True, _NUM),
         "cache_bytes": (True, _NUM),
         "param_bytes": (True, _NUM),
+        "cache_layout": (True, list),
     },
     # the expert layers' load over one train call, from numbers the update
     # returns beside its losses: (token, expert) pairs computed here, the

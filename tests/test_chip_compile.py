@@ -242,3 +242,59 @@ def test_uniform_ring_scatter_compiles_for_v5e_in_place(one_chip, size):
     mem = _scatter_steps.lower(ring, rows, _sds(one_chip, (8,), jnp.int32)).compile().memory_analysis()
     _touches_only_its_rows(mem, nbytes)
     assert mem.alias_size_in_bytes >= nbytes
+
+
+# -- the sequence policy's decode step: the latent cache in the layout it is written in ----
+# the cell's MLA widths (kv_lora_rank 512 + rope 64: a minor axis of 576, no multiple of 128), 32 envs, capacity 512;
+# the rest cut so that one compile takes seconds
+SEQ_CUT = [
+    "exp=ppo_recurrent_xing4", "algo.backbone.hidden_size=256", "algo.backbone.num_hidden_layers=2",
+    "algo.backbone.first_k_dense_replace=1", "algo.backbone.n_routed_experts=8", "algo.backbone.experts_held=2",
+    "algo.backbone.vocab_size=1024", "algo.backbone.vocab_held=512", "algo.backbone.num_attention_heads=4",
+    "algo.backbone.intermediate_size=1024", "algo.backbone.moe_intermediate_size=256",
+]
+SEQ_ENVS, SEQ_CAPACITY = 32, 512
+
+
+@pytest.fixture(scope="module")
+def decode_carry(one_chip):
+    """(module, params, the carry on the chip as `main` places it, the cache's bytes)."""
+    from sheeprl_tpu.algos.ppo_recurrent import sequence_policy as sp
+    from sheeprl_tpu.algos.ppo_recurrent.agent import SequencePolicy
+    from sheeprl_tpu.config import compose
+    from sheeprl_tpu.models import sequence as seq
+
+    scfg = seq.SequenceConfig.from_node(compose("config", SEQ_CUT).algo.backbone)
+    assert (scfg.kv_lora_rank, scfg.qk_rope_head_dim) == (512, 64)
+    module = SequencePolicy(scfg, "token")
+    params = _like(one_chip, jax.eval_shape(lambda k: seq.init_params(scfg, k), jax.random.key(0)))
+    state = _like(one_chip, jax.eval_shape(lambda: sp.new_state(module, SEQ_ENVS, SEQ_CAPACITY)))
+    latents = state["cache"]["latents"]
+    return module, params, state, int(np.prod(latents.shape)) * latents.dtype.itemsize
+
+
+def _whole_cache_copies(compiled, carry):
+    shape = "f32[%s]" % ",".join(map(str, carry["cache"]["latents"].shape))
+    return [line.strip()[:120] for line in compiled.as_text().splitlines() if " copy(" in line and shape + "{" in line]
+
+
+@pytest.mark.parametrize("program", ["act", "restart", "value_fn"])
+def test_decode_step_compiles_for_v5e_with_the_cache_updated_in_place(one_chip, decode_carry, program):
+    """The TPU's default layout for `f32[.., 512, 576]` puts the capacity axis minor-most; a step that writes one row
+    row-major relaid the whole cache out and back each call (two copies of 189 MB at the cell's 5 layers), and the
+    restart copied it once. Held `[.., 512, 640]` (`seq.cache_width`), the default layout is row-major."""
+    from sheeprl_tpu.algos.ppo_recurrent import sequence_policy as sp
+
+    module, params, carry, cache_bytes = decode_carry
+    tokens = _sds(one_chip, (SEQ_ENVS,), jnp.int32)
+    if program == "act":
+        key = _like(one_chip, jax.eval_shape(lambda: jax.random.key(0)))
+        lowered = sp.make_act_fn(module).lower(params, carry, tokens, _sds(one_chip, (SEQ_ENVS,), jnp.bool_), key)
+    elif program == "restart":
+        lowered = sp.restart.lower(carry)
+    else:  # a truncation's bootstrap reads the cache and returns values alone
+        lowered = sp.make_value_fn(module).lower(params, carry, tokens)
+    compiled = lowered.compile()
+    assert _whole_cache_copies(compiled, carry) == []
+    if program != "value_fn":
+        assert compiled.memory_analysis().alias_size_in_bytes >= cache_bytes  # the donated cache comes back in place

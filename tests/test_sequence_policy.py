@@ -137,6 +137,42 @@ def test_decode_through_the_cache_with_resets_equals_the_full_forward(small, pro
     assert int(cache["pos"]) == T and list(np.asarray(cache["start"])) == [0, 5, 8]
 
 
+def test_the_cache_pad_is_never_read_through_act_restart_and_value_fn(small):
+    """The cache is held `[.., capacity, cache_width]`, `kv_lora_rank + rope` rounded up to whole lanes (24 -> 128
+    here) so that the TPU holds it row-major. Two rollouts through `act`, `restart` and `value_fn` as the loop drives
+    them with the parent's unpadded cache, with zeros in the pad and with NaN there: the same bits, the pad left as it
+    was, and the cache handed back in the layout it was given."""
+    from sheeprl_tpu.algos.ppo_recurrent import sequence_policy as sp
+    from sheeprl_tpu.algos.ppo_recurrent.agent import SequencePolicy
+
+    _, scfg, _, shapes, tokens, is_first = small
+    module, params = SequencePolicy(scfg, "token"), weights(shapes)
+    act, value_fn, width = sp.make_act_fn(module), sp.make_value_fn(module), scfg.kv_lora_rank + scfg.qk_rope_head_dim
+    assert seq.cache_width(scfg) == 128 and seq.cache_width(scfg._replace(kv_lora_rank=512, qk_rope_head_dim=64)) == 640
+    got = {}
+    for pad in (None, 0.0, np.nan):
+        carry, out = sp.new_state(module, B, T), []
+        latents = carry["cache"]["latents"]
+        carry["cache"]["latents"] = latents[..., :width] if pad is None else latents.at[..., width:].set(pad)
+        layout = carry["cache"]["latents"].format.layout.major_to_minor
+        for rollout in range(2):
+            carry = sp.restart(carry)
+            for t in range(T):
+                actions, carry = act(params, carry, tokens[:, t], is_first[:, t], jax.random.key(rollout))
+                out.append(actions)
+                if t == 8:  # a truncation's bootstrap
+                    out.append(value_fn(params, carry, tokens[:, t + 1]))
+            latents = np.asarray(carry["cache"]["latents"])  # before the next donation
+            out += [np.asarray(carry["logprobs"]), np.asarray(carry["values"]), latents[..., :width]]
+            if pad is not None:
+                assert np.array_equal(latents[..., width:], np.full_like(latents[..., width:], pad), equal_nan=True)
+            assert carry["cache"]["latents"].format.layout.major_to_minor == layout
+        got[pad] = [np.asarray(x) for x in out]
+    unpadded, zeros, nans = got.values()
+    assert len(unpadded) == len(zeros) == len(nans) == 2 * (T + 4)
+    assert all(a.dtype == b.dtype and a.tobytes() == b.tobytes() == c.tobytes() for a, b, c in zip(unpadded, zeros, nans))
+
+
 @pytest.mark.parametrize("grouped", [True, False], ids=["grouped", "acting_form"])
 def test_the_expert_shares_add_up_to_the_whole_layer(small, grouped):
     _, scfg, sz, shapes, _, _ = small
@@ -260,7 +296,7 @@ def test_the_recipe_trains_through_cli_run_with_every_new_span_and_event(tiny_ru
     assert all(e["rows"] == 2 * 4 * 32 * 2 and e["routed_here"] == round(e["slot_occupancy"] * e["rows"]) for e in loads)
     share = next(e for e in events if e["event"] == "sequence_policy")
     assert (share["experts_held"], share["first_expert"], share["heads_held"], share["vocab_held"], share["layers"]) == (2, 2, 2, 32, 3)
-    assert share["cache_bytes"] == 3 * 4 * 16 * 24 * 4 + 4 + 4 * 4
+    assert share["cache_bytes"] == 3 * 4 * 16 * 128 * 4 + 4 + 4 * 4 and share["cache_layout"] == [0, 1, 2, 3]  # 24 columns held in 128
     placement = next(e for e in events if e["event"] == "placement")
     assert placement["same_device"] == 1 and placement["refresh"] == "alias"
     spans = set().union(*(e["spans"] for e in events if e["event"] in ("log", "shutdown")))
