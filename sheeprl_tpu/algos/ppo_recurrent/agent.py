@@ -156,6 +156,23 @@ class SequencePolicy(NamedTuple):
 
     cfg: seq.SequenceConfig
     token_key: str
+    block_length: int = 1  # tokens an env step
+    denoising_steps: int = 1  # forwards an env step
+
+
+class BlockDiffusionPolicy(NamedTuple):
+    """A model that generates by diffusion over blocks (`models/block_diffusion.py`) as the loop sees it: a block of
+    `block_length` token ids an env step in, a block of as many ids of the held slice out, committed over
+    `denoising_steps` forwards; the recurrent carry is the per-env K/V cache. The slice's last id is the mask."""
+
+    cfg: seq.SequenceConfig
+    token_key: str
+    block_length: int
+    denoising_steps: int
+
+    @property
+    def mask_id(self) -> int:
+        return self.cfg.vocab_held - 1
 
 
 def backbone_name(cfg: Any) -> str:
@@ -164,19 +181,29 @@ def backbone_name(cfg: Any) -> str:
 
 
 def build_sequence_agent(dist: Any, cfg: Any, observation_space: gym.spaces.Dict, action_space: gym.Space, key: jax.Array,
-                         params: Optional[Any] = None) -> Tuple[SequencePolicy, Any]:
-    scfg = seq.SequenceConfig.from_node(cfg.algo.backbone)
+                         params: Optional[Any] = None) -> Tuple[Any, Any]:
+    """A `SequencePolicy`, or a `BlockDiffusionPolicy` where the backbone names a `block_length`, and its parameters."""
+    node = cfg.algo.backbone
+    scfg = seq.SequenceConfig.from_node(node)
     keys = list(cfg.algo.mlp_keys.encoder)
     if len(keys) != 1 or list(cfg.algo.cnn_keys.encoder):
         raise ValueError(f"a sequence policy reads one token key (`algo.mlp_keys.encoder`), got {keys}")
     space = observation_space[keys[0]]
-    if not np.issubdtype(space.dtype, np.integer) or int(np.prod(space.shape)) != 1:
-        raise ValueError(f"observation {keys[0]!r} must be one integer id a step, got {space}")
-    if not isinstance(action_space, gym.spaces.Discrete) or int(action_space.n) != scfg.vocab_held:
-        raise ValueError(f"the actions are the ids of the held slice, Discrete({scfg.vocab_held}); the env has {action_space}")
-    if params is None:
-        params = seq.init_params(scfg, key)
-    return SequencePolicy(scfg, keys[0]), dist.replicate(params)
+    L = int(node.get("block_length") or 1)
+    if not np.issubdtype(space.dtype, np.integer) or int(np.prod(space.shape)) != L:
+        raise ValueError(f"observation {keys[0]!r} must be {L} integer id(s) a step, got {space}")
+    if "block_length" not in node:
+        if not isinstance(action_space, gym.spaces.Discrete) or int(action_space.n) != scfg.vocab_held:
+            raise ValueError(f"the actions are the ids of the held slice, Discrete({scfg.vocab_held}); the env has {action_space}")
+        return SequencePolicy(scfg, keys[0]), dist.replicate(seq.init_params(scfg, key) if params is None else params)
+    from ...models import block_diffusion as bd
+
+    S = int(node["denoising_steps"])
+    if scfg.attention != "gqa" or L % S:
+        raise ValueError(f"block diffusion runs grouped-query attention and commits block_length / denoising_steps ids a forward: {scfg.attention}, {L}, {S}")
+    if not isinstance(action_space, gym.spaces.MultiDiscrete) or list(action_space.nvec) != [scfg.vocab_held] * L:
+        raise ValueError(f"the actions are blocks of {L} ids of the held slice, MultiDiscrete([{scfg.vocab_held}] x {L}); the env has {action_space}")
+    return BlockDiffusionPolicy(scfg, keys[0], L, S), dist.replicate(bd.init_params(scfg, key) if params is None else params)
 
 
 def build_agent(

@@ -12,16 +12,22 @@ updated in place by one jitted decode step for all envs, which also keeps the
 log-probabilities and values of the rollout on the device; the update takes
 whole `[rollout_steps, b]` sequences with a causal same-episode mask.
 
+`main` drives this module's one-token policy, or a block-diffusion policy
+(`block_diffusion_policy.py`, a backbone that names a `block_length`) through
+the same names: `new_state`, `make_act_fn`, `make_value_fn`, `make_update_fn`
+and `STORED`, the rollout's arrays the decode step keeps. An env step is then
+a block of ids, observed and acted.
+
 The context never crosses a rollout boundary: the policy sees `is_first` at
 the first step of every rollout (the cache restarts, `Time/cache_reset`), and
 an episode still running at the last step counts as truncated there, its
 reward bootstrapped with the value of the next token. `algo.rollout_steps` is
-the cache's capacity.
+the cache's capacity (in blocks for a block-diffusion policy).
 """
 from __future__ import annotations
 
 from functools import partial
-from typing import Any, Dict
+from typing import Any, Callable, Dict, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -36,9 +42,12 @@ from ...parallel import Distributed
 from ...parallel.placement import make_param_mirror, tree_bytes
 from ...utils.env import episode_stats
 from ..ppo.loss import entropy_loss, policy_loss, value_loss
-from .agent import SequencePolicy, build_agent
+from .agent import BlockDiffusionPolicy, SequencePolicy, build_agent
 from .loop import LoopRun
 from .utils import update_coefs
+
+
+STORED = ("logprobs", "values")  # what the decode step keeps of a rollout, `[T, envs, ...]`, for the update
 
 
 def new_state(module: SequencePolicy, num_envs: int, capacity: int) -> Dict[str, Any]:
@@ -75,28 +84,26 @@ def restart(state):
     return {**state, "cache": {**cache, "pos": jnp.zeros_like(cache["pos"]), "start": jnp.zeros_like(cache["start"])}}
 
 
-def make_update_fn(module: SequencePolicy, tx, cfg: Config, num_minibatches: int, mb_size: int):
-    """Epochs x minibatches of whole sequences as one jitted program. `data` is sequence-major `[envs, T]`. Returns
-    the losses' means beside, per gradient step, the losses and the expert layers' load."""
-    update_epochs = int(cfg.algo.update_epochs)
-    clip_vloss = bool(cfg.algo.clip_vloss)
-    normalize_advantages = bool(cfg.algo.normalize_advantages)
+def ppo_losses(new_logprobs: jax.Array, entropy: jax.Array, new_values: jax.Array, mb: Dict[str, jax.Array], coefs: Dict[str, jax.Array],
+               cfg: Config) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+    """The clipped policy loss, the value loss and the entropy loss of one minibatch of whole sequences, and their sum
+    by the coefficients: (loss, the three by name)."""
+    advantages = mb["advantages"]
+    if bool(cfg.algo.normalize_advantages):
+        advantages = (advantages - jnp.mean(advantages)) / (jnp.std(advantages) + 1e-8)
     reduction = str(cfg.algo.loss_reduction)
+    pg_loss = policy_loss(new_logprobs, mb["logprobs"], advantages, coefs["clip_coef"], reduction)
+    v_loss = value_loss(new_values, mb["values"], mb["returns"], coefs["clip_coef"], bool(cfg.algo.clip_vloss), reduction)
+    ent_loss = entropy_loss(entropy, reduction)
+    loss = pg_loss + coefs["vf_coef"] * v_loss + coefs["ent_coef"] * ent_loss
+    return loss, {"Loss/policy_loss": pg_loss, "Loss/value_loss": v_loss, "Loss/entropy_loss": ent_loss}
 
-    def loss_fn(params, mb: Dict[str, jax.Array], coefs: Dict[str, jax.Array]):
-        logits, new_values, load = seq.forward_train(params, mb["tokens"], mb["is_first"], module.cfg)
-        with jax.named_scope("loss"):
-            logp = jax.nn.log_softmax(logits)
-            new_logprobs = jnp.take_along_axis(logp, mb["actions"][..., None], -1)[..., 0]
-            entropy = -jnp.sum(jnp.exp(logp) * logp, -1)
-            advantages = mb["advantages"]
-            if normalize_advantages:
-                advantages = (advantages - jnp.mean(advantages)) / (jnp.std(advantages) + 1e-8)
-            pg_loss = policy_loss(new_logprobs, mb["logprobs"], advantages, coefs["clip_coef"], reduction)
-            v_loss = value_loss(new_values, mb["values"], mb["returns"], coefs["clip_coef"], clip_vloss, reduction)
-            ent_loss = entropy_loss(entropy, reduction)
-            loss = pg_loss + coefs["vf_coef"] * v_loss + coefs["ent_coef"] * ent_loss
-        return loss, ({"Loss/policy_loss": pg_loss, "Loss/value_loss": v_loss, "Loss/entropy_loss": ent_loss}, load)
+
+def minibatch_update(loss_fn: Callable, tx, cfg: Config, num_minibatches: int, mb_size: int):
+    """Epochs x minibatches of whole sequences as one jitted program, each step `jax.value_and_grad(loss_fn)` and the
+    optimizer. `data` is sequence-major `[envs, T, ...]`. Returns the losses' means beside, per gradient step, the
+    losses and the expert layers' load (`loss_fn`'s aux: (losses, load))."""
+    update_epochs = int(cfg.algo.update_epochs)
 
     @partial(jax.jit, donate_argnums=(0, 1))
     def update(params, opt_state, data: Dict[str, jax.Array], coefs, key):
@@ -125,6 +132,21 @@ def make_update_fn(module: SequencePolicy, tx, cfg: Config, num_minibatches: int
     return update
 
 
+def make_update_fn(module: SequencePolicy, tx, cfg: Config, num_minibatches: int, mb_size: int):
+    """The update of a one-token policy: one causal same-episode forward over each minibatch's `[b, T]` sequences."""
+
+    def loss_fn(params, mb: Dict[str, jax.Array], coefs: Dict[str, jax.Array]):
+        logits, new_values, load = seq.forward_train(params, mb["tokens"], mb["is_first"], module.cfg)
+        with jax.named_scope("loss"):
+            logp = jax.nn.log_softmax(logits)
+            new_logprobs = jnp.take_along_axis(logp, mb["actions"][..., None], -1)[..., 0]
+            entropy = -jnp.sum(jnp.exp(logp) * logp, -1)
+            loss, losses = ppo_losses(new_logprobs, entropy, new_values, mb, coefs, cfg)
+        return loss, (losses, load)
+
+    return minibatch_update(loss_fn, tx, cfg, num_minibatches, mb_size)
+
+
 def moe_load_event(load: Dict[str, np.ndarray]) -> Dict[str, Any]:
     """The train call's `moe_load` event from what the update returned per gradient step (summed over the expert
     layers; `max_over_mean` the worst layer's)."""
@@ -142,6 +164,12 @@ def main(dist: Distributed, cfg: Config) -> None:
     root_key, init_key = jax.random.split(run.root_key)
     module, params = build_agent(dist, cfg, envs.single_observation_space, envs.single_action_space, init_key,
                                  state["params"] if state else None)
+    blocks = isinstance(module, BlockDiffusionPolicy)  # a block of ids an env step, committed over a few forwards
+    if blocks:
+        from . import block_diffusion_policy as policy
+    else:
+        from . import sequence_policy as policy
+    L, S = module.block_length, module.denoising_steps
     tx = clipped(instantiate(cfg.algo.optimizer), cfg.algo.get("max_grad_norm", 0.0))
     opt_state = dist.replicate(state["opt_state"] if state else tx.init(params))
 
@@ -153,9 +181,9 @@ def main(dist: Distributed, cfg: Config) -> None:
     num_minibatches = num_envs // mb_size
     grad_steps = num_minibatches * int(cfg.algo.update_epochs)
 
-    act = make_act_fn(module)
-    value_fn = make_value_fn(module)
-    update = make_update_fn(module, tx, cfg, num_minibatches, mb_size)
+    act = policy.make_act_fn(module)
+    value_fn = policy.make_value_fn(module)
+    update = policy.make_update_fn(module, tx, cfg, num_minibatches, mb_size)
     gae_fn = jax.jit(partial(gae_op, num_steps=rollout_steps, gamma=cfg.algo.gamma, gae_lambda=cfg.algo.gae_lambda))
 
     policy_steps_per_iter = num_envs * rollout_steps
@@ -167,27 +195,30 @@ def main(dist: Distributed, cfg: Config) -> None:
     # learner's own arrays (`in_order`), so no second copy of the parameters exists
     mirror, pdev, player_key, root_key = make_param_mirror(cfg, dist.local_device, params, root_key, allow_async=False, in_order=True)
     telem.emit(mirror.placement)
-    carry = jax.device_put(new_state(module, num_envs, rollout_steps), pdev)
+    carry = jax.device_put(policy.new_state(module, num_envs, rollout_steps), pdev)
     scfg = module.cfg
     telem.emit({
         "event": "sequence_policy", "backbone": str(cfg.algo.backbone.name), "layers": scfg.num_hidden_layers,
         "experts_held": scfg.experts_held, "first_expert": scfg.first_expert, "n_routed_experts": scfg.n_routed_experts,
         "heads_held": scfg.heads_held, "num_attention_heads": scfg.num_attention_heads, "vocab_held": scfg.vocab_held,
         "vocab_size": scfg.vocab_size, "cache_bytes": tree_bytes(carry["cache"]), "param_bytes": tree_bytes(params),
-        "cache_layout": list(carry["cache"]["latents"].format.layout.major_to_minor),
+        "cache_layout": {k: list(x.format.layout.major_to_minor) for k, x in carry["cache"].items() if x.ndim > 1},
     })
+    if blocks:
+        telem.emit(policy.block_event(module, num_envs, rollout_steps, mb_size))
 
     obs, _ = envs.reset(seed=cfg.seed)
+    token_shape = (L,) if blocks else ()
 
     def learner():
         return params, opt_state, root_key
 
     def tokens_of(o: Dict[str, np.ndarray]) -> np.ndarray:
-        return np.asarray(o[module.token_key], np.int32).reshape(-1)
+        return np.asarray(o[module.token_key], np.int32).reshape(num_envs, *token_shape)
 
     for update_iter in range(run.start_iter, run.num_updates + 1):
         telem.tick(run.policy_step)
-        with telem.span("Time/cache_reset", rows=num_envs * rollout_steps):
+        with telem.span("Time/cache_reset", rows=num_envs * rollout_steps * L):
             carry = restart(carry)
         rows: Dict[str, list] = {k: [] for k in ("tokens", "actions", "rewards", "dones", "is_first")}
         is_first = np.ones(num_envs, bool)  # the context never crosses a rollout boundary
@@ -195,7 +226,7 @@ def main(dist: Distributed, cfg: Config) -> None:
         with telem.span("Time/env_interaction_time", env_steps=policy_steps_per_iter):
             for t in range(rollout_steps):
                 tokens = tokens_of(obs)
-                with telem.span("Player/act", tokens=num_envs, cache_rows=num_envs * (t + 1)):
+                with telem.span("Player/act", tokens=num_envs * L, cache_rows=num_envs * L * (t + 1), forwards=S):
                     actions, carry = act(mirror.current(), carry, tokens, is_first, rollout_key)
                     np_actions = np.asarray(actions)
                 with telem.span("Player/env_step"):
@@ -207,7 +238,7 @@ def main(dist: Distributed, cfg: Config) -> None:
                     cut = np.array(truncated, bool)
                     final = tokens_of(obs)
                     if np.any(cut) and "final_obs" in info:
-                        final[cut] = [int(np.asarray(info["final_obs"][i][module.token_key]).reshape(-1)[0]) for i in np.nonzero(cut)[0]]
+                        final[cut] = [np.asarray(info["final_obs"][i][module.token_key]).reshape(token_shape) for i in np.nonzero(cut)[0]]
                     if t == rollout_steps - 1:  # an episode still running is truncated where the context is cut
                         cut |= ~dones
                         dones = np.ones(num_envs, bool)
@@ -221,12 +252,12 @@ def main(dist: Distributed, cfg: Config) -> None:
                         aggregator.update("Rewards/rew_avg", ep_rew)
                         aggregator.update("Game/ep_len_avg", ep_len)
 
-        with telem.span("Time/train_time", grad_steps=grad_steps, burst=update_iter, tokens=policy_steps_per_iter):
-            host = {k: np.stack(v) for k, v in rows.items()}  # [T, envs]
+        with telem.span("Time/train_time", grad_steps=grad_steps, burst=update_iter, tokens=policy_steps_per_iter * L):
+            host = {k: np.stack(v) for k, v in rows.items()}  # [T, envs, ...]
             returns, advantages = gae_fn(host["rewards"], carry["values"], host["dones"], jnp.zeros((num_envs,)))
-            data = {k: jax.device_put(host[k].T, dist.batch_sharding) for k in host}
-            data.update({k: jax.device_put(v.T, dist.batch_sharding) for k, v in
-                         (("logprobs", carry["logprobs"]), ("values", carry["values"]), ("returns", returns), ("advantages", advantages))})
+            data = {k: jax.device_put(np.swapaxes(host[k], 0, 1), dist.batch_sharding) for k in host}
+            data.update({k: jax.device_put(jnp.swapaxes(v, 0, 1), dist.batch_sharding) for k, v in
+                         [(k, carry[k]) for k in policy.STORED] + [("returns", returns), ("advantages", advantages)]})
             coefs = update_coefs(cfg, update_iter, run.num_updates)
             root_key, up_key = jax.random.split(root_key)
             params, opt_state, metrics, report = update(params, opt_state, data, coefs, up_key)

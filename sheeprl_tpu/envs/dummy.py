@@ -129,3 +129,37 @@ class TokenDummyEnv(gym.Env):
 
     def close(self):
         pass
+
+
+class TokenBlockDummyEnv(gym.Env):
+    """A block of `block` token ids a step: the observation is the agent's last action block echoed (ids 0 after a
+    reset), the action a block of ids of `MultiDiscrete([vocab] x block)`, episodes of `n_steps` steps, reward 1 at
+    the last step if the episode's ids were not all alike. What a block-diffusion policy (`exp=ppo_recurrent_sdar`)
+    acts on where no text environment is installed."""
+
+    def __init__(self, vocab: int = 16, block: int = 4, n_steps: int = 8):
+        self.observation_space = gym.spaces.Dict({"block": gym.spaces.Box(0, vocab - 1, (int(block),), np.int32)})
+        self.action_space = gym.spaces.MultiDiscrete([int(vocab)] * int(block))
+        self._block, self._n_steps = int(block), int(n_steps)
+        self._seen: List[int] = []
+        self._steps = 0
+        self.render_mode = "rgb_array"
+
+    def reset(self, seed: Optional[int] = None, options: Optional[dict] = None):
+        super().reset(seed=seed)
+        self._seen, self._steps = [], 0
+        return {"block": np.zeros((self._block,), np.int32)}, {}
+
+    def step(self, action: Any):
+        ids = np.asarray(action, np.int32).reshape(self._block)
+        self._seen += ids.tolist()
+        self._steps += 1
+        done = self._steps >= self._n_steps
+        reward = float(done and len(set(self._seen)) > 1)
+        return {"block": ids}, reward, done, False, {}
+
+    def render(self):
+        return np.zeros((64, 64, 3), dtype=np.uint8)
+
+    def close(self):
+        pass

@@ -1,6 +1,9 @@
 """Blocks of a sequence policy: latent attention in its two forms, a routed
-expert layer that is told which experts it holds, manifold-constrained
-hyper-connections, RMSNorm and YaRN rotary embeddings.
+expert layer that is told which experts it holds (sigmoid or softmax routing,
+with or without a shared expert), manifold-constrained hyper-connections,
+RMSNorm and YaRN or plain rotary embeddings. Grouped-query attention and the
+block-diffusion forms are in `models/block_diffusion.py`, which takes the
+expert layer, the norms and the rotary from here.
 
 Plain functions over a nested dict of parameters (a leaf is `kernel`, `scale`,
 `embedding` or a named vector), so that one tree serves the update's full
@@ -35,31 +38,12 @@ NEG = -1e30  # a masked score
 
 class SequenceConfig(NamedTuple):
     hidden_size: int
-    q_lora_rank: int
-    kv_lora_rank: int
-    qk_nope_head_dim: int
-    qk_rope_head_dim: int
-    v_head_dim: int
     num_attention_heads: int
-    intermediate_size: int
     moe_intermediate_size: int
     n_routed_experts: int
     num_experts_per_tok: int
-    n_shared_experts: int
-    routed_scaling_factor: float
-    first_k_dense_replace: int
     num_hidden_layers: int
-    hc_mult: int
-    hc_sinkhorn_iters: int
-    hc_eps: float
-    mhc_h_res_clamp_min: float
-    mhc_h_res_clamp_max: float
     rope_theta: float
-    rope_factor: float
-    rope_beta_fast: float
-    rope_beta_slow: float
-    rope_mscale_all_dim: float
-    rope_original_max_position_embeddings: int
     vocab_size: int
     rms_norm_eps: float
     # the share of one chip
@@ -67,24 +51,60 @@ class SequenceConfig(NamedTuple):
     first_expert: int
     heads_held: int
     vocab_held: int
+    # what a model without the key does not have: latent attention's ranks, dense layers, a shared expert,
+    # hyper-connections (1 stream is the plain residual), YaRN (factor 1 is plain rotary)
+    attention: str = "mla"  # or "gqa": `num_key_value_heads` heads of `head_dim`, q/k RMSNorm per head
+    num_key_value_heads: int = 0
+    head_dim: int = 0
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    intermediate_size: int = 0
+    n_shared_experts: int = 0
+    scoring_func: str = "sigmoid"  # the router's: sigmoid with a bias to choose by (noaux_tc), or softmax
+    routed_scaling_factor: float = 1.0
+    first_k_dense_replace: int = 0
+    hc_mult: int = 1
+    hc_sinkhorn_iters: int = 0
+    hc_eps: float = 1e-6
+    mhc_h_res_clamp_min: float = 0.0
+    mhc_h_res_clamp_max: float = 0.0
+    rope_factor: float = 1.0
+    rope_beta_fast: float = 0.0
+    rope_beta_slow: float = 0.0
+    rope_mscale_all_dim: float = 0.0
+    rope_original_max_position_embeddings: int = 0
 
     @classmethod
     def from_node(cls, node: Any) -> "SequenceConfig":
-        """From the recipe's `algo.backbone` node: the published keys under their published names."""
-        rope = node["rope_scaling"]
-        kw = {f: node[f] for f in cls._fields if not f.startswith("rope_") or f == "rope_theta"}
-        kw.update({f"rope_{k}": rope[k] for k in ("factor", "beta_fast", "beta_slow", "mscale_all_dim", "original_max_position_embeddings")})
+        """From the recipe's `algo.backbone` node: the published keys under their published names; a key the model
+        does not publish takes its field's default. `rope_scaling: null` is plain rotary."""
+        rope = node["rope_scaling"] or {}
+        if not node.get("norm_topk_prob", True):
+            raise ValueError("the router's weights are the chosen experts' scores normalised over the chosen (norm_topk_prob)")
+        kw = {f: node.get(f, cls._field_defaults.get(f)) for f in cls._fields if not f.startswith("rope_") or f == "rope_theta"}
+        kw.update({f"rope_{k}": rope.get(k, cls._field_defaults[f"rope_{k}"])
+                   for k in ("factor", "beta_fast", "beta_slow", "mscale_all_dim", "original_max_position_embeddings")})
         types = get_type_hints(cls)
         cfg = cls(**{f: types[f](kw[f]) for f in cls._fields})
         if not (0 <= cfg.first_expert and cfg.first_expert + cfg.experts_held <= cfg.n_routed_experts):
             raise ValueError(f"experts {cfg.first_expert}..{cfg.first_expert + cfg.experts_held} are not among {cfg.n_routed_experts}")
         if not (0 < cfg.heads_held <= cfg.num_attention_heads and 0 < cfg.vocab_held <= cfg.vocab_size):
             raise ValueError("heads_held and vocab_held are shares of num_attention_heads and vocab_size")
+        if cfg.attention not in ("mla", "gqa") or cfg.scoring_func not in ("sigmoid", "softmax"):
+            raise ValueError(f"attention is mla or gqa, scoring_func sigmoid or softmax: got {cfg.attention}, {cfg.scoring_func}")
         return cfg
 
     @property
     def qk_head_dim(self) -> int:
-        return self.qk_nope_head_dim + self.qk_rope_head_dim
+        return self.head_dim if self.attention == "gqa" else self.qk_nope_head_dim + self.qk_rope_head_dim
+
+    @property
+    def rope_dim(self) -> int:
+        """The rotated part of a query or key head: the whole head in grouped-query attention."""
+        return self.head_dim if self.attention == "gqa" else self.qk_rope_head_dim
 
     @property
     def score_scale(self) -> float:
@@ -145,13 +165,22 @@ def init_params(cfg: SequenceConfig, key: jax.Array) -> Params:
         if cfg.is_dense(i):
             layer["mlp"] = gated(cfg.intermediate_size)
         else:
-            layer["moe"] = {
-                "router": kernel(C, cfg.n_routed_experts), "e_bias": jnp.zeros((cfg.n_routed_experts,)),
-                "experts": gated(cfg.moe_intermediate_size, cfg.experts_held),
-                "shared": gated(cfg.moe_intermediate_size * cfg.n_shared_experts),
-            }
+            layer["moe"] = expert_layer(cfg, kernel, gated)
         params[f"layer_{i}"] = layer
     return params
+
+
+def expert_layer(cfg: SequenceConfig, kernel, gated) -> Params:
+    """An expert layer's leaves: the router over all `n_routed_experts`, the held experts, the bias that sigmoid
+    routing chooses by, and the shared expert where the model has one."""
+    C = cfg.hidden_size
+    p = {"router": kernel(C, cfg.n_routed_experts)}
+    if cfg.scoring_func == "sigmoid":
+        p["e_bias"] = jnp.zeros((cfg.n_routed_experts,))
+    p["experts"] = gated(cfg.moe_intermediate_size, cfg.experts_held)
+    if cfg.n_shared_experts:
+        p["shared"] = gated(cfg.moe_intermediate_size * cfg.n_shared_experts)
+    return p
 
 
 # -- norms and rotary ----------------------------------------------------------------
@@ -163,7 +192,7 @@ def rms_norm(x: jax.Array, scale: Any, eps: float) -> jax.Array:
 def yarn_inv_freq(cfg: SequenceConfig) -> np.ndarray:
     """Rotary frequencies, blended (YaRN) between the trained ones, where a dimension turns more than `beta_fast`
     times over the original context, and those divided by `factor`, where it turns fewer than `beta_slow` times."""
-    d, base = cfg.qk_rope_head_dim, cfg.rope_theta
+    d, base = cfg.rope_dim, cfg.rope_theta
     trained = 1.0 / base ** (np.arange(0, d, 2, dtype=np.float64) / d)
     if cfg.rope_factor <= 1:
         return trained.astype(np.float32)
@@ -275,10 +304,15 @@ def gated_mlp(p: Params, u: jax.Array) -> jax.Array:
 
 
 def route(p: Params, u: jax.Array, cfg: SequenceConfig) -> Tuple[jax.Array, jax.Array]:
-    """(chosen experts [R, k] among all `n_routed_experts`, their weights [R, k]): sigmoid scores, the choice by
-    score plus a bias that gets no gradient, the weights the chosen scores normalised and scaled."""
-    s = jax.nn.sigmoid(u @ p["router"]["kernel"])
-    _, idx = jax.lax.top_k(s + jax.lax.stop_gradient(p["e_bias"]), cfg.num_experts_per_tok)
+    """(chosen experts [R, k] among all `n_routed_experts`, their weights [R, k]): `sigmoid` scores and the choice by
+    score plus a bias that gets no gradient, or `softmax` scores over all experts and the choice by score; the
+    weights are the chosen scores normalised and scaled."""
+    if cfg.scoring_func == "softmax":
+        s = jax.nn.softmax(u @ p["router"]["kernel"], -1)
+        _, idx = jax.lax.top_k(s, cfg.num_experts_per_tok)
+    else:
+        s = jax.nn.sigmoid(u @ p["router"]["kernel"])
+        _, idx = jax.lax.top_k(s + jax.lax.stop_gradient(p["e_bias"]), cfg.num_experts_per_tok)
     sk = jnp.take_along_axis(s, idx, -1)
     return idx, sk / (jnp.sum(sk, -1, keepdims=True) + 1e-20) * cfg.routed_scaling_factor
 
@@ -325,7 +359,7 @@ def moe(p: Params, u: jax.Array, cfg: SequenceConfig, choices: bool = False, gro
         routed = jnp.sum(sizes)
         with jax.named_scope("experts"):  # one plain product per expert and kernel: a batched one came out a tenth slower on the chip
             routed_out = sum(weight[:, i:i + 1] * gated_mlp(jax.tree.map(lambda x: x[i], e), u) for i in range(E))
-    out = routed_out + gated_mlp(p["shared"], u)
+    out = routed_out + gated_mlp(p["shared"], u) if "shared" in p else routed_out
     load = {"routed_here": routed, "rows": jnp.asarray(M, jnp.int32), "dropped": jnp.sum(here) - routed,
             "max_over_mean": jnp.max(sizes) * E / jnp.maximum(routed, 1).astype(jnp.float32)}
     if choices:  # for a comparison of routings: the chosen experts of every token, sorted

@@ -442,8 +442,9 @@ EVENT_SCHEMAS: Dict[str, Dict[str, Tuple[bool, type]]] = {
     },
     # a sequence policy on the recurrent on-policy loop, once at set-up
     # (algos/ppo_recurrent/sequence_policy.py): the share of each layer this
-    # chip holds, the bytes of the per-env latent cache and of the parameters,
-    # and the major-to-minor order the cache is held in on the device
+    # chip holds, the bytes of the per-env cache and of the parameters, and
+    # per leaf of the cache (the latents; or the keys and values) the
+    # major-to-minor order it is held in on the device
     "sequence_policy": {
         "backbone": (True, _STR),
         "layers": (True, _NUM),
@@ -456,7 +457,19 @@ EVENT_SCHEMAS: Dict[str, Dict[str, Tuple[bool, type]]] = {
         "vocab_size": (True, _NUM),
         "cache_bytes": (True, _NUM),
         "param_bytes": (True, _NUM),
-        "cache_layout": (True, list),
+        "cache_layout": (True, _DICT),
+    },
+    # a block-diffusion policy on the same loop, once at set-up
+    # (algos/ppo_recurrent/block_diffusion_policy.py): the ids an env step
+    # commits, the forwards it takes for them, the ids a forward commits for
+    # all envs, the rows one gradient step's forward carries (the clean
+    # sequences and their denoising copies), and the id that stands for a mask
+    "block_diffusion": {
+        "block_length": (True, _NUM),
+        "denoising_steps": (True, _NUM),
+        "tokens_per_forward": (True, _NUM),
+        "update_tokens": (True, _NUM),
+        "mask_id": (True, _NUM),
     },
     # the expert layers' load over one train call, from numbers the update
     # returns beside its losses: (token, expert) pairs computed here, the
@@ -751,7 +764,7 @@ SPAN_SCHEMAS: Dict[str, Tuple[str, ...]] = {
     "Time/cache_reset": ("rows",),
     "Wait/learner_queue": ("packets",),
     "Wait/player_queue": (),
-    "Player/act": ("tokens", "cache_rows"),
+    "Player/act": ("tokens", "cache_rows", "forwards"),
     "Player/env_step": (),
     "Player/record": (),
 }

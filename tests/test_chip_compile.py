@@ -298,3 +298,53 @@ def test_decode_step_compiles_for_v5e_with_the_cache_updated_in_place(one_chip, 
     assert _whole_cache_copies(compiled, carry) == []
     if program != "value_fn":
         assert compiled.memory_analysis().alias_size_in_bytes >= cache_bytes  # the donated cache comes back in place
+
+
+# -- the block-diffusion policy's env step: the K/V cache held in the order its products take it ----
+# the cell's attention widths (32 query and 4 KV heads of 128), 32 envs, 128 blocks of 4; the rest cut so that one
+# compile takes seconds
+BD_CUT = [
+    "exp=ppo_recurrent_sdar", "algo.backbone.hidden_size=256", "algo.backbone.num_hidden_layers=2", "algo.backbone.n_routed_experts=16",
+    "algo.backbone.experts_held=4", "algo.backbone.num_experts_per_tok=2", "algo.backbone.moe_intermediate_size=256",
+    "algo.backbone.vocab_size=1024",
+]
+
+
+@pytest.fixture(scope="module")
+def block_carry(one_chip):
+    """(module, params, the carry on the chip, the cache's bytes)."""
+    from sheeprl_tpu.algos.ppo_recurrent import block_diffusion_policy as bdp
+    from sheeprl_tpu.algos.ppo_recurrent.agent import BlockDiffusionPolicy
+    from sheeprl_tpu.config import compose
+    from sheeprl_tpu.models import block_diffusion as bd
+    from sheeprl_tpu.models import sequence as seq
+
+    scfg = seq.SequenceConfig.from_node(compose("config", BD_CUT).algo.backbone)
+    assert (scfg.num_attention_heads, scfg.num_key_value_heads, scfg.head_dim) == (32, 4, 128)
+    module = BlockDiffusionPolicy(scfg, "block", 4, 2)
+    params = _like(one_chip, jax.eval_shape(lambda k: bd.init_params(scfg, k), jax.random.key(0)))
+    state = _like(one_chip, jax.eval_shape(lambda: bdp.new_state(module, SEQ_ENVS, SEQ_CAPACITY // 4)))
+    return module, params, state, 2 * int(np.prod(state["cache"]["k"].shape)) * 4
+
+
+@pytest.mark.parametrize("program", ["act", "restart", "value_fn"])
+def test_block_diffusion_step_compiles_for_v5e_with_the_cache_updated_in_place(one_chip, block_carry, program):
+    """Held `[.., capacity, KV x 128]`, the K/V cache was relaid whole out and back around every env step (four
+    copies, keys and values); held `[.., KV, capacity, 128]`, as the attention's products take it, none."""
+    from sheeprl_tpu.algos.ppo_recurrent import block_diffusion_policy as bdp
+    from sheeprl_tpu.algos.ppo_recurrent import sequence_policy as sp
+
+    module, params, carry, cache_bytes = block_carry
+    blocks = _sds(one_chip, (SEQ_ENVS, 4), jnp.int32)
+    if program == "act":
+        key = _like(one_chip, jax.eval_shape(lambda: jax.random.key(0)))
+        lowered = bdp.make_act_fn(module).lower(params, carry, blocks, _sds(one_chip, (SEQ_ENVS,), jnp.bool_), key)
+    elif program == "restart":
+        lowered = sp.restart.lower(carry)
+    else:
+        lowered = bdp.make_value_fn(module).lower(params, carry, blocks)
+    compiled = lowered.compile()
+    shape = "f32[%s]" % ",".join(map(str, carry["cache"]["k"].shape))
+    assert [line for line in compiled.as_text().splitlines() if " copy(" in line and shape + "{" in line] == []
+    if program != "value_fn":
+        assert compiled.memory_analysis().alias_size_in_bytes >= cache_bytes  # the donated caches come back in place

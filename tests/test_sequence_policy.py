@@ -296,13 +296,13 @@ def test_the_recipe_trains_through_cli_run_with_every_new_span_and_event(tiny_ru
     assert all(e["rows"] == 2 * 4 * 32 * 2 and e["routed_here"] == round(e["slot_occupancy"] * e["rows"]) for e in loads)
     share = next(e for e in events if e["event"] == "sequence_policy")
     assert (share["experts_held"], share["first_expert"], share["heads_held"], share["vocab_held"], share["layers"]) == (2, 2, 2, 32, 3)
-    assert share["cache_bytes"] == 3 * 4 * 16 * 128 * 4 + 4 + 4 * 4 and share["cache_layout"] == [0, 1, 2, 3]  # 24 columns held in 128
+    assert share["cache_bytes"] == 3 * 4 * 16 * 128 * 4 + 4 + 4 * 4 and share["cache_layout"] == {"latents": [0, 1, 2, 3]}  # 24 columns held in 128
     placement = next(e for e in events if e["event"] == "placement")
     assert placement["same_device"] == 1 and placement["refresh"] == "alias"
     spans = set().union(*(e["spans"] for e in events if e["event"] in ("log", "shutdown")))
     assert {"Time/cache_reset", "Player/act", "Player/env_step", "Player/record", "Time/env_interaction_time", "Time/train_time",
             "Time/param_refresh"} <= spans <= set(SPAN_SCHEMAS)
-    assert SPAN_SCHEMAS["Player/act"] == ("tokens", "cache_rows") and "tokens" in SPAN_SCHEMAS["Time/train_time"]
+    assert SPAN_SCHEMAS["Player/act"] == ("tokens", "cache_rows", "forwards") and "tokens" in SPAN_SCHEMAS["Time/train_time"]
     logs = [e for e in events if e["event"] == "log"]
     assert len(logs) == 2 and all(np.isfinite(e["metrics"]["Loss/policy_loss"]) for e in logs)
 
