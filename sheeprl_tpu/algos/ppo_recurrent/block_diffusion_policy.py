@@ -96,13 +96,15 @@ def make_value_fn(module: BlockDiffusionPolicy):
     return value_fn
 
 
-def make_update_fn(module: BlockDiffusionPolicy, tx, cfg: Config, num_minibatches: int, mb_size: int):
+def make_update_fn(module: BlockDiffusionPolicy, tx, cfg: Config, num_minibatches: int, mb_size: int, attention: str = "auto"):
     """The update: for each minibatch's `[b, T]` blocks one forward over the clean sequence and the `S` copies of every
-    action block; a block's log-probability and entropy are the sums over its positions, each from its copy."""
+    action block; a block's log-probability and entropy are the sums over its positions, each from its copy. The
+    attention's form (`bd.resolve_train_attention`) is resolved here, once."""
+    attention = bd.resolve_train_attention(attention)
 
     def loss_fn(params, mb: Dict[str, jax.Array], coefs: Dict[str, jax.Array]):
         logits, new_values, load = bd.forward_train(params, mb["tokens"], mb["is_first"], mb["actions"], mb["order"], module.cfg,
-                                                    module.denoising_steps, module.mask_id)
+                                                    module.denoising_steps, module.mask_id, attention=attention)
         with jax.named_scope("loss"):
             logp = jax.nn.log_softmax(logits)
             new_logprobs = jnp.sum(jnp.take_along_axis(logp, mb["actions"][..., None], -1)[..., 0], -1)
@@ -115,7 +117,12 @@ def make_update_fn(module: BlockDiffusionPolicy, tx, cfg: Config, num_minibatche
 
 def block_event(module: BlockDiffusionPolicy, num_envs: int, blocks: int, mb_size: int) -> Dict[str, Any]:
     """The run's `block_diffusion` event: the block, the forwards an env step takes, the ids a forward commits for
-    all envs, the rows one gradient step's forward carries, and the mask id."""
+    all envs, the rows one gradient step's forward carries, the mask id, the update's attention form and, for the
+    kernel, the share of its tiles it computes."""
     L, S = module.block_length, module.denoising_steps
-    return {"event": "block_diffusion", "block_length": L, "denoising_steps": S, "tokens_per_forward": num_envs * L // S,
-            "update_tokens": mb_size * blocks * L * (1 + S), "mask_id": module.mask_id}
+    attention = bd.resolve_train_attention()
+    event = {"event": "block_diffusion", "block_length": L, "denoising_steps": S, "tokens_per_forward": num_envs * L // S,
+             "update_tokens": mb_size * blocks * L * (1 + S), "mask_id": module.mask_id, "train_attention": attention}
+    if attention == "kernel":
+        event["attn_blocks_computed"] = bd.attn_blocks_computed(blocks, L, S)
+    return event

@@ -463,13 +463,18 @@ EVENT_SCHEMAS: Dict[str, Dict[str, Tuple[bool, type]]] = {
     # (algos/ppo_recurrent/block_diffusion_policy.py): the ids an env step
     # commits, the forwards it takes for them, the ids a forward commits for
     # all envs, the rows one gradient step's forward carries (the clean
-    # sequences and their denoising copies), and the id that stands for a mask
+    # sequences and their denoising copies), the id that stands for a mask,
+    # the form of the update's attention (`kernel` on a TPU, `xla` elsewhere)
+    # and, with the kernel, the share of its (row tile, key tile) pairs the
+    # static mask leaves non-empty for one sequence
     "block_diffusion": {
         "block_length": (True, _NUM),
         "denoising_steps": (True, _NUM),
         "tokens_per_forward": (True, _NUM),
         "update_tokens": (True, _NUM),
         "mask_id": (True, _NUM),
+        "train_attention": (True, _STR),
+        "attn_blocks_computed": (False, _NUM),
     },
     # the expert layers' load over one train call, from numbers the update
     # returns beside its losses: (token, expert) pairs computed here, the

@@ -232,6 +232,48 @@ def test_the_later_blocks_never_see_a_copy(small):
     assert np.array_equal(np.asarray(lg[:, 2:]), np.asarray(logits[:, 2:])) and np.array_equal(np.asarray(v), np.asarray(values))
 
 
+def test_the_kernel_form_of_the_updates_attention_gives_the_xla_forms_values_and_gradients(small):
+    """`train_attention_kernel` through the splash kernel's interpreter against `train_attention`, on the output and
+    on the gradients of q, k and v: 8 query heads over 2 KV heads of 128, T 8 blocks of L 4 and S 2 copies (96 rows,
+    padded to 128), sequence 0 one episode from its first block, sequence 1 a second episode from block 5. Both are
+    float32 on the CPU; they differ by the order of the sums."""
+    b, t, l, s, h, kv, d = 2, 8, 4, 2, 8, 2, 128
+    rows = (1 + s) * t * l
+    cfg = small[1].cfg._replace(head_dim=d)  # the scale reads the head's width alone
+    k = jax.random.split(jax.random.key(21), 4)
+    q, key, val = (jax.random.normal(k[0], (b, rows, h, d)), jax.random.normal(k[1], (b, rows, kv, d)), jax.random.normal(k[2], (b, rows, kv, d)))
+    w = jax.random.normal(k[3], (b, rows, h * d))
+    episode = jnp.cumsum(jnp.zeros((b, t), bool).at[:, 0].set(True).at[1, 5].set(True), 1)
+    block = jnp.arange(t)
+    sees, seen = jnp.concatenate([jnp.repeat(block, l), jnp.repeat(block, s * l)]), jnp.repeat(block, l)
+    mask = (seen[None, None, :] <= sees[None, :, None]) & (episode[:, sees][:, :, None] == episode[:, seen][:, None, :])
+    forms = {"xla": lambda q, k, v: bd.train_attention(q, k, v, mask, t * l, l, cfg),
+             "kernel": lambda q, k, v: bd.train_attention_kernel(q, k, v, episode[:, sees], t, l, s, cfg, interpret=True)}
+    got = {n: jax.jit(jax.value_and_grad(lambda q, k, v: jnp.sum(f(q, k, v) * w), (0, 1, 2)))(q, key, val) for n, f in forms.items()}
+    outs = {n: jax.jit(f)(q, key, val) for n, f in forms.items()}
+    close(outs["kernel"], outs["xla"], 1e-5)
+    for g_kernel, g_xla in zip(got["kernel"][1], got["xla"][1]):
+        close(g_kernel, g_xla, 1e-5)
+
+
+def test_the_kernels_mask_is_the_two_parts_and_the_resolver_keeps_the_xla_form_on_the_cpu():
+    """`kernel_mask` over the real rows is the clean part's block-causal mask beside each copy's own block (the
+    episode is the segment ids'); a pad row sees itself alone. Off a TPU `auto` resolves to the XLA form."""
+    t, l, s = 8, 4, 2
+    m = bd.kernel_mask(t, l, s)
+    clean, rows = t * l, (1 + s) * t * l
+    assert m.shape == (128, 128) and bd.kernel_rows(t, l, s) == 128
+    sees = np.concatenate([np.repeat(np.arange(t), l), np.repeat(np.arange(t), s * l)])
+    assert np.array_equal(m[:rows, :clean], np.arange(clean)[None, :] // l <= sees[:, None])
+    copies = np.kron(np.eye(s * t, dtype=bool), np.ones((l, l), bool))
+    assert np.array_equal(m[:rows, clean:rows], np.vstack([np.zeros((clean, s * t * l), bool), copies]))
+    assert np.array_equal(m[rows:], np.eye(128, dtype=bool)[rows:]) and not m[:rows, rows:].any()
+    assert bd.attn_blocks_computed(t, l, s) == 1.0  # one tile of 128 rows
+    assert bd.resolve_train_attention() == "xla" and bd.resolve_train_attention("kernel") == "kernel"
+    with pytest.raises(ValueError, match="auto"):
+        bd.resolve_train_attention("splash")
+
+
 @pytest.mark.parametrize("grouped", [True, False], ids=["grouped", "acting_form"])
 def test_the_expert_shares_add_up_to_the_whole_layer_with_softmax_routing_and_no_shared_expert(small, grouped):
     _, module, sz, shapes, _ = small
@@ -287,6 +329,7 @@ def test_the_recipe_trains_through_cli_run_with_its_events(tiny_run):
     blocks = next(e for e in events if e["event"] == "block_diffusion")
     # 3 envs x 4 ids / 2 steps a forward; one minibatch of 3 sequences x 4 blocks x 4 ids x (1 clean + 2 copies)
     assert [blocks[k] for k in ("block_length", "denoising_steps", "tokens_per_forward", "update_tokens", "mask_id")] == [4, 2, 6, 144, 96]
+    assert blocks["train_attention"] == "xla" and "attn_blocks_computed" not in blocks  # the kernel runs on a TPU alone
     share = next(e for e in events if e["event"] == "sequence_policy")
     assert share["cache_layout"] == {"k": [0, 1, 2, 3, 4], "v": [0, 1, 2, 3, 4]} and share["cache_bytes"] == 2 * 2 * 3 * 16 * 32 * 4 + 4 + 3 * 4
     loads = [e for e in events if e["event"] == "moe_load"]

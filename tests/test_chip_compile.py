@@ -15,6 +15,7 @@ only one process at a time may load the TPU's library, so it must happen in
 the one xdist worker that runs this file, after collection, never at import.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -348,3 +349,48 @@ def test_block_diffusion_step_compiles_for_v5e_with_the_cache_updated_in_place(o
     assert [line for line in compiled.as_text().splitlines() if " copy(" in line and shape + "{" in line] == []
     if program != "value_fn":
         assert compiled.memory_analysis().alias_size_in_bytes >= cache_bytes  # the donated caches come back in place
+
+
+# the update at the cell's shapes: minibatches of 4 sequences of 128 blocks, 512 clean rows and 1024 copy rows
+BD_SEQS, BD_BLOCKS, BD_CLEAN, BD_ROWS = 4, 128, 512, 1536
+
+
+def _instructions(text):
+    """The compiled program's instructions, each whole: a kernel's metadata can span several lines."""
+    return re.split(r"\n\s*(?:ROOT )?%", text)
+
+
+@pytest.mark.parametrize("form", ["kernel", "xla"])
+def test_block_diffusion_update_compiles_for_v5e_with_the_attention_as_one_kernel(one_chip, block_carry, form):
+    """`jit_update` at the cell's attention widths and rows. With the kernel (what `auto` resolves to on a TPU) no f32
+    tensor of scores, rows against clean keys, is left in the program, and every kernel's `op_name` carries the
+    scope `bd_attn`, by which the benchmark books it; a forward kernel for the forward and for remat's recompute and
+    one backward kernel (dq beside dk and dv) in each layer. The XLA form is the control: it holds such tensors."""
+    from sheeprl_tpu.algos.ppo_recurrent import block_diffusion_policy as bdp
+    from sheeprl_tpu.config import compose, instantiate
+    from sheeprl_tpu.optim import clipped
+
+    module, params, _, _ = block_carry
+    cfg = compose("config", BD_CUT)
+    tx = clipped(instantiate(cfg.algo.optimizer), cfg.algo.get("max_grad_norm", 0.0))
+    L = module.block_length
+    data = {k: _sds(one_chip, (SEQ_ENVS, BD_BLOCKS, L), jnp.int32) for k in ("tokens", "actions", "order")}
+    data["is_first"] = _sds(one_chip, (SEQ_ENVS, BD_BLOCKS), jnp.bool_)
+    data.update({k: _sds(one_chip, (SEQ_ENVS, BD_BLOCKS)) for k in ("logprobs", "values", "returns", "advantages", "rewards", "dones")})
+    coefs = {k: _sds(one_chip, ()) for k in ("clip_coef", "ent_coef", "vf_coef", "lr_frac")}
+    key = _like(one_chip, jax.eval_shape(lambda: jax.random.key(0)))
+    update = bdp.make_update_fn(module, tx, cfg, SEQ_ENVS // BD_SEQS, BD_SEQS, attention=form)
+    text = update.lower(params, _like(one_chip, jax.eval_shape(tx.init, params)), data, coefs, key).compile().as_text()
+    score = re.compile(r"f32\[(?:\d+,){2,}(?:%d,%d|%d,%d|%d,%d|%d,%d)\]" % (
+        BD_ROWS, BD_CLEAN, BD_CLEAN, BD_ROWS, BD_ROWS - BD_CLEAN, BD_CLEAN, BD_CLEAN, BD_ROWS - BD_CLEAN))
+    scores = [line.strip()[:120] for line in text.splitlines() if score.search(line)]
+    kernels = [i for i in _instructions(text) if 'custom_call_target="tpu_custom_call"' in i and i.startswith("splash_")]
+    if form == "xla":
+        assert scores and not kernels
+        return
+    assert scores == []
+    layers = module.cfg.num_hidden_layers
+    assert [sum(i.startswith(f"splash_mqa_{p}_") for i in kernels) for p in ("fwd", "dkv")] == [2 * layers, layers]
+    op_names = [re.findall(r'metadata=\{op_name="([^"]*)"', i) for i in kernels]
+    # a component of the name, as `span_reduce.part_of` reads it: autodiff wraps it (`jvp(bd_attn)`)
+    assert all(len(n) == 1 and re.search(r"[/(]bd_attn[)/]", n[0]) for n in op_names), op_names
