@@ -49,6 +49,7 @@ from ...parallel import Distributed
 from ...parallel.mesh import maybe_shard_opt_state, maybe_shard_params
 from ...parallel.placement import make_param_mirror, player_device, read_subtree
 from ...telemetry import Telemetry
+from ...telemetry.spans import call_counts
 from ...telemetry import xla as _xla
 from ...utils.checkpoint import CheckpointManager
 from ...utils.env import episode_stats, patch_restarted_envs, probe_env_spaces, vectorize
@@ -722,6 +723,7 @@ def main(dist: Distributed, cfg: Config) -> None:
     _t0 = time.perf_counter()
 
     p_step = policy_step  # player-side env-step counter (== policy_step with the inline source)
+    act_call: Dict[str, int] = {}  # `Player/dispatch`'s counts, the same for every act: counted at the first
 
     def interact(sink) -> None:
         """ONE vector env step (the reference train() env block): act from
@@ -741,13 +743,18 @@ def main(dist: Distributed, cfg: Config) -> None:
                 actions_np = np.concatenate(oh, axis=-1)
         else:
             with telem.span("Player/act"):
-                host_obs = prepare_obs(obs, cnn_keys, mlp_keys, num_envs)
-                env_actions, actions_cat, player_state, player_key = player_step_fn(
-                    mirror.current(), host_obs, player_state, player_key,
-                    action_mask=extract_masks(obs, num_envs),
-                )
-                actions_np = np.asarray(actions_cat)
-                actions_env = np.asarray(env_actions)
+                with telem.span("Player/prepare"):
+                    host_obs = prepare_obs(obs, cnn_keys, mlp_keys, num_envs)
+                    masks = extract_masks(obs, num_envs)
+                    if not act_call:
+                        act_call.update(call_counts(mirror.params, host_obs, player_state, player_key, masks))
+                with telem.span("Player/dispatch", leaves=act_call["leaves"], h2d_bytes=act_call["h2d_bytes"]):
+                    env_actions, actions_cat, player_state, player_key = player_step_fn(
+                        mirror.current(), host_obs, player_state, player_key, action_mask=masks
+                    )
+                with telem.span("Player/fetch", arrays=2, d2h_bytes=actions_cat.nbytes + env_actions.nbytes):
+                    actions_np = np.asarray(actions_cat)
+                    actions_env = np.asarray(env_actions)
             if is_continuous:
                 actions_env = actions_env.reshape(num_envs, -1)
             elif not is_multidiscrete:

@@ -40,6 +40,7 @@ from ...ops import gae as gae_op
 from ...optim import clipped
 from ...parallel import Distributed
 from ...parallel.placement import make_param_mirror, tree_bytes
+from ...telemetry.spans import call_counts
 from ...utils.env import episode_stats
 from ..ppo.loss import entropy_loss, policy_loss, value_loss
 from .agent import BlockDiffusionPolicy, SequencePolicy, build_agent
@@ -213,6 +214,8 @@ def main(dist: Distributed, cfg: Config) -> None:
     def learner():
         return params, opt_state, root_key
 
+    act_call: Dict[str, int] = {}  # `Player/dispatch`'s counts, the same for every act: counted at the first
+
     def tokens_of(o: Dict[str, np.ndarray]) -> np.ndarray:
         return np.asarray(o[module.token_key], np.int32).reshape(num_envs, *token_shape)
 
@@ -225,10 +228,16 @@ def main(dist: Distributed, cfg: Config) -> None:
         player_key, rollout_key = jax.random.split(player_key)
         with telem.span("Time/env_interaction_time", env_steps=policy_steps_per_iter):
             for t in range(rollout_steps):
-                tokens = tokens_of(obs)
                 with telem.span("Player/act", tokens=num_envs * L, cache_rows=num_envs * L * (t + 1), forwards=S):
-                    actions, carry = act(mirror.current(), carry, tokens, is_first, rollout_key)
-                    np_actions = np.asarray(actions)
+                    with telem.span("Player/prepare"):
+                        tokens = tokens_of(obs)
+                        if not act_call:
+                            act_call.update(call_counts(mirror.params, carry, tokens, is_first, rollout_key))
+                    with telem.span("Player/dispatch", leaves=act_call["leaves"], h2d_bytes=act_call["h2d_bytes"]):
+                        # lint: ok[rng-reuse] `call_counts` counts the key as a leaf and does not consume it
+                        actions, carry = act(mirror.current(), carry, tokens, is_first, rollout_key)
+                    with telem.span("Player/fetch", arrays=1, d2h_bytes=actions.nbytes):
+                        np_actions = np.asarray(actions)
                 with telem.span("Player/env_step"):
                     obs, rewards, terminated, truncated, info = envs.step(np_actions)
                 run.policy_step += num_envs
@@ -243,7 +252,8 @@ def main(dist: Distributed, cfg: Config) -> None:
                         cut |= ~dones
                         dones = np.ones(num_envs, bool)
                     if np.any(cut):
-                        rewards[cut] += float(cfg.algo.gamma) * np.asarray(value_fn(mirror.current(), carry, final))[cut]
+                        with telem.span("Player/bootstrap", envs=int(np.sum(cut))):
+                            rewards[cut] += float(cfg.algo.gamma) * np.asarray(value_fn(mirror.current(), carry, final))[cut]
                     for k, v in (("tokens", tokens), ("actions", np_actions.astype(np.int32)), ("rewards", rewards),
                                  ("dones", dones.astype(np.float32)), ("is_first", is_first)):
                         rows[k].append(v)
@@ -264,13 +274,15 @@ def main(dist: Distributed, cfg: Config) -> None:
             telem.record_grad_steps(grad_steps)
             mirror.refresh(params)  # blocking: the next rollout acts with these
 
-        metrics, load = jax.device_get((metrics, report["load"]))  # host-sync: ok (update cadence)
-        for k, v in metrics.items():
-            aggregator.update(k, v)
-        if np.sum(load["rows"]) > 0:
-            telem.emit(moe_load_event(load))
-
-        if run.end_iteration(update_iter, learner):
+        # the update's losses and load, the log and the checkpoint: the host's work between the update and the next rollout
+        with telem.span("Time/update_report"):
+            metrics, load = jax.device_get((metrics, report["load"]))  # host-sync: ok (update cadence)
+            for k, v in metrics.items():
+                aggregator.update(k, v)
+            if np.sum(load["rows"]) > 0:
+                telem.emit(moe_load_event(load))
+            stop = run.end_iteration(update_iter, learner)
+        if stop:
             break
 
     run.close(learner)

@@ -33,9 +33,7 @@ from .throughput import (
 )
 from .xla import (
     RETRACE_DETECTOR,
-    TRANSFER_COUNTER,
     RetraceDetector,
-    TransferCounter,
     compile_counters,
     device_memory_stats,
     instrument,
@@ -72,9 +70,7 @@ __all__ = [
     "peak_flops_for",
     "peak_flops_record",
     "RETRACE_DETECTOR",
-    "TRANSFER_COUNTER",
     "RetraceDetector",
-    "TransferCounter",
     "compile_counters",
     "device_memory_stats",
     "instrument",

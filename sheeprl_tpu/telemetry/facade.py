@@ -218,12 +218,6 @@ class Telemetry:
                 step_fn=lambda: self._last_step,
             ).start()
 
-        self._transfers: Optional[_xla.TransferCounter] = None
-        if self.enabled and bool(sel("metric.telemetry.transfer_counter", True)):
-            self._transfers = _xla.TRANSFER_COUNTER
-            self._transfers.install()
-            self._transfers0 = self._transfers.snapshot()
-
         # step annotation + windowed profiler capture
         self._annotate_steps = self.enabled and bool(sel("metric.telemetry.step_annotation", True))
         self._step_ann: Any = None
@@ -506,10 +500,6 @@ class Telemetry:
         if len(attribution) > self._attr_seen:
             out["retrace_attribution"] = attribution[self._attr_seen :]
             self._attr_seen = len(attribution)
-        if self._transfers is not None:
-            snap = self._transfers.snapshot()
-            out["h2d_calls"] = snap["h2d_calls"] - self._transfers0["h2d_calls"]
-            out["h2d_bytes"] = snap["h2d_bytes"] - self._transfers0["h2d_bytes"]
         return out
 
     # -- the log interval --------------------------------------------------
@@ -610,9 +600,6 @@ class Telemetry:
                     "total_grad_steps": self.throughput.total_grad_steps,
                 }
             )
-        if self._transfers is not None:
-            self._transfers.uninstall()
-            self._transfers = None
         if self._prom_server is not None:
             self._prom_server.stop()
             self._prom_server = None

@@ -755,7 +755,9 @@ EVENT_SCHEMAS: Dict[str, Dict[str, Tuple[bool, type]]] = {
 # span over it on ANY thread: `Time/<phase>` is a phase of the thread that
 # feeds the device and tiles that thread's iteration; `Wait/<what>` is a
 # thread blocked on the other; `Player/<step>` is a sub-step of the player
-# inside `Time/env_interaction_time`. howto/telemetry.md has where each lies.
+# inside `Time/env_interaction_time` (`Player/prepare`, `Player/dispatch` and
+# `Player/fetch` tile `Player/act`; `Player/bootstrap` lies in
+# `Player/record`). howto/telemetry.md has where each lies.
 SPAN_SCHEMAS: Dict[str, Tuple[str, ...]] = {
     "Time/env_interaction_time": ("env_steps", "version"),
     "Time/train_time": ("grad_steps", "burst", "tokens"),
@@ -767,11 +769,16 @@ SPAN_SCHEMAS: Dict[str, Tuple[str, ...]] = {
     "Time/log_flush": (),
     "Time/checkpoint": (),
     "Time/cache_reset": ("rows",),
+    "Time/update_report": (),
     "Wait/learner_queue": ("packets",),
     "Wait/player_queue": (),
     "Player/act": ("tokens", "cache_rows", "forwards"),
+    "Player/prepare": (),
+    "Player/dispatch": ("leaves", "h2d_bytes"),
+    "Player/fetch": ("arrays", "d2h_bytes"),
     "Player/env_step": (),
     "Player/record": (),
+    "Player/bootstrap": ("envs",),
 }
 SPAN_PREFIXES = ("Time/", "Wait/", "Player/")
 

@@ -190,3 +190,15 @@ class Span:
             self.tracker._pop()
             self.tracker.record(self.name, self.elapsed, self.counts)
         return False
+
+
+def call_counts(*args) -> Dict[str, int]:
+    """The counts of a `Player/dispatch` span: the leaves of a jitted call's
+    arguments, and the bytes of those that are host (numpy) arrays, which the
+    call copies to its device. The same for every call of one shape, so a loop
+    counts them once and hands the numbers to every span."""
+    import jax
+    import numpy as np
+
+    leaves = jax.tree.leaves(args)
+    return {"leaves": len(leaves), "h2d_bytes": int(sum(x.nbytes for x in leaves if isinstance(x, np.ndarray)))}

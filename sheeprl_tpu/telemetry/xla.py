@@ -1,7 +1,7 @@
-"""XLA health counters: compiles, retraces (with shape attribution),
-device memory, and host→device transfers.
+"""XLA health counters: compiles, retraces (with shape attribution) and
+device memory.
 
-Three independent mechanisms, each robust on its own:
+Independent mechanisms, each robust on its own:
 
 * **global compile counters** — a `jax.monitoring` duration listener counts
   `/jax/core/compile/backend_compile_duration` events (one per backend
@@ -13,10 +13,6 @@ Three independent mechanisms, each robust on its own:
   (re)trace. It records the abstract shape/dtype signature of every trace
   and, on a retrace, diffs against the previous signature to say *which*
   argument changed shape — the attribution the BENCH rounds were missing.
-* **`TransferCounter`** — counts `jax.device_put` calls and bytes while
-  installed (facade-scoped, refcounted). Dispatch inside jit does not go
-  through `device_put`, so this is specifically the host→device staging
-  traffic the train loops control.
 """
 from __future__ import annotations
 
@@ -240,71 +236,3 @@ def suppress_retrace_accounting():
         yield
     finally:
         _tls.suppress_retraces = False
-
-
-class TransferCounter:
-    """Counts host→device transfers (jax.device_put calls + bytes) while
-    installed. Refcounted so nested facades (decoupled player + trainer)
-    install/uninstall safely; the wrapper is a strict pass-through."""
-
-    def __init__(self) -> None:
-        self._lock = threading.Lock()
-        self._installs = 0
-        self._orig: Optional[Callable] = None
-        self.calls = 0
-        self.bytes = 0
-
-    def _count(self, x: Any) -> None:
-        total = 0
-        try:
-            import jax
-
-            for leaf in jax.tree.leaves(x):
-                total += int(getattr(leaf, "nbytes", 0) or 0)
-        except Exception:
-            pass
-        with self._lock:
-            self.calls += 1
-            self.bytes += total
-
-    def install(self) -> None:
-        with self._lock:
-            self._installs += 1
-            if self._installs > 1:
-                return
-        try:
-            import jax
-
-            orig = jax.device_put
-
-            def counting_device_put(x, *args, **kwargs):
-                self._count(x)
-                return orig(x, *args, **kwargs)
-
-            self._orig = orig
-            jax.device_put = counting_device_put
-        except Exception:
-            self._orig = None
-
-    def uninstall(self) -> None:
-        with self._lock:
-            if self._installs == 0:
-                return
-            self._installs -= 1
-            if self._installs > 0:
-                return
-        if self._orig is not None:
-            try:
-                import jax
-
-                jax.device_put = self._orig
-            except Exception:
-                pass
-            self._orig = None
-
-    def snapshot(self) -> Dict[str, int]:
-        with self._lock:
-            return {"h2d_calls": self.calls, "h2d_bytes": self.bytes}
-
-
-TRANSFER_COUNTER = TransferCounter()

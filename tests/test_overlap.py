@@ -362,10 +362,18 @@ def test_overlap_replay_ratio_ledger_matches_inline(algo):
 # ---------------------------------------------------------------------------
 # e2e: 512-step DreamerV3 — overlap telemetry, span overlap, retrace==0
 # ---------------------------------------------------------------------------
-def test_dreamer_v3_overlap_512_steps_telemetry_and_no_retraces():
+def test_dreamer_v3_overlap_512_steps_telemetry_and_no_retraces(monkeypatch):
     from sheeprl_tpu.cli import run
     from sheeprl_tpu.telemetry.schema import validate_jsonl
+    from sheeprl_tpu.telemetry.spans import SpanTracker
 
+    recorded, record = [], SpanTracker.record
+
+    def tapped_record(tracker, name, seconds, counts=None):
+        recorded.append((name, tracker.current(), dict(counts or {})))  # the span is off its thread's stack: current() is its parent
+        return record(tracker, name, seconds, counts)
+
+    monkeypatch.setattr(SpanTracker, "record", tapped_record)
     run(
         _dv3_args(
             "overlap_dv3",
@@ -393,6 +401,17 @@ def test_dreamer_v3_overlap_512_steps_telemetry_and_no_retraces():
         and e["spans"].get("Time/train_time", 0) > 0
     ]
     assert both, f"no interval shows env+train spans together: {[e['spans'] for e in logs]}"
+
+    # the act's three children: in the emitted intervals, inside `Player/act` once each an act, with their counts
+    children = ("Player/prepare", "Player/dispatch", "Player/fetch")
+    assert set(children) <= set().union(*(e["spans"] for e in logs))
+    assert {n: {p for name, p, _ in recorded if name == n} for n in children} == {n: {"Player/act"} for n in children}
+    acts = sum(name == "Player/act" for name, _, _ in recorded)
+    assert acts > 0 and all(sum(name == n for name, _, _ in recorded) == acts for n in children)
+    fetched = [c for name, _, c in recorded if name == "Player/fetch"]
+    assert all(c["arrays"] == 2 and c["d2h_bytes"] > 0 for c in fetched)  # the one-hot action and the env's action
+    dispatched = [c for name, _, c in recorded if name == "Player/dispatch"]
+    assert all(c == dispatched[0] for c in dispatched) and dispatched[0]["leaves"] > 10 and dispatched[0]["h2d_bytes"] > 0
 
     # the player's pinned act never retraced (retrace-detector accounting
     # wraps the jitted player step; the shutdown record carries the delta)

@@ -250,11 +250,17 @@ def test_every_token_routed_to_one_held_expert_is_computed_and_none_is_dropped(s
 @pytest.fixture(scope="module")
 def tiny_run(tmp_path_factory):
     """`exp=ppo_recurrent_xing4` at tiny widths, two iterations through `cli.run`: (events, the shapes of everything
-    the loop stores of a rollout)."""
+    the loop stores of a rollout, (name, the span it lay in, its counts) of every span the tracker recorded)."""
     from sheeprl_tpu.algos.ppo_recurrent import sequence_policy as sp
     from sheeprl_tpu.cli import run
+    from sheeprl_tpu.telemetry.spans import SpanTracker
 
-    stored = {}
+    stored, spans = {}, []
+    record = SpanTracker.record
+
+    def tapped_record(tracker, name, seconds, counts=None):
+        spans.append((name, tracker.current(), dict(counts or {})))  # the span is off its thread's stack: current() is its parent
+        return record(tracker, name, seconds, counts)
     make_update_fn, new_state = sp.make_update_fn, sp.new_state
 
     def tapped(*args, **kwargs):
@@ -273,22 +279,22 @@ def tiny_run(tmp_path_factory):
 
     cwd = os.getcwd()
     os.chdir(tmp_path_factory.mktemp("seq_run"))
-    sp.make_update_fn, sp.new_state = tapped, tapped_state
+    sp.make_update_fn, sp.new_state, SpanTracker.record = tapped, tapped_state, tapped_record
     try:
         run(TINY + FEW_ROUNDS + ["run_name=seq_tiny"])
         stream = glob.glob("logs/runs/ppo_recurrent/*/seq_tiny/version_0/telemetry.jsonl")[0]
         with open(stream) as f:
             events = [json.loads(line) for line in f]
     finally:
-        sp.make_update_fn, sp.new_state = make_update_fn, new_state
+        sp.make_update_fn, sp.new_state, SpanTracker.record = make_update_fn, new_state, record
         os.chdir(cwd)
-    return events, stored
+    return events, stored, spans
 
 
 def test_the_recipe_trains_through_cli_run_with_every_new_span_and_event(tiny_run):
     from sheeprl_tpu.telemetry.schema import SPAN_SCHEMAS, validate_event
 
-    events, _ = tiny_run
+    events, _, recorded = tiny_run
     assert [validate_event(e) for e in events if e["event"] in ("moe_load", "sequence_policy", "placement")] == [[]] * 4
     loads = [e for e in events if e["event"] == "moe_load"]
     assert len(loads) == 2 and all(e["dropped"] == 0 and 0 < e["slot_occupancy"] <= 1 and e["max_over_mean"] >= 1 for e in loads)
@@ -301,14 +307,28 @@ def test_the_recipe_trains_through_cli_run_with_every_new_span_and_event(tiny_ru
     assert placement["same_device"] == 1 and placement["refresh"] == "alias"
     spans = set().union(*(e["spans"] for e in events if e["event"] in ("log", "shutdown")))
     assert {"Time/cache_reset", "Player/act", "Player/env_step", "Player/record", "Time/env_interaction_time", "Time/train_time",
-            "Time/param_refresh"} <= spans <= set(SPAN_SCHEMAS)
+            "Time/param_refresh", "Player/prepare", "Player/dispatch", "Player/fetch", "Player/bootstrap",
+            "Time/update_report"} <= spans <= set(SPAN_SCHEMAS)
     assert SPAN_SCHEMAS["Player/act"] == ("tokens", "cache_rows", "forwards") and "tokens" in SPAN_SCHEMAS["Time/train_time"]
+    # the act's three children lie in it, once each an act; the bootstrap in the record; the report on its own
+    parents = {n: {p for name, p, _ in recorded if name == n} for n in ("Player/prepare", "Player/dispatch", "Player/fetch",
+                                                                         "Player/bootstrap", "Time/update_report")}
+    assert parents == {"Player/prepare": {"Player/act"}, "Player/dispatch": {"Player/act"}, "Player/fetch": {"Player/act"},
+                       "Player/bootstrap": {"Player/record"}, "Time/update_report": {None}}
+    acts = sum(name == "Player/act" for name, _, _ in recorded)
+    assert acts == 2 * 16 and all(sum(name == n for name, _, _ in recorded) == acts for n in ("Player/prepare", "Player/dispatch", "Player/fetch"))
+    counts = {n: [c for name, _, c in recorded if name == n] for n in ("Player/dispatch", "Player/fetch", "Player/bootstrap")}
+    assert all(c == {"arrays": 1, "d2h_bytes": 4 * 4} for c in counts["Player/fetch"])  # one int32 id an env
+    # every leaf of the parameters, the cache and the key, and the host arrays: 4 ids (int32) and 4 `is_first` (bool)
+    assert all(c == counts["Player/dispatch"][0] for c in counts["Player/dispatch"]) and counts["Player/dispatch"][0]["h2d_bytes"] == 4 * 4 + 4
+    assert counts["Player/dispatch"][0]["leaves"] > 10
+    assert len(counts["Player/bootstrap"]) >= 2 and all(1 <= c["envs"] <= 4 for c in counts["Player/bootstrap"])  # each rollout's last step
     logs = [e for e in events if e["event"] == "log"]
     assert len(logs) == 2 and all(np.isfinite(e["metrics"]["Loss/policy_loss"]) for e in logs)
 
 
 def test_no_stored_rollout_leaf_has_a_trailing_axis_of_the_vocabulary(tiny_run):
-    _, stored = tiny_run
+    _, stored, _ = tiny_run
     assert {"data/tokens", "data/actions", "data/logprobs", "data/values", "data/advantages", "state/logprobs", "state/cache/latents"} <= set(stored)
     assert all(shape == (4, 16) for name, shape in stored.items() if name.startswith("data/")), stored
     assert not [name for name, shape in stored.items() if shape and shape[-1] in (32, 64)], stored  # the slice, the vocabulary

@@ -265,7 +265,6 @@ def test_facade_windowed_trace_capture(tmp_path, monkeypatch):
                 "metric.telemetry.trace_window": 10,
                 "metric.telemetry.jsonl": False,
                 "metric.telemetry.heartbeat": False,
-                "metric.telemetry.transfer_counter": False,
             }.get(path, default)
 
     telem = Telemetry(Cfg(), str(tmp_path), rank=0)
@@ -285,7 +284,6 @@ def test_facade_honors_disable_timer(tmp_path):
                 "metric.disable_timer": True,
                 "metric.telemetry.jsonl": False,
                 "metric.telemetry.heartbeat": False,
-                "metric.telemetry.transfer_counter": False,
             }.get(path, default)
 
     telem = Telemetry(Cfg(), str(tmp_path), rank=0)
