@@ -150,10 +150,12 @@ def make_update_fn(module: SequencePolicy, tx, cfg: Config, num_minibatches: int
 
 def moe_load_event(load: Dict[str, np.ndarray]) -> Dict[str, Any]:
     """The train call's `moe_load` event from what the update returned per gradient step (summed over the expert
-    layers; `max_over_mean` the worst layer's)."""
+    layers; `max_over_mean` the worst layer's; `fallbacks` the layers and steps whose load took the worst case's
+    buffer)."""
     routed, rows = int(np.sum(load["routed_here"])), int(np.sum(load["rows"]))
     return {"event": "moe_load", "routed_here": routed, "rows": rows, "slot_occupancy": routed / rows,
-            "max_over_mean": float(np.max(load["max_over_mean"])), "dropped": int(np.sum(load["dropped"]))}
+            "max_over_mean": float(np.max(load["max_over_mean"])), "dropped": int(np.sum(load["dropped"])),
+            "fallbacks": int(np.sum(load["fallbacks"]))}
 
 
 def main(dist: Distributed, cfg: Config) -> None:

@@ -244,10 +244,10 @@ def _value(params: Params, x: jax.Array, cfg: SequenceConfig) -> jax.Array:
     return (x @ params["value"]["kernel"] + params["value"]["bias"])[..., 0]
 
 
-def _ffn(layer: Params, X: jax.Array, cfg: SequenceConfig) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+def _ffn(layer: Params, X: jax.Array, cfg: SequenceConfig, fit: bool = False) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     with jax.named_scope("moe"):
         u = rms_norm(X, layer["ffn_norm"]["scale"], cfg.rms_norm_eps)
-        y, load = seq.moe(layer["moe"], u.reshape(-1, u.shape[-1]), cfg)
+        y, load = seq.moe(layer["moe"], u.reshape(-1, u.shape[-1]), cfg, fit=fit)
         return X + y.reshape(X.shape), load
 
 
@@ -281,7 +281,7 @@ def forward_train(params: Params, tokens: jax.Array, is_first: jax.Array, action
             u = rms_norm(X, layer["attn_norm"]["scale"], cfg.rms_norm_eps)
             q, k, v = project(layer["attn"], u, positions, cfg, inv_freq)
             X = X + attend_rows(q, k, v) @ layer["attn"]["w_o"]["kernel"]
-        return _ffn(layer, X, cfg)
+        return _ffn(layer, X, cfg, fit=True)
 
     with jax.named_scope("embed"):
         X = params["embed"]["embedding"][ids]

@@ -26,6 +26,7 @@ per-row coefficient, and the Sinkhorn rounds run over `[n, n, rows]`.
 from __future__ import annotations
 
 import math
+from functools import partial
 from typing import Any, Dict, NamedTuple, Tuple, get_type_hints
 
 import jax
@@ -117,6 +118,12 @@ class SequenceConfig(NamedTuple):
     def expert_rows(self, tokens: int) -> int:
         """Rows of the grouped expert product for `tokens` tokens: every pair that CAN be routed here has a slot."""
         return tokens * min(self.num_experts_per_tok, self.experts_held)
+
+    def load_rows(self, tokens: int) -> int:
+        """Rows of the update's grouped expert product for `tokens` tokens: twice the pairs a uniform router sends to
+        the held experts, in whole tiles of 128 rows, at most `expert_rows`. A load past it takes the worst case."""
+        pairs = -(-2 * tokens * self.num_experts_per_tok * self.experts_held // self.n_routed_experts)
+        return min(-(-pairs // 128) * 128, self.expert_rows(tokens))
 
 
 # -- parameters ----------------------------------------------------------------------
@@ -317,14 +324,65 @@ def route(p: Params, u: jax.Array, cfg: SequenceConfig) -> Tuple[jax.Array, jax.
     return idx, sk / (jnp.sum(sk, -1, keepdims=True) + 1e-20) * cfg.routed_scaling_factor
 
 
-def moe(p: Params, u: jax.Array, cfg: SequenceConfig, choices: bool = False, grouped: bool = True) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+def _grouped(e: Params, u: jax.Array, w: jax.Array, order: jax.Array, sizes: jax.Array, routed: jax.Array, k: int,
+             M: int) -> jax.Array:
+    """The grouped form over a buffer of `M` rows: the first `M` pairs of `order` (sorted by held expert, the `routed`
+    pairs of the held experts first; rows past them are padding) gathered from `u` [R, C], multiplied as one grouped
+    product per kernel, weighted by `w` [R, k] and summed back by token."""
+    order = order[:M]
+    live = (jnp.arange(M) < routed)[:, None]
+    token = order // k
+    with jax.named_scope("experts"):
+        x = jnp.where(live, u[token], 0.0)
+        h = jax.nn.silu(jax.lax.ragged_dot(x, e["w_gate"]["kernel"], sizes)) * jax.lax.ragged_dot(x, e["w_up"]["kernel"], sizes)
+        y = jnp.where(live, jax.lax.ragged_dot(h, e["w_down"]["kernel"], sizes), 0.0)
+    y = y * w.reshape(-1)[order][:, None]
+    return jnp.zeros_like(u).at[token].add(y)
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8))
+def _fitted(e: Params, u: jax.Array, w: jax.Array, order: jax.Array, sizes: jax.Array, routed: jax.Array, k: int, rows: int,
+            M: int) -> jax.Array:
+    """`_grouped` over `rows` where the routed pairs fit them, else over the worst case's `M`, by a `lax.cond`. The
+    backward takes the same branch in a `lax.cond` of its own and computes that branch's forward again inside it, as
+    remat would: differentiated through, the forward `cond` would hand the worst case's M-sized residuals out of
+    either branch, zeros from the small one."""
+    return jax.lax.cond(routed <= rows, partial(_grouped, k=k, M=rows), partial(_grouped, k=k, M=M), e, u, w, order, sizes, routed)
+
+
+def _fitted_fwd(e, u, w, order, sizes, routed, k, rows, M):
+    return _fitted(e, u, w, order, sizes, routed, k, rows, M), (e, u, w, order, sizes, routed)
+
+
+def _fitted_bwd(k, rows, M, res, ct):
+    e, u, w, order, sizes, routed = res
+
+    def grads(m):
+        return lambda e, u, w: jax.vjp(lambda e, u, w: _grouped(e, u, w, order, sizes, routed, k, m), e, u, w)[1](ct)
+
+    return (*jax.lax.cond(routed <= rows, grads(rows), grads(M), e, u, w), None, None, None)
+
+
+_fitted.defvjp(_fitted_fwd, _fitted_bwd)
+
+
+def moe(p: Params, u: jax.Array, cfg: SequenceConfig, choices: bool = False, grouped: bool = True,
+        fit: bool = False) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     """The expert layer over `u` [R, C]: dropless with static shapes, in two forms that agree. Returns the load beside
-    the output: the pairs computed, the rows multiplied, the pairs left out (none) and the fullest held expert's load
-    over the mean.
+    the output: the pairs computed, the rows multiplied, the pairs left out (none), the fullest held expert's load
+    over the mean and whether the load took the worst case's buffer (`fallbacks`).
 
     `grouped` (the training form): the (token, expert) pairs routed to the experts held here are sorted by expert into
     a buffer with a slot for every pair that can come (`expert_rows`), multiplied as one grouped product per kernel,
-    weighted and summed back by token.
+    weighted and summed back by token. With `fit` (the block-diffusion update's) the buffer holds `load_rows`, about
+    twice the load a uniform router sends, and a load past it takes the worst case's buffer behind a `lax.cond`
+    (`_fitted`): the same pairs by the same kernels in the same sorted order, with fewer rows of padding. Where
+    `load_rows` is `expert_rows` the program is the worst case's. A decode step's few rows route to every held
+    expert, and its time is the kernels' bytes: it keeps the worst case. The one-token update (`forward_train` here)
+    keeps it too for now: on a TPU the grouped products compile to kernels named `ragged-dot-none` with no
+    `named_scope`, so the benchmark books to `experts` only the padding's gathers and scatters, and its
+    `train_step.moe_roofline` would divide the kernels' bound by the padding's time alone (143 % at
+    `xing4_a4b.gen32x512` with `fit` on a v5e, `PERF.md` section 6).
 
     Not `grouped` (the acting form, one token an env): every held expert multiplies every row, and the routing's
     weights, zero where an expert was not chosen, sum the results. No sort and no gather, and the same work whatever
@@ -337,20 +395,20 @@ def moe(p: Params, u: jax.Array, cfg: SequenceConfig, choices: bool = False, gro
     local = idx - cfg.first_expert
     here = (local >= 0) & (local < E)
     e = p["experts"]
+    fallbacks = jnp.zeros((), jnp.int32)
     if grouped:
         local = jnp.where(here, local, E).reshape(-1)  # pairs of the absent chips sort last
         M = cfg.expert_rows(R)
         order = jnp.argsort(local, stable=True)[:M]
         sizes = jnp.bincount(local, length=E + 1)[:E].astype(jnp.int32)
         routed = jnp.minimum(jnp.sum(sizes), M)  # what the buffer holds of what came: all of it, M is the worst case
-        live = (jnp.arange(M) < routed)[:, None]
-        token = order // k
-        with jax.named_scope("experts"):
-            x = jnp.where(live, u[token], 0.0)
-            h = jax.nn.silu(jax.lax.ragged_dot(x, e["w_gate"]["kernel"], sizes)) * jax.lax.ragged_dot(x, e["w_up"]["kernel"], sizes)
-            y = jnp.where(live, jax.lax.ragged_dot(h, e["w_down"]["kernel"], sizes), 0.0)
-        y = y * w.reshape(-1)[order][:, None]
-        routed_out = jnp.zeros_like(u).at[token].add(y)
+        rows = cfg.load_rows(R) if fit else M
+        if rows < M:
+            routed_out = _fitted(e, u, w, order, sizes, routed, k, rows, M)
+            fallbacks = (routed > rows).astype(jnp.int32)
+            M = jnp.where(fallbacks, M, rows)
+        else:
+            routed_out = _grouped(e, u, w, order, sizes, routed, k, M)
     else:
         M = R * E
         chose = local[..., None] == jnp.arange(E)  # [R, k, E]
@@ -361,7 +419,7 @@ def moe(p: Params, u: jax.Array, cfg: SequenceConfig, choices: bool = False, gro
             routed_out = sum(weight[:, i:i + 1] * gated_mlp(jax.tree.map(lambda x: x[i], e), u) for i in range(E))
     out = routed_out + gated_mlp(p["shared"], u) if "shared" in p else routed_out
     load = {"routed_here": routed, "rows": jnp.asarray(M, jnp.int32), "dropped": jnp.sum(here) - routed,
-            "max_over_mean": jnp.max(sizes) * E / jnp.maximum(routed, 1).astype(jnp.float32)}
+            "max_over_mean": jnp.max(sizes) * E / jnp.maximum(routed, 1).astype(jnp.float32), "fallbacks": fallbacks}
     if choices:  # for a comparison of routings: the chosen experts of every token, sorted
         load["chosen"] = jnp.sort(idx, -1)
     return out, load
@@ -405,7 +463,7 @@ def _sum_loads(loads) -> Dict[str, jax.Array]:
     loads = [x for x in loads if x is not None]
     if not loads:
         zero = jnp.zeros((), jnp.int32)
-        return {"routed_here": zero, "rows": zero, "dropped": zero, "max_over_mean": jnp.zeros(())}
+        return {"routed_here": zero, "rows": zero, "dropped": zero, "max_over_mean": jnp.zeros(()), "fallbacks": zero}
     how = {"max_over_mean": jnp.max, "chosen": lambda x: x}  # the choices stay per layer: [expert layers, rows, k]
     return {k: how.get(k, jnp.sum)(jnp.stack([x[k] for x in loads])) for k in loads[0]}
 

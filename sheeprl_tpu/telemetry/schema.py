@@ -478,15 +478,20 @@ EVENT_SCHEMAS: Dict[str, Dict[str, Tuple[bool, type]]] = {
     },
     # the expert layers' load over one train call, from numbers the update
     # returns beside its losses: (token, expert) pairs computed here, the
-    # rows the grouped products multiplied (a slot for every pair that can
-    # come), their ratio, the fullest held expert's load over the mean (the
-    # worst layer and step) and the pairs left out (none: dropless)
+    # rows the grouped products multiplied (the buffer each layer and step
+    # took: a slot for every pair that can come, or `load_rows`, about
+    # twice a uniform router's load, where the update sizes it so), their
+    # ratio, the fullest held expert's load over the mean (the worst layer
+    # and step), the pairs left out (none: dropless) and the (layer,
+    # gradient step) pairs whose load did not fit `load_rows` and took the
+    # worst case's buffer
     "moe_load": {
         "routed_here": (True, _NUM),
         "rows": (True, _NUM),
         "slot_occupancy": (True, _NUM),
         "max_over_mean": (True, _NUM),
         "dropped": (True, _NUM),
+        "fallbacks": (True, _NUM),
     },
     # how a device replay ring keeps its rows, once per ring when it is
     # allocated (data/device_ring.py `_allocate`): per key the item's own

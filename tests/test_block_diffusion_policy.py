@@ -17,6 +17,7 @@ which magnifies a rounding where a gradient is near 0)."""
 import glob
 import json
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -291,6 +292,119 @@ def test_the_expert_shares_add_up_to_the_whole_layer_with_softmax_routing_and_no
         parts = parts + y
         assert int(load["dropped"]) == 0
     close(parts, whole)
+
+
+FIT_TOKENS = 256  # rows of the update-form tests: with 2 of the 8 experts held, the load-sized buffer (256) is half the worst case's
+
+
+@pytest.fixture(scope="module")
+def seeded(small):
+    """The fixture's seeded weights, made once for the tests below."""
+    return weights(small[3])
+
+
+def two_held(p, scfg, routing, steer):
+    """The fixture's layer cut to its first 2 held experts under `routing` (`sigmoid`, with an `e_bias` to choose by,
+    or `softmax`) and FIT_TOKENS rows of input; with `steer`, one input column the router reads as +50 for a held expert
+    and -50 for the others."""
+    scfg = scfg._replace(experts_held=2, scoring_func=routing)
+    p = {"router": p["router"], "experts": {n: {"kernel": k["kernel"][:2]} for n, k in p["experts"].items()}}
+    if routing == "sigmoid":
+        p["e_bias"] = 0.1 * jax.random.normal(jax.random.key(18), (scfg.n_routed_experts,))
+    u = jax.random.normal(jax.random.key(17), (FIT_TOKENS, scfg.hidden_size))
+    if steer:
+        local = jnp.arange(scfg.n_routed_experts) - scfg.first_expert
+        p["router"] = {"kernel": p["router"]["kernel"].at[0].set(jnp.where((local >= 0) & (local < 2), 50.0, -50.0))}
+        u = u.at[:, 0].set(1.0)
+    return p, u, scfg
+
+
+@pytest.mark.parametrize("routing", ["sigmoid", "softmax"])
+@pytest.mark.parametrize("steer", [False, True], ids=["load_inside_the_buffer", "load_over_the_buffer"])
+def test_the_load_sized_buffer_gives_the_worst_cases_values_and_gradients_with_no_shared_expert(small, seeded, routing, steer):
+    """The update's form of the expert layer (`moe(fit=True)`) against the worst case's: values, and the gradients of
+    the input, the router and the three expert kernels; a load over the buffer takes the worst case's, and no pair
+    is dropped."""
+    module = small[1]
+    p, u, scfg = two_held(seeded["layer_1"]["moe"], module.cfg, routing, steer)
+    assert (scfg.load_rows(FIT_TOKENS), scfg.expert_rows(FIT_TOKENS)) == (256, 512)
+
+    def run(fit):
+        def loss(p, u):
+            y, load = seq.moe(p, u, scfg, fit=fit)
+            return jnp.sum(jnp.sin(y)), (y, load)
+
+        (_, (y, load)), (gp, gu) = jax.jit(jax.value_and_grad(loss, (0, 1), has_aux=True))(p, u)
+        return y, load, [gu, gp["router"]["kernel"], *(k["kernel"] for k in gp["experts"].values())]
+
+    (y, load, grads), (want, want_load, want_grads) = run(True), run(False)
+    close(y, want)
+    for g, w in zip(grads, want_grads):
+        close(g, w)
+    routed = int(want_load["routed_here"])
+    assert int(load["routed_here"]) == routed and int(load["dropped"]) == 0 and int(want_load["rows"]) == 512
+    fallbacks, rows = int(load["fallbacks"]), int(load["rows"])
+    assert (fallbacks, rows) == ((1, 512) if steer else (0, 256)) and (routed == 512 if steer else 0 < routed <= 256)
+
+
+def test_at_the_fixtures_size_the_updates_expert_layer_has_no_branch(small):
+    """4 of 8 experts held and top-2: twice a uniform router's load is every pair that can come, so the update's
+    forward lowers with no `cond` (the worst case's program); with 2 of 16 held the `cond` is there."""
+    _, module, _, shapes, r = small
+    rows = B * (1 + S) * T * L
+
+    def branches(cfg, shapes):
+        fwd = lambda p: bd.forward_train(p, r["tokens"], r["is_first"], r["actions"], r["order"], cfg, S, module.mask_id)  # noqa: E731
+        tree = nest({n: jax.ShapeDtypeStruct(*sd) for n, sd in shapes.items()})
+        return "stablehlo.case" in jax.jit(jax.grad(lambda p: jnp.sum(fwd(p)[0][..., 0]))).lower(tree).as_text()
+
+    two = module.cfg._replace(experts_held=2, n_routed_experts=16)
+    two_shapes = {n: ((2, *shape[1:]) if "/experts/" in n else (shape[0], 16) if n.endswith("router/kernel") else shape, dtype)
+                  for n, (shape, dtype) in shapes.items()}
+    assert module.cfg.load_rows(rows) == module.cfg.expert_rows(rows) and not branches(module.cfg, shapes)
+    assert two.load_rows(rows) < two.expert_rows(rows) and branches(two, two_shapes)
+
+
+def test_every_expert_op_of_the_update_lies_under_moe_and_experts(small):
+    """The benchmark books the experts' device time by the `op_name`'s whole components (`span_reduce.part_of`): with 2
+    of 16 experts held, every grouped product of the update, in both branches of the `cond` (the worst case's first),
+    forward and backward (which computes the branch's forward again), carries both `moe` and `experts`."""
+    from perfbench.span_reduce import part_of
+
+    _, module, _, shapes, r = small
+    two = module.cfg._replace(experts_held=2, n_routed_experts=16)
+    tree = nest({n: jax.ShapeDtypeStruct((2, *shape[1:]) if "/experts/" in n else (shape[0], 16) if n.endswith("router/kernel") else shape, dtype)
+                 for n, (shape, dtype) in shapes.items()})
+    fwd = lambda p: bd.forward_train(p, r["tokens"], r["is_first"], r["actions"], r["order"], two, S, module.mask_id)  # noqa: E731
+    text = jax.jit(jax.grad(lambda p: jnp.sum(fwd(p)[0][..., 0]))).lower(tree).as_text(debug_info=True)
+    names = set(re.findall(r'loc\("([^"]*ragged_dot_general)"', text))
+    assert names and all(part_of(n, ("moe",)) == "moe" and part_of(n, ("experts",)) == "experts" for n in names), names
+    branches = {re.search(r"/cond/(branch_\d)_fun/", n).group(1) for n in names}
+    passes = {next(p for p in ("transpose", "jvp") if p in n) for n in names}
+    assert branches == {"branch_0", "branch_1"} and passes == {"transpose", "jvp"}, names
+
+
+def test_the_env_step_multiplies_the_worst_cases_rows_with_no_loop_or_branch(small, seeded):
+    """The env step's forwards route 2 L and L rows an env and every held expert is chosen: its grouped products keep
+    the worst case's buffer, `expert_rows`, outside any loop or branch."""
+    from sheeprl_tpu.algos.ppo_recurrent import block_diffusion_policy as bdp
+
+    _, module, _, _, rollout = small
+    scfg = module.cfg
+    act = bdp.make_act_fn(module)
+    jaxpr = jax.make_jaxpr(act)(seeded, bdp.new_state(module, B, T), rollout["tokens"][:, 0], rollout["is_first"][:, 0],
+                                jax.random.key(0)).jaxpr
+
+    def eqns(jaxpr, inside=()):
+        for eqn in jaxpr.eqns:
+            yield eqn, inside
+            flow = (eqn.primitive.name,) if eqn.primitive.name in ("while", "cond") else ()
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from eqns(sub, inside + flow)
+
+    products = [(inside, e.invars[0].aval.shape[0]) for e, inside in eqns(jaxpr) if e.primitive.name == "ragged_dot_general"]
+    assert len(products) == 3 * S * scfg.num_hidden_layers
+    assert {inside for inside, _ in products} == {()} and {rows for _, rows in products} == {scfg.expert_rows(B * 2 * L), scfg.expert_rows(B * L)}
 
 
 def test_the_config_reads_the_published_keys_and_refuses_what_it_cannot_run(small):

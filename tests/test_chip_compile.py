@@ -360,27 +360,38 @@ def _instructions(text):
     return re.split(r"\n\s*(?:ROOT )?%", text)
 
 
+_BLOCK_UPDATES = {}  # the compiled update of each attention form: two tests read the kernel form's
+
+
+def _block_update(one_chip, block_carry, form):
+    """`jit_update` at the cell's attention widths and rows (what `auto` resolves to on a TPU is the kernel), compiled."""
+    from sheeprl_tpu.algos.ppo_recurrent import block_diffusion_policy as bdp
+    from sheeprl_tpu.config import compose, instantiate
+    from sheeprl_tpu.optim import clipped
+
+    if form not in _BLOCK_UPDATES:
+        module, params, _, _ = block_carry
+        cfg = compose("config", BD_CUT)
+        tx = clipped(instantiate(cfg.algo.optimizer), cfg.algo.get("max_grad_norm", 0.0))
+        L = module.block_length
+        data = {k: _sds(one_chip, (SEQ_ENVS, BD_BLOCKS, L), jnp.int32) for k in ("tokens", "actions", "order")}
+        data["is_first"] = _sds(one_chip, (SEQ_ENVS, BD_BLOCKS), jnp.bool_)
+        data.update({k: _sds(one_chip, (SEQ_ENVS, BD_BLOCKS)) for k in ("logprobs", "values", "returns", "advantages", "rewards", "dones")})
+        coefs = {k: _sds(one_chip, ()) for k in ("clip_coef", "ent_coef", "vf_coef", "lr_frac")}
+        key = _like(one_chip, jax.eval_shape(lambda: jax.random.key(0)))
+        update = bdp.make_update_fn(module, tx, cfg, SEQ_ENVS // BD_SEQS, BD_SEQS, attention=form)
+        _BLOCK_UPDATES[form] = update.lower(params, _like(one_chip, jax.eval_shape(tx.init, params)), data, coefs, key).compile()
+    return _BLOCK_UPDATES[form]
+
+
 @pytest.mark.parametrize("form", ["kernel", "xla"])
 def test_block_diffusion_update_compiles_for_v5e_with_the_attention_as_one_kernel(one_chip, block_carry, form):
     """`jit_update` at the cell's attention widths and rows. With the kernel (what `auto` resolves to on a TPU) no f32
     tensor of scores, rows against clean keys, is left in the program, and every kernel's `op_name` carries the
     scope `bd_attn`, by which the benchmark books it; a forward kernel for the forward and for remat's recompute and
     one backward kernel (dq beside dk and dv) in each layer. The XLA form is the control: it holds such tensors."""
-    from sheeprl_tpu.algos.ppo_recurrent import block_diffusion_policy as bdp
-    from sheeprl_tpu.config import compose, instantiate
-    from sheeprl_tpu.optim import clipped
-
-    module, params, _, _ = block_carry
-    cfg = compose("config", BD_CUT)
-    tx = clipped(instantiate(cfg.algo.optimizer), cfg.algo.get("max_grad_norm", 0.0))
-    L = module.block_length
-    data = {k: _sds(one_chip, (SEQ_ENVS, BD_BLOCKS, L), jnp.int32) for k in ("tokens", "actions", "order")}
-    data["is_first"] = _sds(one_chip, (SEQ_ENVS, BD_BLOCKS), jnp.bool_)
-    data.update({k: _sds(one_chip, (SEQ_ENVS, BD_BLOCKS)) for k in ("logprobs", "values", "returns", "advantages", "rewards", "dones")})
-    coefs = {k: _sds(one_chip, ()) for k in ("clip_coef", "ent_coef", "vf_coef", "lr_frac")}
-    key = _like(one_chip, jax.eval_shape(lambda: jax.random.key(0)))
-    update = bdp.make_update_fn(module, tx, cfg, SEQ_ENVS // BD_SEQS, BD_SEQS, attention=form)
-    text = update.lower(params, _like(one_chip, jax.eval_shape(tx.init, params)), data, coefs, key).compile().as_text()
+    module = block_carry[0]
+    text = _block_update(one_chip, block_carry, form).as_text()
     score = re.compile(r"f32\[(?:\d+,){2,}(?:%d,%d|%d,%d|%d,%d|%d,%d)\]" % (
         BD_ROWS, BD_CLEAN, BD_CLEAN, BD_ROWS, BD_ROWS - BD_CLEAN, BD_CLEAN, BD_CLEAN, BD_ROWS - BD_CLEAN))
     scores = [line.strip()[:120] for line in text.splitlines() if score.search(line)]
@@ -394,3 +405,50 @@ def test_block_diffusion_update_compiles_for_v5e_with_the_attention_as_one_kerne
     op_names = [re.findall(r'metadata=\{op_name="([^"]*)"', i) for i in kernels]
     # a component of the name, as `span_reduce.part_of` reads it: autodiff wraps it (`jvp(bd_attn)`)
     assert all(len(n) == 1 and re.search(r"[/(]bd_attn[)/]", n[0]) for n in op_names), op_names
+
+
+def _computations(text):
+    """The compiled program's computations by name, each its body's text."""
+    comps, name, body = {}, None, []
+    for line in text.splitlines():
+        head = re.match(r"(?:ENTRY )?%([\w.-]+) .*\{$", line)
+        if head and not line.startswith(" "):
+            name, body = head.group(1), []
+        elif line == "}" and name is not None:
+            comps[name], name = "\n".join(body), None
+        elif name is not None:
+            body.append(line)
+    return comps
+
+
+def _reachable(comps, roots):
+    seen, todo = set(), list(roots)
+    while todo:
+        name = todo.pop()
+        if name not in seen:
+            seen.add(name)
+            todo += [c for c in re.findall(r"%([\w.-]+)", comps[name]) if c in comps]
+    return seen
+
+
+def test_block_diffusion_update_compiles_for_v5e_with_the_worst_case_expert_buffer_behind_a_cond(one_chip, block_carry):
+    """At the cut the update routes 6144 rows a layer to 4 of 16 experts, top-2: the load-sized buffer holds 6144 pairs
+    and the worst case's 12288. Each expert layer has two `conditional`s under `moe`, the forward's and the backward's,
+    and every tensor of the worst case's rows lies in their fallback branches (the first: `lax.cond`'s false branch),
+    so a load that fits never touches one."""
+    from perfbench.span_reduce import part_of
+
+    module = block_carry[0]
+    scfg = module.cfg
+    R = BD_SEQS * BD_ROWS
+    rows, M = scfg.load_rows(R), scfg.expert_rows(R)
+    assert (rows, M, scfg.hidden_size, scfg.moe_intermediate_size) == (6144, 12288, 256, 256)
+    text = _block_update(one_chip, block_carry, "kernel").as_text()
+    conds = [i for i in _instructions(text) if re.match(r"[\w.-]+ = .* conditional\(", i)]
+    assert len(conds) == 2 * scfg.num_hidden_layers
+    names = [re.search(r'op_name="([^"]*)"', i).group(1) for i in conds]
+    assert all(n.endswith("/cond") and part_of(n, ("moe",)) == "moe" for n in names), names
+    comps = _computations(text)
+    fallback = _reachable(comps, [re.search(r"branch_computations=\{%([\w.-]+),", i).group(1) for i in conds])
+    worst = {name for name, body in comps.items() if re.search(r"= f32\[%d,%d\]" % (M, scfg.hidden_size), body)}
+    assert worst and worst <= fallback, sorted(worst - fallback)

@@ -9,6 +9,7 @@ whole; the routed layer drops nothing; the recipe through `cli.run`."""
 import glob
 import json
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -236,15 +237,130 @@ def test_every_token_routed_to_one_held_expert_is_computed_and_none_is_dropped(s
     p = weights(shapes)["layer_1"]["moe"]
     p = {**p, "e_bias": jnp.zeros_like(p["e_bias"]).at[scfg.first_expert].set(100.0)}  # every token's first choice
     u = jax.random.normal(jax.random.key(16), (B * T, scfg.hidden_size))
-    y, load = seq.moe(p, u, scfg, grouped=grouped)
+    y, load = seq.moe(p, u, scfg, grouped=grouped, fit=grouped)  # the block-diffusion update's form of the grouped layer
     want, _ = reference.experts(p, u[None], sz, None)
     close(y, want[0])
+    # the rows of the buffer taken: at this size the load-sized buffer is the worst case's
+    assert scfg.load_rows(B * T) == scfg.expert_rows(B * T)
     rows = scfg.expert_rows(B * T) if grouped else B * T * scfg.experts_held
-    assert int(load["dropped"]) == 0 and B * T <= int(load["routed_here"]) <= int(load["rows"]) == rows
+    assert int(load["dropped"]) == 0 and B * T <= int(load["routed_here"]) <= int(load["rows"]) == rows and int(load["fallbacks"]) == 0
     other, other_load = seq.moe(p, u, scfg, grouped=not grouped)
     close(y, other, 1e-5)
     assert int(other_load["routed_here"]) == int(load["routed_here"])
     assert float(load["max_over_mean"]) >= 1.0
+
+
+FIT_TOKENS = 256  # rows of the expert layer's own tests of its `fit` form: the load-sized buffer (256) is half the worst case's
+
+
+@pytest.fixture(scope="module")
+def seeded(small):
+    """The fixture's seeded weights, made once for the tests below (each making takes seconds)."""
+    return weights(small[3])
+
+
+def routed_layer(p, scfg, routing, steer):
+    """The layer `p` under `routing` (`sigmoid`, which chooses by score plus `e_bias`, or `softmax`) and FIT_TOKENS rows of
+    input; with `steer`, one input column the router reads as +50 for a held expert and -50 for the others, so that
+    every token's choices are the held experts."""
+    scfg = scfg._replace(scoring_func=routing)
+    p = {n: v for n, v in p.items() if n != "e_bias"}
+    if routing == "sigmoid":
+        p["e_bias"] = 0.1 * jax.random.normal(jax.random.key(18), (scfg.n_routed_experts,))
+    u = jax.random.normal(jax.random.key(17), (FIT_TOKENS, scfg.hidden_size))
+    if steer:
+        local = jnp.arange(scfg.n_routed_experts) - scfg.first_expert
+        p["router"] = {"kernel": p["router"]["kernel"].at[0].set(jnp.where((local >= 0) & (local < scfg.experts_held), 50.0, -50.0))}
+        u = u.at[:, 0].set(1.0)
+    return p, u, scfg
+
+
+def load_sized_and_worst_case(p, u, scfg):
+    """((output, load, gradients) of the update's form, the same of the worst case's), the gradients those of `u`, the
+    router and the three expert kernels."""
+    def run(fit):
+        def loss(p, u):
+            y, load = seq.moe(p, u, scfg, fit=fit)
+            return jnp.sum(jnp.sin(y)), (y, load)
+
+        (_, (y, load)), (gp, gu) = jax.jit(jax.value_and_grad(loss, (0, 1), has_aux=True))(p, u)
+        return y, load, {"u": gu, "router": gp["router"]["kernel"], **{n: k["kernel"] for n, k in gp["experts"].items()}}
+
+    return run(True), run(False)
+
+
+@pytest.mark.parametrize("routing", ["sigmoid", "softmax"])
+@pytest.mark.parametrize("steer", [False, True], ids=["load_inside_the_buffer", "load_over_the_buffer"])
+def test_the_load_sized_buffer_gives_the_worst_cases_values_and_gradients(small, seeded, routing, steer):
+    """The layer's `fit` form (the block-diffusion update's) sizes the buffer for about twice a uniform router's load;
+    a load over it takes the worst case's buffer. Either way the same pairs, none dropped, and the worst case's values
+    and gradients."""
+    _, scfg, _, shapes, _, _ = small
+    p, u, scfg = routed_layer(seeded["layer_1"]["moe"], scfg, routing, steer)
+    assert scfg.load_rows(FIT_TOKENS) == 256 and scfg.expert_rows(FIT_TOKENS) == 512
+    (y, load, grads), (want, want_load, want_grads) = load_sized_and_worst_case(p, u, scfg)
+    close(y, want)
+    for name, g in want_grads.items():
+        close(grads[name], g)
+    routed = int(want_load["routed_here"])
+    assert int(load["routed_here"]) == routed and int(load["dropped"]) == int(want_load["dropped"]) == 0
+    assert int(want_load["rows"]) == 512 and int(want_load["fallbacks"]) == 0
+    if steer:  # every pair of every token is here: the worst case's buffer
+        assert routed == 512 and (int(load["fallbacks"]), int(load["rows"])) == (1, scfg.expert_rows(FIT_TOKENS))
+    else:
+        assert 0 < routed <= 256 and (int(load["fallbacks"]), int(load["rows"])) == (0, scfg.load_rows(FIT_TOKENS))
+
+
+def _has_branch(lowered):
+    return "stablehlo.case" in lowered.as_text()
+
+
+def test_where_the_load_sized_buffer_is_the_worst_cases_the_layer_is_the_worst_cases_program(small, seeded):
+    """At the fixture's own size the load-sized buffer holds every pair that can come: the update's form lowers to the
+    worst case's program, with no `cond`; where it is smaller the `cond` is there."""
+    scfg, p = small[1], seeded["layer_1"]["moe"]
+
+    def lowered(tokens, fit):
+        u = jax.ShapeDtypeStruct((tokens, scfg.hidden_size), jnp.float32)
+        return jax.jit(jax.grad(lambda p, u: jnp.sum(seq.moe(p, u, scfg, fit=fit)[0]), (0, 1))).lower(p, u)
+
+    assert scfg.load_rows(B * T) == scfg.expert_rows(B * T) and scfg.load_rows(FIT_TOKENS) < scfg.expert_rows(FIT_TOKENS)
+    assert lowered(B * T, True).as_text() == lowered(B * T, False).as_text() and not _has_branch(lowered(B * T, True))
+    assert _has_branch(lowered(FIT_TOKENS, True)) and not _has_branch(lowered(FIT_TOKENS, False))
+
+
+def test_the_one_token_update_keeps_the_worst_cases_buffer(small, seeded):
+    """`forward_train` asks for no `fit`: at 128 tokens, where the load-sized buffer would be half the worst case's,
+    its expert layers lower with no `cond` (the reason is in `moe`'s docstring)."""
+    scfg = small[1]
+    assert scfg.load_rows(4 * 32) < scfg.expert_rows(4 * 32)
+    tokens, first = jax.ShapeDtypeStruct((4, 32), jnp.int32), jax.ShapeDtypeStruct((4, 32), jnp.bool_)
+    grad = jax.jit(jax.grad(lambda p, t, f: jnp.sum(seq.forward_train(p, t, f, scfg)[0])))
+    assert not _has_branch(grad.lower(seeded, tokens, first))
+
+
+def all_eqns(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs it calls."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from all_eqns(sub)
+
+
+def test_the_decode_step_keeps_the_acting_form_with_no_loop_or_branch(small, seeded):
+    """The decode step's experts are the acting form (every held expert over every row): no grouped product, and no
+    loop or branch under `moe`."""
+    from sheeprl_tpu.algos.ppo_recurrent import sequence_policy as sp
+    from sheeprl_tpu.algos.ppo_recurrent.agent import SequencePolicy
+
+    _, scfg, _, _, tokens, is_first = small
+    module, params = SequencePolicy(scfg, "token"), seeded
+    act = sp.make_act_fn(module)
+    jaxpr = jax.make_jaxpr(act)(params, sp.new_state(module, B, T), tokens[:, 0], is_first[:, 0], jax.random.key(0)).jaxpr
+    eqns = list(all_eqns(jaxpr))
+    assert not [e for e in eqns if e.primitive.name == "ragged_dot_general"]
+    assert any("moe" in str(e.source_info.name_stack) for e in eqns)
+    assert not [e for e in eqns if e.primitive.name in ("while", "cond") and "moe" in str(e.source_info.name_stack)]
 
 
 @pytest.fixture(scope="module")
@@ -300,6 +416,7 @@ def test_the_recipe_trains_through_cli_run_with_every_new_span_and_event(tiny_ru
     assert len(loads) == 2 and all(e["dropped"] == 0 and 0 < e["slot_occupancy"] <= 1 and e["max_over_mean"] >= 1 for e in loads)
     # 2 expert layers x 2 epochs x 2 minibatches of [16, 2] tokens, a slot for each of a token's 2 choices
     assert all(e["rows"] == 2 * 4 * 32 * 2 and e["routed_here"] == round(e["slot_occupancy"] * e["rows"]) for e in loads)
+    assert all(e["fallbacks"] == 0 for e in loads)  # a buffer that holds every pair that can come
     share = next(e for e in events if e["event"] == "sequence_policy")
     assert (share["experts_held"], share["first_expert"], share["heads_held"], share["vocab_held"], share["layers"]) == (2, 2, 2, 32, 3)
     assert share["cache_bytes"] == 3 * 4 * 16 * 128 * 4 + 4 + 4 * 4 and share["cache_layout"] == {"latents": [0, 1, 2, 3]}  # 24 columns held in 128
